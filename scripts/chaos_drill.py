@@ -657,7 +657,7 @@ def drill_supervisor__sigkill_ga_resume():
         cmd = [sys.executable, "-m", "veles_tpu"]
         if fault:
             cmd.append("--supervise")
-        cmd += ["--optimize", "5:2", "-b", "tpu-evaluator",
+        cmd += ["--optimize", "5:2", "-b", "cpu",
                 "--ga-workers", "2", "--ga-state", state, wf, cfg]
         res = subprocess.run(cmd, env=env, capture_output=True,
                              text=True, timeout=420, cwd=REPO)

@@ -27,7 +27,7 @@ XLA materializes more, so the printed ceiling is an upper bound):
 Usage: python scripts/layer_roofline.py [mb] [--measure] [--iters K]
 
 ``--measure`` (round-5 VERDICT next #3 — finish the ceiling proof):
-runs each AlexNet conv's fwd+bwd ALONE on the default jax device at
+runs each AlexNet conv's fwd+bwd ALONE on the TPU (-b tpu rules) at
 the same shapes/dtypes the fused step uses (bf16 compute on TPU, f32
 master params, per-iteration param carry inside a lax.scan so XLA
 cannot hoist the loop-invariant work) and prints measured us/sample
@@ -155,9 +155,17 @@ def measure_conv_layers(w, rows, mb: int, iters: int = 8,
     from veles_tpu.backends import make_device
     from veles_tpu.engine import core as engine_core
 
-    device = make_device("auto")
-    if not device.is_jax:
-        raise SystemExit("--measure needs a jax device (TPU/XLA:CPU)")
+    from veles_tpu import profiling
+
+    device = make_device("tpu")   # a chip timing or none
+    # the floors this is printed against are v5e's: a chip with other
+    # peaks (or one profiling.py does not know) has no roofline here
+    peak = profiling.device_peak_flops(device.jax_device)
+    if peak != PEAK_FLOPS:
+        raise RuntimeError(
+            f"--measure: floors are computed for {PEAK_FLOPS:.3g} "
+            f"FLOP/s (v5e); {device.jax_device.device_kind!r} peaks "
+            f"at {peak} in profiling.PEAK_FLOPS")
     cd = jnp.dtype(device.compute_dtype)
     mixed = cd != jnp.float32
     floor_by_name = {r["name"]: r for r in rows}
@@ -273,8 +281,7 @@ def main():
     if measure:
         from veles_tpu.backends import make_device
         measured = measure_conv_layers(w, rows, mb, iters=iters)
-        kind = getattr(make_device("auto").jax_device, "device_kind",
-                       "cpu")
+        kind = make_device("tpu").jax_device.device_kind
         print_measured(measured, kind)
 
 

@@ -48,7 +48,7 @@ def main():
         decision_config={"max_epochs": 10 ** 9},
         superstep=ss, name="Roofline")
     w.evaluator.compute_confusion = False
-    device = make_device("auto")
+    device = make_device("tpu")   # a chip timing or none
     w.initialize(device=device)
     loader, fused = w.loader, w.fused
 

@@ -218,6 +218,30 @@ class TestFusedMatrix:
         self.assert_bitwise(p_rep, p_str)
 
 
+class TestOneExecutablePerStep:
+    """PR 21 (found on the chip: the second dispatch of a cold AlexNet
+    run re-compiled for 23 s): jit lowers one executable per pattern
+    of committed/uncommitted arguments, so host-fresh metric carries
+    at class starts, device carries mid-class, and uncommitted
+    device-born momentum on the very first call were three compiles
+    of the one train program.  Every dispatch now presents the same
+    placement."""
+
+    @pytest.mark.parametrize("loader_kw", [{}, {"max_resident_bytes": 0}])
+    def test_class_starts_and_mid_class_share_one_executable(
+            self, loader_kw):
+        w = build_workflow(mb=8, max_epochs=3, **loader_kw)
+        w.superstep = 2          # several dispatches per class
+        w.initialize(device=JaxDevice(platform="cpu"))
+        w.run()
+        fused = w.fused
+        dispatches_per_class = N_TRAIN // 8 // 2
+        assert dispatches_per_class > 1
+        assert fused._train_step._cache_size() == 1
+        assert fused._eval_step._cache_size() == 1
+        w.stop()
+
+
 class TestCohortMatrix:
     """PopulationTrainEngine: the full streaming x member-sharded
     grid returns bitwise-identical fitness vectors — the PR 18 lift

@@ -545,6 +545,47 @@ class TestFleetGrayFailures:
             router.close(kill=True)
 
 
+class TestOnePlatformPerFleet:
+    """PR 21: replicas say where they run in their own hello, and a
+    fleet answers from ONE platform — `-b auto` with more replicas
+    than chips (first claims the TPU, the rest land on XLA:CPU) is
+    refused, and a (re)spawn that comes up elsewhere is a failed
+    spawn, never a CPU stand-in under the same replica index."""
+
+    def test_mixed_platform_fleet_is_refused(self, monkeypatch):
+        from veles_tpu.serve import fleet, router
+
+        def spawn(self):
+            self.platform = "tpu" if self.idx == 0 else "cpu"
+            return {"pid": 1000 + self.idx, "platform": self.platform}
+
+        monkeypatch.setattr(fleet.Replica, "spawn", spawn)
+        with pytest.raises(RuntimeError, match="different platforms"):
+            router.FleetRouter({"m": "/no/such.vpkg"}, 2,
+                               backend="auto")
+
+    def test_respawn_on_another_platform_fails_the_spawn(
+            self, monkeypatch):
+        from veles_tpu.serve import fleet
+        platforms = iter(["tpu", "cpu"])
+
+        class Client:
+            def __init__(self, *a, **k):
+                self.hello = {"pid": 1, "platform": next(platforms),
+                              "device_kind": "fake"}
+                self.closed = False
+
+            def close(self, kill=False):
+                self.closed = True
+
+        monkeypatch.setattr(fleet, "HiveClient", Client)
+        r = fleet.Replica(0, {"m": "/no/such.vpkg"}, backend="auto")
+        assert r.spawn()["platform"] == "tpu" and r.healthy
+        with pytest.raises(RuntimeError, match="came up on 'cpu'"):
+            r.spawn()
+        assert r.client.closed and r.platform == "tpu"
+
+
 class TestFleetCliProtocol:
     """The real ``python -m veles_tpu --serve-fleet N`` front end: the
     hello line carries fleet/placement/canary state, requests answer
@@ -583,6 +624,9 @@ class TestFleetCliProtocol:
 
             hello = read_msg()
             assert hello["ready"] and hello["fleet"] == 2
+            # where the REPLICAS run, relayed by a parent that never
+            # loads jax itself
+            assert hello["platform"] == "cpu" and hello["device_kind"]
             assert set(hello["models"]) == {"alpha", "beta"}
             assert hello["canaries"]["beta"]["of"] == "alpha"
             assert len(hello["replica_pids"]) == 2
@@ -601,7 +645,7 @@ class TestFleetCliProtocol:
             st = read_msg()
             assert st["id"] == 2
             assert len(st["fleet"]["replicas"]) == 2
-            assert all(r["healthy"]
+            assert all(r["healthy"] and r["platform"] == "cpu"
                        for r in st["fleet"]["replicas"])
 
             proc.stdin.write(json.dumps({"op": "shutdown"}) + "\n")

@@ -260,24 +260,29 @@ class TestPoolCohortParity:
             assert np.allclose([mixed[0], mixed[2]], oracle[:2],
                                atol=1e-3)
 
-    def test_cli_ga_cohort_end_to_end(self, cohort_workflow):
-        """`python -m veles_tpu -b tpu-evaluator --optimize` with
-        cohort batching on: mixed-signature generations bucket and
-        complete with finite best fitness."""
-        import subprocess
-
-        import os
+    def test_optimizer_buckets_cohorts_through_the_pool(
+            self, cohort_workflow):
+        """run_optimizer's wiring (optimizer <-> evaluator pool, cohort
+        batching on) without its device policy: mixed-signature
+        generations bucket through the REAL pool and finish with a
+        finite best fitness.  The CLI spelling, `-b tpu-evaluator
+        --optimize`, needs a TPU since PR 21 and is driven on the chip
+        by tests_tpu/test_0_ga_parent.py."""
+        from veles_tpu.config import root
+        from veles_tpu.genetics import GeneticOptimizer, find_tunes
+        from veles_tpu.genetics.pool import ChipEvaluatorPool
+        from veles_tpu.launcher import apply_config_file
         wf, cfg = cohort_workflow
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(
-            __file__)))
-        res = subprocess.run(
-            [sys.executable, "-m", "veles_tpu", "-b", "tpu-evaluator",
-             "--optimize", "4:1", "--ga-workers", "2", wf, cfg],
-            capture_output=True, text=True, cwd=repo, timeout=600)
-        assert res.returncode == 0, res.stderr[-2000:]
-        assert "cohorts:" in res.stderr      # the batched path ran
-        out = json.loads(res.stdout.strip().splitlines()[-1])
-        assert np.isfinite(out["fitness"])
+        apply_config_file(cfg)
+        with ChipEvaluatorPool(self.serve_cmd(wf, cfg), workers=2,
+                               timeout=300) as pool:
+            opt = GeneticOptimizer(
+                pool.evaluate_one, find_tunes(root), population=4,
+                generations=1, evaluate_many=pool.evaluate_many,
+                evaluate_cohort=pool.evaluate_cohort)
+            _, fitness = opt.run()
+        assert opt.last_cohort_sizes         # the batched path ran
+        assert np.isfinite(fitness)
 
 
 class TestRbmCohortParity:

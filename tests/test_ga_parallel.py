@@ -104,7 +104,6 @@ class TestDeviceRacePolicy:
     def test_explicit_tpu_parallel_serializes(self):
         from veles_tpu.__main__ import _resolve_ga_execution
         assert _resolve_ga_execution("tpu", 4) == (1, "tpu")
-        assert _resolve_ga_execution("jax", 2) == (1, "jax")
 
     def test_cpu_and_single_worker_unchanged(self):
         from veles_tpu.__main__ import _resolve_ga_execution
@@ -200,19 +199,21 @@ class TestChipEvaluatorPool:
             assert fits[1] == float("inf")
             assert np.isfinite(fits[2])  # the queue kept draining
 
-    def test_cli_explicit_tpu_evaluator_mode(self, tuned_workflow):
-        """End to end through `python -m veles_tpu -b tpu-evaluator
-        --optimize`: one evaluator process, N>1 prep workers, finite
-        best fitness."""
+    def test_cli_explicit_tpu_evaluator_needs_a_tpu(self, tuned_workflow):
+        """`-b tpu-evaluator` names the chip: with none (this suite
+        pins XLA:CPU) the run FAILS, naming the missing device — it
+        neither trains on an XLA:CPU evaluator under the chip's name
+        nor drops to the cpu fan-out the way the announced `auto`
+        does."""
         wf, cfg = tuned_workflow
         res = subprocess.run(
             [sys.executable, "-m", "veles_tpu", "-b", "tpu-evaluator",
              "--optimize", "3:1", "--ga-workers", "2", wf, cfg],
             capture_output=True, text=True, cwd=REPO, timeout=600)
-        assert res.returncode == 0, res.stderr[-2000:]
-        assert "tpu-evaluator mode" in res.stderr
-        out = json.loads(res.stdout.strip().splitlines()[-1])
-        assert np.isfinite(out["fitness"])
+        assert res.returncode == 1, res.stderr[-2000:]
+        assert "needs its evaluator on a TPU" in res.stderr
+        assert "falling back" not in res.stderr
+        assert "fitness" not in res.stdout
 
     def test_cli_auto_falls_back_without_accelerator(
             self, tuned_workflow):
@@ -229,6 +230,27 @@ class TestChipEvaluatorPool:
         assert "falling back" in res.stderr
         out = json.loads(res.stdout.strip().splitlines()[-1])
         assert np.isfinite(out["fitness"])
+
+    def test_respawn_on_another_platform_is_refused(self):
+        """A replacement evaluator that finds the chip still held
+        comes up on XLA:CPU; finishing the run there under the same
+        fitness fields is the fallback PR 21 removed."""
+        from veles_tpu.genetics.pool import ChipEvaluatorPool
+        fake = ("import json, os, sys; print(json.dumps({'ready': True,"
+                " 'pid': os.getpid(), 'backend': 'jax', 'platform':"
+                " sys.argv[1]}), flush=True); sys.stdin.read()")
+        pool = ChipEvaluatorPool([sys.executable, "-c", fake, "tpu"],
+                                 workers=1, timeout=60)
+        try:
+            assert pool.start()["platform"] == "tpu"
+            pool._kill()
+            pool.worker_cmd[-1] = "cpu"
+            with pytest.raises(RuntimeError,
+                               match="respawned on 'cpu'"):
+                pool.start()
+            assert pool.platform == "tpu"
+        finally:
+            pool.close()
 
     def test_tpu_evaluator_without_optimize_rejected(self):
         res = subprocess.run(

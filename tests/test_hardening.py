@@ -1,6 +1,7 @@
 """Round-1 VERDICT next #8: loud op registry, idempotent multihost
 init, strict forge manifests."""
 
+import os
 import subprocess
 import sys
 
@@ -270,7 +271,6 @@ class TestForgeMarketplace:
                 req = Request(url + path, data=b"not a tarball")
                 with pytest.raises(HTTPError):
                     urlopen(req, timeout=10)
-            import os
             store = tmp_path / "store"
             assert not any(os.scandir(store)), \
                 "rejected uploads must leave nothing in the store"
@@ -279,51 +279,114 @@ class TestForgeMarketplace:
             t.join(timeout=5)
 
 
-class TestAtomicCompileCacheWrites:
-    """PR-3 hardening: jax's LRUCache.put (eviction disabled — the
-    default) writes persistent compile-cache entries with a bare
-    write_bytes, so concurrent same-key compiles tear the entry and
-    every later reader hard-aborts deserializing it (reproduced
-    deterministically on this box).  backends.py patches the write to
-    pid-tempfile + os.replace."""
+class _ConfigUpdates:
+    """Records jax.config.update calls instead of applying them, so a
+    test can say what the program WOULD set without touching this
+    process's real cache setting (or depending on an inherited one)."""
 
-    def test_patch_applied_and_atomic(self, tmp_path):
-        from veles_tpu.backends import _harden_compile_cache_writes
-        _harden_compile_cache_writes()      # idempotent
-        _harden_compile_cache_writes()      # second call = no-op
-        from jax._src import lru_cache as lc
-        assert getattr(lc.LRUCache.put, "_veles_atomic", False)
-        cache = lc.LRUCache(str(tmp_path / "c"), max_size=-1)
-        assert not cache.eviction_enabled   # the unlocked path
-        cache.put("k1", b"\x01" * 64)
-        suffix = lc._CACHE_SUFFIX
-        files = sorted(p.name for p in (tmp_path / "c").iterdir())
-        assert f"k1{suffix}" in files
-        assert not any(".tmp" in f for f in files)  # replace, not write
-        assert cache.get("k1") == b"\x01" * 64
-        # existing entries are never rewritten (jax's documented put
-        # semantics survive the patch)
-        cache.put("k1", b"\x02" * 64)
-        assert cache.get("k1") == b"\x01" * 64
+    def __init__(self, monkeypatch):
+        import jax
+        self.calls = []
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda name, value: self.calls.append((name, value)))
 
-    def test_cache_dir_is_era_namespaced(self):
-        """The default dir retires anything the old non-atomic writers
-        could have torn: version + `-aw` era tag."""
+    @property
+    def cache_dirs(self):
+        return [v for n, v in self.calls
+                if n == "jax_compilation_cache_dir"]
+
+
+class TestCompileCachePlacement:
+    """PR 21: the compile cache can be placed from outside.  With
+    JAX_COMPILATION_CACHE_DIR set the program keeps its cache there
+    and makes NO jax_compilation_cache_dir update of its own; unset,
+    a TPU engine uses the one fixed in-checkout directory and an
+    XLA:CPU engine none."""
+
+    REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def test_external_dir_wins_and_program_sets_nothing(
+            self, monkeypatch, tmp_path):
+        from veles_tpu import backends
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        updates = _ConfigUpdates(monkeypatch)
+        for platform in ("tpu", "cpu"):
+            backends._enable_persistent_compile_cache(platform)
+            assert backends.compile_cache_dir(platform) == str(tmp_path)
+        backends.JaxDevice(platform="cpu")   # the real call site
+        assert updates.calls == []
+
+    def test_unset_means_the_fixed_in_checkout_dir(self, monkeypatch):
+        from veles_tpu import backends
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        updates = _ConfigUpdates(monkeypatch)
+        backends._enable_persistent_compile_cache("tpu")
+        want = os.path.join(self.REPO, ".jax_cache")
+        assert updates.cache_dirs == [want]
+        assert backends.compile_cache_dir("tpu") == want
+        # nothing that varies between runs or installs is in the path
+        import jax
+        assert jax.__version__ not in os.path.relpath(want, self.REPO)
+
+    def test_cpu_engine_leaves_the_in_checkout_cache_off(
+            self, monkeypatch):
+        from veles_tpu import backends
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        updates = _ConfigUpdates(monkeypatch)
+        backends.JaxDevice(platform="cpu")
+        assert updates.calls == []
+        assert backends.compile_cache_dir("cpu") is None
+
+
+class TestDeviceRequestCannotHide:
+    """PR 21: a request for the chip is the chip or an exception, and
+    `auto` no longer turns a backend error into the numpy engine."""
+
+    def test_tpu_raises_on_a_cpu_only_process(self):
+        from veles_tpu.backends import make_device
+        with pytest.raises(RuntimeError, match="(?i)tpu"):
+            make_device("tpu")
+
+    def test_auto_propagates_a_backend_error(self, monkeypatch):
         import jax
 
-        from veles_tpu.backends import _compile_cache_default_dir
-        d = _compile_cache_default_dir()
-        assert d.endswith("-aw")
-        assert jax.__version__ in d
+        from veles_tpu.backends import make_device
 
-    def test_cpu_process_never_enables_the_cache(self):
-        """Faultline root cause: XLA:CPU executables round-tripped
-        through the persistent cache deserialize to numerically WRONG
-        programs (nondeterministic NaN trainings + the GPF/SIGABRT
-        family).  A CPU-backend process must leave the cache off."""
-        import jax
+        def boom(*a, **k):
+            raise RuntimeError("backend failed to initialize")
 
-        from veles_tpu.backends import _enable_persistent_compile_cache
-        assert jax.default_backend() == "cpu"   # the test suite's pin
-        _enable_persistent_compile_cache()
-        assert jax.config.jax_compilation_cache_dir in (None, "")
+        make_device.cache_clear()
+        monkeypatch.setattr(jax, "local_devices", boom)
+        try:
+            with pytest.raises(RuntimeError, match="failed to init"):
+                make_device("auto")
+        finally:
+            make_device.cache_clear()
+
+    def test_auto_announces_the_platform_it_resolved(self):
+        from veles_tpu.backends import make_device
+        d = make_device("auto").describe()
+        assert d["platform"] == "cpu" and d["device_kind"]
+        assert d["jax"] and d["jaxlib"] and d["libtpu"]
+
+    def test_jax_is_no_longer_a_backend_name(self):
+        from veles_tpu.backends import make_device
+        with pytest.raises(ValueError):
+            make_device("jax")
+
+    def test_tpu_without_reported_memory_limit_is_an_error(self):
+        from veles_tpu.backends import device_bytes_limit
+
+        class Dev:
+            def __init__(self, platform, stats):
+                self.platform, self._stats = platform, stats
+
+            def memory_stats(self):
+                return self._stats
+
+        assert device_bytes_limit(Dev("cpu", None)) is None
+        assert device_bytes_limit(
+            Dev("tpu", {"bytes_limit": 17})) == 17
+        with pytest.raises(RuntimeError, match="bytes_limit"):
+            device_bytes_limit(Dev("tpu", {}))

@@ -267,8 +267,6 @@ class TestLRN:
         numpy shifted-adds oracle, forward and backward, both real
         channel widths (96 aligns to no lane boundary; 256 to two)."""
         from veles_tpu.ops import lrn_pallas
-        if not lrn_pallas.available():
-            pytest.skip("no pallas in this jax build")
         for c, n in ((96, 5), (256, 5), (96, 4)):
             u = lrn_mod.LRNormalizer(alpha=3e-2, beta=0.75, n=n, k=2.0)
             x = RNG.standard_normal((16, 3, 3, c)).astype(np.float32)

@@ -1,0 +1,109 @@
+"""Compile for the TPU without a TPU (PR 21).
+
+libtpu is installed here, and ``jax.experimental.topologies`` hands out
+a compile-only v5e topology, so the programs the chip will be asked to
+run can be pushed through the real TPU compiler (and Mosaic, for the
+Pallas kernels) on a CPU-only box.  Nothing executes; a timing is not
+to be had this way.  Each case is its own subprocess because the
+failure this file exists for is a SIGSEGV inside the compiler, not a
+Python exception.
+"""
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+PRELUDE = """
+import sys
+import numpy as np
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                    platform="tpu")
+on_chip = SingleDeviceSharding(topo.devices[0])
+
+
+def spec(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+"""
+
+MEMBER_STACKED = PRELUDE + """
+from veles_tpu import prng
+from veles_tpu.backends import NumpyDevice
+from veles_tpu.datasets import synthetic_classification
+from veles_tpu.engine import core as engine_core
+from veles_tpu.loader import ArrayLoader
+from veles_tpu.ops.standard_workflow import StandardWorkflow
+
+members, hidden, classes = (int(a) for a in sys.argv[1:4])
+prng.seed_all(4242)
+train, valid, _ = synthetic_classification(
+    64, 16, (6, 6, 1), n_classes=classes, seed=5)
+w = StandardWorkflow(
+    loader_factory=lambda w: ArrayLoader(
+        w, train=train, valid=valid, minibatch_size=16, name="loader"),
+    layers=[{"type": "all2all_tanh",
+             "->": {"output_sample_shape": hidden},
+             "<-": {"learning_rate": 0.1}},
+            {"type": "softmax", "->": {"output_sample_shape": classes},
+             "<-": {"learning_rate": 0.1}}],
+    decision_config={"max_epochs": 2}, name="wf")
+w.initialize(device=NumpyDevice())
+params = {f.name: {k: spec((members,) + np.asarray(v).shape, jnp.float32)
+                   for k, v in f.gather_params().items()}
+          for f in w.forwards}
+fn = engine_core.build_mean_probs(w.forwards, members, jnp.bfloat16)
+jax.jit(fn).lower(params, spec((8, 6, 6, 1), jnp.float32)).compile()
+print("COMPILED")
+"""
+
+PALLAS_LRN = PRELUDE + """
+from veles_tpu.ops import lrn_pallas
+
+shape = tuple(int(a) for a in sys.argv[1:5])
+x = spec(shape, jnp.bfloat16)
+assert lrn_pallas.usable(shape, 5, 0.75)
+jax.jit(lambda x: lrn_pallas.lrn_fwd(x, 5, 2.0, 1e-4)).lower(x).compile()
+jax.jit(lambda x, e: lrn_pallas.lrn_bwd(x, e, 5, 2.0, 1e-4)
+        ).lower(x, x).compile()
+print("COMPILED")
+"""
+
+
+def _compile(src, *argv):
+    res = subprocess.run(
+        [sys.executable, "-c", src, *map(str, argv)], cwd=REPO,
+        capture_output=True, text=True, timeout=300)
+    assert res.returncode == 0 and "COMPILED" in res.stdout, \
+        f"rc={res.returncode}\n{res.stderr[-1500:]}"
+
+
+@pytest.mark.parametrize("members,hidden,classes", [
+    (3, 12, 3),        # chip_smoke's served package: the first sighting
+    (3, 128, 1000),    # a 3-member ensemble over AlexNet's head width
+    (3, 16, 4),
+])
+def test_member_stacked_softmax_head_compiles(members, hidden, classes):
+    """The served ensemble forward — softmax head under a stacked
+    member axis — at shapes for which libtpu 0.0.34's compiler
+    overflowed its stack fusing the softmax into the member-batched
+    matmul (every 3-member case tried), killing the Hive replica on
+    its first request.  engine/core.py build_member_forward now
+    splits the head at its logits."""
+    _compile(MEMBER_STACKED, members, hidden, classes)
+
+
+@pytest.mark.parametrize("shape", [(128, 55, 55, 96),
+                                   (128, 27, 27, 256)])
+def test_pallas_lrn_compiles_at_the_alexnet_shapes(shape):
+    """The opt-in Pallas LRN kernels through Mosaic, bf16, at the two
+    LRN shapes of AlexNet at its own minibatch — C = 96 as the full
+    (non-lane-multiple) channel axis, row tiles aligned to 8 (the
+    compiler takes them for bf16 too).  tests_tpu/ runs them on the
+    chip against the XLA form."""
+    _compile(PALLAS_LRN, *shape)

@@ -29,12 +29,16 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("files", nargs="+",
                    help="workflow.py [config.py ...]")
     p.add_argument("-b", "--backend", default="auto",
-                   choices=["auto", "tpu", "jax", "cpu", "numpy",
+                   choices=["auto", "tpu", "cpu", "numpy",
                             "tpu-evaluator"],
-                   help="execution backend (default: auto); "
+                   help="execution backend (default: auto = the TPU "
+                        "when JAX has one, else XLA:CPU, announced in "
+                        "the launcher line); 'tpu' is the TPU or an "
+                        "error, never another platform; "
                         "'tpu-evaluator' is --optimize-only: one "
                         "chip-owning evaluator process + host prep "
-                        "workers")
+                        "workers, and fails when that evaluator is "
+                        "not on a TPU")
     p.add_argument("-s", "--seed", type=int, default=1234)
     p.add_argument("--snapshot", default=None,
                    help="resume from a snapshot file")
@@ -237,7 +241,7 @@ def _ga_worker_count(args) -> int:
         return max(1, args.ga_workers)
     # cpu/numpy workers are evaluation subprocesses; auto/tpu-evaluator
     # workers are prep threads for the single chip-owning evaluator —
-    # both parallelize across host cores.  Explicit tpu/jax serializes
+    # both parallelize across host cores.  Explicit tpu serializes
     # (the chip admits one client and the user asked for direct mode).
     if args.backend in ("numpy", "cpu", "auto", "tpu-evaluator"):
         import os
@@ -258,12 +262,13 @@ def _resolve_ga_execution(backend: str, workers: int):
       (``--ga-cohort``; run_optimizer wires evaluate_cohort).  When
       the evaluator's hello reports no accelerator, run_optimizer
       falls back to the classic ``cpu`` subprocess fan-out;
-    - explicit ``tpu-evaluator`` -> the same, honored even without an
-      accelerator (the evaluator then runs genomes on XLA:CPU,
-      still one process, compile caches warm across genomes);
-    - explicit ``tpu``/``jax`` + parallel workers -> serialized to 1
-      direct worker (honors the per-genome-subprocess choice; the
-      chip admits one client);
+    - explicit ``tpu-evaluator`` -> the same, on a TPU or not at all:
+      the evaluator asks for ``-b tpu`` and run_optimizer fails the
+      run when its hello is not on one (no CPU stand-in under the
+      chip's name);
+    - explicit ``tpu`` + parallel workers -> serialized to 1 direct
+      worker (honors the per-genome-subprocess choice; the chip admits
+      one client);
     - ``cpu``/``numpy`` parallelize freely.
     """
     if backend in ("auto", "tpu-evaluator"):
@@ -430,10 +435,16 @@ def run_optimizer(args, workflow_file: str, config_files, overrides) \
     pool = None
     if worker_backend == "tpu-evaluator":
         from veles_tpu.genetics.pool import ChipEvaluatorPool
+
+        # the evaluator child is the ONLY process that probes the
+        # device: `tpu-evaluator` asks it for the chip, `auto` for
+        # whatever JAX has there
+        strict = args.backend == "tpu-evaluator"
         serve_cmd = [sys.executable, "-m",
                      "veles_tpu.genetics.worker", "--serve",
                      workflow_file, *config_files, *overrides,
-                     "-b", "auto", "-s", str(args.seed),
+                     "-b", "tpu" if strict else "auto",
+                     "-s", str(args.seed),
                      "--cohort", str(max(0, args.ga_cohort))]
         if args.verbose:
             serve_cmd.append("-v")
@@ -444,16 +455,25 @@ def run_optimizer(args, workflow_file: str, config_files, overrides) \
             seed=args.seed)
         try:
             hello = pool.start()
-        except Exception as e:  # noqa: BLE001 — fall back, not die
-            print(f"--optimize: chip evaluator failed to start ({e})",
-                  file=sys.stderr)
+        except (RuntimeError, OSError) as e:
             pool.close()
             pool = None
-            hello = None
-        if pool is not None and not pool.is_accelerator \
-                and args.backend == "auto":
-            # no chip behind `auto`: the classic CPU fan-out
-            # parallelizes better than one XLA:CPU evaluator process
+            if strict:
+                # asked for the chip by name: no evaluator on a TPU
+                # (none there, or another process holds it) is the
+                # answer, not a CPU run under the same flag
+                print(f"--optimize: -b tpu-evaluator needs its "
+                      f"evaluator on a TPU and it did not come up "
+                      f"({e})", file=sys.stderr)
+                finish_preempt()
+                return 1
+            print(f"--optimize: chip evaluator failed to start ({e})",
+                  file=sys.stderr)
+        if pool is not None and not pool.is_accelerator:
+            # no chip behind `auto` (strict mode cannot get here: its
+            # evaluator raises instead of saying hello from a CPU):
+            # the classic CPU fan-out parallelizes better than one
+            # XLA:CPU evaluator process
             print(f"--optimize: no accelerator visible (evaluator "
                   f"landed on {pool.platform}) — falling back to "
                   f"{workers} cpu evaluation subprocesses",

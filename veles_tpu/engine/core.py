@@ -199,6 +199,7 @@ def build_member_forward(forwards, compute_dtype):
     """One member's pure inference chain (no rng, f32 output) — the
     body vmapped over a stacked member axis by the ensemble
     dispatchers and the shadow scorer."""
+    import jax
     import jax.numpy as jnp
 
     mixed = _not_f32(compute_dtype)
@@ -207,8 +208,21 @@ def build_member_forward(forwards, compute_dtype):
         if mixed:
             x = x.astype(compute_dtype)
         for f in forwards:
-            x, _ = f.apply_fwd(params[f.name], x, rng=None,
-                               train=False)
+            if getattr(f, "activation_mode", None) == "softmax":
+                # a softmax head under the stacked member axis is
+                # split at its logits: libtpu 0.0.34's compiler
+                # overflows its stack (SIGSEGV, no Python error)
+                # fusing the softmax reduction into the
+                # member-batched matmul for some member counts —
+                # every 3-member head tried, 3 to 1000 classes (PR 21;
+                # tests/test_tpu_compile.py compiles them without a
+                # chip).  The barrier keeps the two apart; the
+                # single-model train path is untouched.
+                x = f.activation(jax.lax.optimization_barrier(
+                    f.pre_activation(params[f.name], x)))
+            else:
+                x, _ = f.apply_fwd(params[f.name], x, rng=None,
+                                   train=False)
         return x.astype(jnp.float32)
 
     return member_forward

@@ -189,6 +189,17 @@ class ChipEvaluatorPool(Logger):
             self._kill()
             raise RuntimeError(
                 f"evaluator did not come up: {hello!r}")
+        was = (self.hello or {}).get("platform")
+        if was is not None and hello.get("platform") != was:
+            # a respawn that found the chip still held (or gone) must
+            # not finish the run on another platform under the same
+            # fitness fields
+            self._kill()
+            raise RuntimeError(
+                f"evaluator respawned on {hello.get('platform')!r} "
+                f"but this run began on {was!r} (chip still held by "
+                f"the previous evaluator pid "
+                f"{self.hello.get('pid')}?)")
         self.hello = hello
         self.info("chip evaluator up: pid %s on %s (%s)",
                   hello["pid"], hello["platform"], hello["backend"])

@@ -137,14 +137,12 @@ def _hbm_cohort_cap(workflow, requested: int,
             if v:
                 param_bytes += int(np.prod(v.shape)) * 4
     per_member = max(param_bytes * 4, 1)
+    from veles_tpu.backends import device_bytes_limit
+
     budget = None
     jdev = getattr(workflow.fused.device, "jax_device", None)
     if jdev is not None:
-        try:
-            budget = int((jdev.memory_stats() or {})
-                         .get("bytes_limit", 0)) or None
-        except Exception:  # noqa: BLE001 — CPU backends report none
-            budget = None
+        budget = device_bytes_limit(jdev)
     if budget is None:
         budget = int(os.environ.get("VELES_TPU_GA_HBM_BUDGET",
                                     8 << 30))
@@ -206,8 +204,11 @@ def _train_cohort_chunk(create, pristine, config_files, overrides,
             # member-sharded cohort (Lattice): the engine shards its
             # stacked member axis over an N-device mesh and keeps the
             # (small, GA-scale) dataset replicated on it
+            import jax
+
             from veles_tpu.parallel import make_mesh
-            mesh = make_mesh(dp)
+            mesh = make_mesh(
+                dp, devices=jax.devices(launcher.device.platform))
         engine = PopulationTrainEngine(w, rates, decays, mesh=mesh)
         return [float(f) for f in engine.run()]
     finally:

@@ -493,16 +493,6 @@ TPU_SYNTH_CACHE = _knob(
     "Cache large synthetic datasets in-process across loader "
     "constructions (bench/ablation runs).")
 
-# -- XLA compile cache -------------------------------------------------
-
-TPU_NO_COMPILE_CACHE = _knob(
-    "VELES_TPU_NO_COMPILE_CACHE", False, flag,
-    "Disable the persistent XLA compile cache entirely.")
-TPU_COMPILE_CACHE_DIR = _knob(
-    "VELES_TPU_COMPILE_CACHE_DIR", "", str,
-    "Override the era-namespaced default directory of the persistent "
-    "XLA compile cache.")
-
 
 def names() -> frozenset:
     """Every declared knob name (the env-registry rule's whitelist)."""

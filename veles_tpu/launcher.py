@@ -63,8 +63,12 @@ class Launcher(Logger):
         if multihost:
             init_multihost()
         self.device: Device = make_device(backend)
-        self.info("launcher: backend=%s device=%r mode=%s",
-                  backend, self.device, self.mode)
+        # the resolved platform is announced, never assumed: `auto`
+        # may be XLA:CPU, and a run record must say which
+        self.info("launcher: backend=%s device=%r mode=%s %s",
+                  backend, self.device, self.mode,
+                  " ".join(f"{k}={v}" for k, v in
+                           self.device.describe().items()))
 
     @property
     def mode(self) -> str:
@@ -101,7 +105,7 @@ class Launcher(Logger):
             from veles_tpu.parallel import DataParallel
             if not self.device.is_jax:
                 raise ValueError("--dp requires a jax backend "
-                                 "(tpu/jax/cpu), not numpy")
+                                 "(tpu/cpu), not numpy")
             # mesh over the devices of the SELECTED backend platform —
             # jax.devices() alone would pick the default platform even
             # when the user asked for -b cpu
@@ -144,7 +148,7 @@ class Launcher(Logger):
                         raise ValueError(
                             "slave mode computes jobs with the fused "
                             "jitted step — use a jax backend (-b "
-                            "tpu/jax/cpu), not numpy")
+                            "tpu/cpu), not numpy")
                     from veles_tpu.client import SlaveClient
                     SlaveClient(self.workflow,
                                 self.master_address).serve()
@@ -322,11 +326,8 @@ class Launcher(Logger):
     def _kv_client(self):
         """The jax distributed KV client, or None outside a real
         multi-process run."""
-        try:
-            from jax._src.distributed import global_state
-            return global_state.client
-        except Exception:  # noqa: BLE001 — no distributed context
-            return None
+        from jax._src.distributed import global_state
+        return global_state.client
 
     def final_snapshot(self, reason: str) -> Optional[str]:
         """Best-effort final snapshot for a stop/abort path; None when
@@ -429,16 +430,13 @@ class Launcher(Logger):
         ``done`` marker), or None when not in a real multi-process
         run."""
         import threading
-        try:
-            import jax
-            from jax._src.distributed import global_state
-            client = global_state.client
-            if client is None or jax.process_count() <= 1:
-                return None
-            me = jax.process_index()
-            peers = [p for p in range(jax.process_count()) if p != me]
-        except Exception:  # noqa: BLE001 — no distributed context
+
+        import jax
+        client = self._kv_client()
+        if client is None or jax.process_count() <= 1:
             return None
+        me = jax.process_index()
+        peers = [p for p in range(jax.process_count()) if p != me]
         interval = float(os.environ.get("VELES_MULTIHOST_HEARTBEAT",
                                         "2.0"))
         deadline = float(os.environ.get("VELES_MULTIHOST_DEADLINE",
@@ -610,12 +608,8 @@ def init_multihost() -> None:
     # jax.process_count() would itself initialize XLA, after which
     # distributed.initialize() unconditionally raises.  The distributed
     # client handle is the only side-effect-free signal.
-    try:
-        from jax._src.distributed import global_state
-        already = global_state.client is not None
-    except Exception:
-        already = False
-    if not already:
+    from jax._src.distributed import global_state
+    if global_state.client is None:
         # The env-var contract this docstring promises is honored HERE:
         # this jax's bare initialize() only auto-detects known cluster
         # environments (SLURM, TPU pods) and raises "Number of
@@ -625,16 +619,8 @@ def init_multihost() -> None:
         coord = os.environ.get("JAX_COORDINATOR_ADDRESS") or None
         nproc = os.environ.get("JAX_NUM_PROCESSES")
         pid = os.environ.get("JAX_PROCESS_ID")
-        if "cpu" in (os.environ.get("JAX_PLATFORMS") or "").lower():
-            # cross-process collectives on the CPU backend need the
-            # gloo transport selected BEFORE the backend initializes;
-            # without it every psum dies in the partitioner.  Config
-            # knob present on this jax; guarded for future removal.
-            try:
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  "gloo")
-            except Exception:  # noqa: BLE001 — newer jax: gloo default
-                pass
+        # (cross-process collectives on the CPU backend ride gloo,
+        # jax 0.9.0's default jax_cpu_collectives_implementation)
         try:
             jax.distributed.initialize(
                 coordinator_address=coord,

@@ -147,8 +147,7 @@ def make_sharded_row_gather(mesh):
     machinery (np.resize padding + validity masks) never points at
     it."""
     import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental.shard_map import shard_map
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec
 
     axis = mesh.axis_names[0]
@@ -177,7 +176,7 @@ def make_sharded_row_gather(mesh):
             local, mesh=mesh,
             in_specs=(PartitionSpec(),) + (spec,) * len(stores),
             out_specs=(PartitionSpec(),) * len(stores),
-            check_rep=False)(indices, *stores)
+            check_vma=False)(indices, *stores)
         return out[0] if len(stores) == 1 else out
 
     return gather

@@ -12,8 +12,7 @@ would dispatch dozens of tiny XLA computations per step and lose badly
 
 — is traced into ONE jitted function, and a ``lax.scan`` over up to
 ``loader.superstep`` same-class minibatches runs MANY iterations per
-device dispatch (amortizing per-execute latency, which dominates on
-tunneled/remote TPUs).  Metrics and the confusion matrix accumulate
+device dispatch (amortizing per-execute latency).  Metrics and the confusion matrix accumulate
 ON DEVICE in donated carry buffers; the host fetches 12 bytes once per
 class end instead of 3 scalars per minibatch.  Matmuls/convs run in
 the device's ``compute_dtype`` (bfloat16 on TPU — the MXU's native
@@ -200,7 +199,7 @@ class FusedStepRunner(AcceleratedUnit):
         want_confusion = self._want_confusion()
         seed = prng.get(self.rng_stream).seed
         cd = self._resolved_dtype()
-        out_shape = tuple(forwards[-1].output.shape)
+        out_shape = self._out_shape = tuple(forwards[-1].output.shape)
         streaming = self.streaming
         if self._core is not None:    # invalidate_trace rebuild: the
             self._core.release()      # old ledger entry must not leak
@@ -458,6 +457,17 @@ class FusedStepRunner(AcceleratedUnit):
             self._build_steps()
         if self._acc is None:
             self._acc, self._conf = self._fresh_acc()
+        if self.mesh is None:
+            # every dispatch presents the SAME argument placement: jit
+            # lowers — and XLA compiles — one executable per pattern
+            # of committed/uncommitted inputs, so host-fresh metric
+            # carries at class starts (and device-born momentum zeros,
+            # see JaxDevice.zeros) used to cost up to three compiles
+            # of the one train program.  PR 21, on the chip: the
+            # SECOND dispatch of a cold AlexNet run re-compiled for
+            # 23 s.  A committed device array passes through as is.
+            self._acc = self._core.put(self._acc)
+            self._conf = self._core.put(self._conf)
         indices, mask = self._superstep_arrays()
         k = indices.shape[0]
         train = ld.minibatch_class == TRAIN
@@ -492,9 +502,17 @@ class FusedStepRunner(AcceleratedUnit):
             self._dispatch_seen.add(kind)
             telemetry.gauge(
                 f"fused.first_{kind}_dispatch_seconds").set(dt)
+            # the run record's "where and what": the device as JAX
+            # reports it and the static step shape, next to the one
+            # dispatch that traced + compiled (or loaded) the program
             telemetry.event(events.EV_FUSED_FIRST_DISPATCH, kind=kind,
                             seconds=round(dt, 4),
-                            streaming=bool(self.streaming))
+                            streaming=bool(self.streaming),
+                            minibatches=k,
+                            batch_shape=list(
+                                self.loader.minibatch_data.shape),
+                            output_shape=list(self._out_shape),
+                            **self.device.describe())
         else:
             telemetry.histogram(
                 f"fused.{kind}_dispatch_seconds").record(dt)
@@ -533,7 +551,7 @@ class FusedStepRunner(AcceleratedUnit):
 
         The upload is an explicit double-buffered ``device_put``: at
         most two superstep batches are in flight, so a device that
-        falls behind the host (or a slow tunnel that falls behind the
+        falls behind the host (or a link that falls behind the
         dispatch loop) back-pressures the loop instead of piling
         unsent host batches into RAM without bound."""
         import time
@@ -686,9 +704,28 @@ class FusedStepRunner(AcceleratedUnit):
                 events.EV_FUSED_SUMMARY, images=images,
                 images_per_sec_wall=round(rate, 2),
                 mfu=round(u, 5) if u is not None else None,
-                streaming=bool(self.streaming))
+                streaming=bool(self.streaming),
+                device_memory=self._device_memory())
         except Exception:  # noqa: BLE001 — summary is best-effort
             pass
+
+    def _device_memory(self) -> List[Dict[str, Any]]:
+        """Per-device allocator readings for every device this runner
+        drives (all mesh devices under --dp): live bytes show which
+        chips hold the params, peak above live shows which executed.
+        XLA:CPU reports no stats and yields an empty list."""
+        devs = list(self.mesh.devices.flat) if self.mesh is not None \
+            else [self.device.jax_device]
+        rows = []
+        for d in devs:
+            stats = d.memory_stats()
+            if stats:
+                rows.append({
+                    "id": int(d.id),
+                    "bytes_in_use": int(stats["bytes_in_use"]),
+                    "peak_bytes_in_use":
+                        int(stats["peak_bytes_in_use"])})
+        return rows
 
     def release_device_state(self, sync: bool = False) -> None:
         """Drop every device buffer this runner (and its forwards)

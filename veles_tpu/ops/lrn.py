@@ -154,8 +154,7 @@ class LRNormalizer(ForwardUnit):
             # the fused runner's force_xla loop never runs
             return False
         from veles_tpu.ops import lrn_pallas
-        return lrn_pallas.available() and \
-            lrn_pallas.usable(x.shape, self.n, self.beta)
+        return lrn_pallas.usable(x.shape, self.n, self.beta)
 
     def apply_fwd(self, params, x, rng=None, train=True):
         """Residual policy: pallas path and the recompute variant save

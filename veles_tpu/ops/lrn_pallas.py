@@ -36,15 +36,6 @@ from typing import Any, Optional
 import numpy as np
 
 
-def available() -> bool:
-    try:
-        from jax.experimental import pallas  # noqa: F401
-        from jax.experimental.pallas import tpu  # noqa: F401
-        return True
-    except Exception:  # noqa: BLE001 — no pallas on this jax build
-        return False
-
-
 def _band(c: int, n: int, transpose: bool = False) -> np.ndarray:
     """The window matrix — shared single source with the XLA form
     (ops/lrn.py band_matrix; the parity-sensitive tap convention must
@@ -60,8 +51,10 @@ _TILE_BUDGET = 512 * 1024
 
 def _tile_rows(n_rows: int, c: int) -> Optional[int]:
     """Rows per VMEM tile: a divisor of n_rows, multiple of 8 (f32
-    sublane), sized so the kernel's ~6 live f32 (rows, C) buffers stay
-    well under VMEM.  None = no usable divisor; caller falls back."""
+    sublane; Mosaic also takes such tiles for the bf16 the hot path
+    feeds — PR 21 compiled 968 = 8 x 121 rows at 55x55x96), sized so
+    the kernel's ~6 live f32 (rows, C) buffers stay well under VMEM.
+    None = no usable divisor; caller falls back."""
     budget = max(8, _TILE_BUDGET // (4 * c) // 8 * 8)
     t = min(n_rows, budget)
     t -= t % 8

@@ -100,6 +100,12 @@ class MeshJaxDevice(JaxDevice):
         fn = self._zeros_sharded_fn if sharded else self._zeros_fn
         return fn(tuple(int(s) for s in shape), dtype)
 
+    def synchronize(self) -> None:
+        # a replicated scalar lands on EVERY mesh device, so the wait
+        # covers them all (the single-chip form waits on chip 0 only)
+        from veles_tpu.engine import core as engine_core
+        (engine_core.put(0.0, self._repl) + 0).block_until_ready()
+
     def __repr__(self) -> str:
         n = self.mesh.devices.size
         return f"<MeshJaxDevice {n}x{self.platform} axes={self.mesh.axis_names}>"

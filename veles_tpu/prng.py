@@ -9,6 +9,11 @@ host-side work: shuffling, weight init on the numpy backend) and a JAX
 PRNG key chain (for traced stochastic ops: dropout, stochastic pooling).
 ``stream.next_key()`` splits deterministically, and the key counter is
 part of snapshot state so resume continues the exact stream.
+
+``jax`` is imported inside the key methods, not here: the fleet
+router, the GA parent and the supervisor reach this module through
+``veles_tpu.launcher`` and must stay off the device — a process that
+never loads jax cannot take the chip from the child that needs it.
 """
 
 from __future__ import annotations
@@ -16,8 +21,6 @@ from __future__ import annotations
 from typing import Dict, Optional, Tuple
 
 import numpy as np
-
-import jax
 
 
 class RandomStream:
@@ -27,15 +30,17 @@ class RandomStream:
         self.numpy: np.random.Generator = np.random.default_rng(seed)
         self._key_counter = 0
 
-    def next_key(self) -> jax.Array:
+    def next_key(self) -> "jax.Array":
         """Deterministic JAX key #N of this stream (N increments)."""
+        import jax
         k = jax.random.fold_in(jax.random.key(self.seed), self._key_counter)
         self._key_counter += 1
         return k
 
-    def key_at(self, counter: int) -> jax.Array:
+    def key_at(self, counter: int) -> "jax.Array":
         """Key for an explicit counter (used inside jitted steps where the
         counter is threaded as traced state)."""
+        import jax
         return jax.random.fold_in(jax.random.key(self.seed), counter)
 
     # -- snapshot support ---------------------------------------------

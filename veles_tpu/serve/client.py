@@ -85,7 +85,12 @@ class HiveClient:
             # so the checksum-verified package unpack is warm
             cmd += ["--install-dir", install_dir]
         run_env = dict(os.environ)
-        run_env.setdefault("JAX_PLATFORMS", "cpu")
+        if backend == "cpu":
+            # a CPU replica never even probes a chip the machine may
+            # have (its parent, or a sibling, may own it); every other
+            # backend sees the environment as the operator left it —
+            # a default here would put `-b tpu` replicas on the CPU
+            run_env["JAX_PLATFORMS"] = "cpu"
         if env:
             run_env.update(env)
         if mesh and mesh > 1 and \

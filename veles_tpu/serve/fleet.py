@@ -139,6 +139,11 @@ class Replica(Logger):
         #: None until the first spawn
         self.devices = 1
         self.capacity_bytes: Optional[int] = None
+        #: where the replica runs, from its OWN hello (None until the
+        #: first spawn; preset by the router for an elastic member) —
+        #: a later spawn may not change it
+        self.platform: Optional[str] = None
+        self.device_kind: Optional[str] = None
         self.max_batch = max_batch
         #: rows one dispatch can drain — the admission estimate's
         #: queue divisor (capacity, NOT the recent fill: dividing by
@@ -208,7 +213,19 @@ class Replica(Logger):
             env=self.env, cwd=self.cwd, mesh=self.mesh,
             start_timeout=self.start_timeout)
         hello = self.client.hello or {}
+        platform = hello.get("platform")
+        if self.platform is not None and platform != self.platform:
+            # the chip may still be held by the corpse for a moment: a
+            # (re)spawn that came up elsewhere is a FAILED spawn (the
+            # monitor retries with backoff), never a CPU stand-in
+            # answering under the same replica index
+            self.client.close(kill=True)
+            raise RuntimeError(
+                f"replica {self.idx} came up on {platform!r}; its "
+                f"fleet serves on {self.platform!r}")
         with self._lock:
+            self.platform = platform
+            self.device_kind = hello.get("device_kind")
             # capacity comes from the replica's OWN hello — the probed
             # per-device budget on its real device, not a router-side
             # assumption (a mixed fleet's whole point)

@@ -27,8 +27,8 @@ lines:
 
 Protocol lines (stdout; all writes serialized under one lock):
 
-    {"ready": true, "pid", "platform", "backend", "models": {...},
-     "max_batch", "max_wait_ms"}                      -- hello
+    {"ready": true, "pid", "platform", "device_kind", "backend",
+     "models": {...}, "max_batch", "max_wait_ms"}     -- hello
     {"hb": n, "pid"}                                  -- heartbeat
     {"id", "model", "pred": [...], "probs": [[...]],
      "rows_n": n, "crc": c}                           -- response
@@ -230,27 +230,30 @@ def main(argv: Optional[List[str]] = None) -> int:
             return 2
         specs.append((name, path))
 
-    if args.mesh and args.mesh > 1:
-        # the Prism arm: this replica OWNS an N-device mesh — one
-        # MeshJaxDevice through the same make_device seam, so every
-        # downstream consumer (residency, engines, batcher) sees a
-        # device that happens to replicate rows and shard members
-        from veles_tpu.parallel.data_parallel import MeshJaxDevice
-        from veles_tpu.parallel.mesh import make_mesh
-        try:
-            device = MeshJaxDevice(make_mesh(int(args.mesh)))
-        except ValueError as e:
-            print(f"--serve-models --mesh {args.mesh}: {e}",
-                  file=sys.stderr)
-            return 2
-    else:
-        device = make_device(args.backend)
-    platform = getattr(device, "platform", device.backend_name)
-    if not getattr(device, "is_jax", False):
+    device = make_device(args.backend)
+    if not device.is_jax:
         print("--serve-models needs a jax device (TPU or XLA:CPU); "
               "-b numpy has no vmapped serving engine",
               file=sys.stderr)
         return 2
+    if args.mesh and args.mesh > 1:
+        # the Prism arm: this replica OWNS an N-device mesh over the
+        # devices of the REQUESTED backend's platform (jax.devices()
+        # alone is the default platform, whatever -b said) — every
+        # downstream consumer (residency, engines, batcher) sees a
+        # device that happens to replicate rows and shard members
+        import jax
+
+        from veles_tpu.parallel.data_parallel import MeshJaxDevice
+        from veles_tpu.parallel.mesh import make_mesh
+        try:
+            device = MeshJaxDevice(make_mesh(
+                int(args.mesh), devices=jax.devices(device.platform)))
+        except ValueError as e:
+            print(f"--serve-models --mesh {args.mesh}: {e}",
+                  file=sys.stderr)
+            return 2
+    platform = device.platform
     residency = ResidencyManager(
         device, budget_bytes=args.hbm_budget or None,
         max_batch=max(1, args.max_batch),
@@ -290,6 +293,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     hello = {
         "ready": True, "pid": os.getpid(),
         "backend": device.backend_name, "platform": platform,
+        "device_kind": device.jax_device.device_kind,
         "max_batch": residency.max_batch,
         "max_wait_ms": residency.max_wait_s * 1000.0,
         "online": learner is not None,
@@ -308,9 +312,9 @@ def main(argv: Optional[List[str]] = None) -> int:
             for m in residency.models.values()},
     }
     telemetry.event(events.EV_SERVE_READY, pid=os.getpid(),
-                    platform=platform,
                     models=sorted(residency.models),
-                    max_batch=residency.max_batch)
+                    max_batch=residency.max_batch,
+                    **device.describe())
     emit(hello)
     telemetry.flush()
 

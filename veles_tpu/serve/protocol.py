@@ -64,6 +64,7 @@ K_READY = _k("ready")
 K_PID = _k("pid")
 K_BACKEND = _k("backend")
 K_PLATFORM = _k("platform")
+K_DEVICE_KIND = _k("device_kind")  #: as jax reports the replica's chip
 K_MODELS = _k("models")
 K_MAX_BATCH = _k("max_batch")
 K_MAX_WAIT_MS = _k("max_wait_ms")

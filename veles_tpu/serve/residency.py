@@ -117,14 +117,11 @@ class ResidencyManager(Logger):
             return unified
         jdev = getattr(device, "jax_device", None)
         if jdev is not None:
-            try:
-                limit = int((jdev.memory_stats() or {})
-                            .get("bytes_limit", 0))
-                if limit:
-                    # half held back for activations + micro-batches
-                    return limit // 2
-            except Exception:  # noqa: BLE001 — CPU backends report none
-                pass
+            from veles_tpu.backends import device_bytes_limit
+            limit = device_bytes_limit(jdev)
+            if limit:
+                # half held back for activations + micro-batches
+                return limit // 2
         return int(knobs.get(knobs.SERVE_HBM_BUDGET))
 
     # -- registry ------------------------------------------------------

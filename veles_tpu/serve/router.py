@@ -190,6 +190,21 @@ class FleetRouter(Logger):
             self.replicas, heartbeat_deadline=heartbeat_deadline,
             respawn_backoff=respawn_backoff)
         hellos = self.fleet.start()
+        platforms = {r.platform for r in self.replicas}
+        if len(platforms) > 1:
+            # e.g. `-b auto` with more replicas than chips: the first
+            # claims the TPU and the rest land on XLA:CPU.  One fleet
+            # answers from one platform; say so instead of serving a
+            # mixed one under the same model names
+            self.fleet.close(kill=True)
+            raise RuntimeError(
+                f"replicas came up on different platforms "
+                f"{sorted(map(str, platforms))} — one process per "
+                f"chip; a fleet of "
+                f"{self.n_replicas} needs {self.n_replicas} devices")
+        #: the one platform / device kind every replica reported
+        self.platform = platforms.pop()
+        self.device_kind = self.replicas[0].device_kind
         self.hello_models = hellos[0].get("models", {})
 
         #: routing affinity: hot models on all replicas, long tail
@@ -752,6 +767,9 @@ class FleetRouter(Logger):
             self._next_idx += 1
             warm = self._warm_dirs.pop() if self._warm_dirs else None
         r = self._make_replica(idx, install_dir=warm)
+        # an elastic member joins the fleet's ONE platform or its
+        # spawn fails (Replica.spawn checks the hello against this)
+        r.platform = self.platform
         try:
             hello = r.spawn()
         except Exception as e:  # noqa: BLE001 — a failed scale-up
@@ -934,6 +952,7 @@ class FleetRouter(Logger):
                  "healthy": r.healthy, "inflight": r.inflight,
                  "routed": self.routed_counts()[r.idx],
                  "deaths": r.deaths,
+                 "platform": r.platform,
                  "devices": r.devices,
                  "device_budget": (r.capacity_bytes // r.devices
                                    if r.capacity_bytes else None),
@@ -1134,6 +1153,9 @@ def main(argv: Optional[List[str]] = None) -> int:
             print(json.dumps(obj), flush=True)
 
     emit({"ready": True, "pid": os.getpid(),
+          # where the REPLICAS run (this parent never loads jax)
+          "platform": router.platform,
+          "device_kind": router.device_kind,
           "fleet": args.replicas,
           "replica_pids": [r.pid for r in router.replicas],
           "models": router.hello_models,
