@@ -319,7 +319,7 @@ def train_stage(name, dp, cap):
         r["peak_bytes_in_use"] > r["bytes_in_use"] > 0 for r in memory),
         f"{name}: not every device holds bytes and has executed: "
         f"{memory}")
-    steady = h.get("fused.train_dispatch_seconds", {})
+    steady = h.get("fused.train_submit", {})
     rec = {
         "stage": name, "platform": facts["platform"],
         "device_kind": facts["device_kind"],
@@ -333,9 +333,9 @@ def train_stage(name, dp, cap):
         "dispatches": int(c["fused.dispatches"]),
         "losses": [[e, k, round(v, 4)] for e, k, v in losses],
         "setup_first_train_dispatch_s":
-            round(g["fused.first_train_dispatch_seconds"], 3),
+            round(g["fused.first_train_submit_seconds"], 3),
         "setup_first_eval_dispatch_s":
-            round(g["fused.first_eval_dispatch_seconds"], 3),
+            round(g["fused.first_eval_submit_seconds"], 3),
         "steady_train_dispatch_submit_s": {
             "count": steady.get("count", 0),
             "p50": round(steady.get("p50", 0.0), 4)},

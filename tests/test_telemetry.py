@@ -348,7 +348,8 @@ class TestPoolGenerationReset:
 
 # -- fused runner telemetry -------------------------------------------
 
-def _tiny_workflow(n_train=160, max_epochs=2):
+def _tiny_workflow(n_train=160, max_epochs=2, validation=True,
+                   stream=False):
     from veles_tpu import prng
     from veles_tpu.datasets import synthetic_classification
     from veles_tpu.loader import ArrayLoader
@@ -357,10 +358,11 @@ def _tiny_workflow(n_train=160, max_epochs=2):
     train, valid, _ = synthetic_classification(
         n_train, 40, (8, 8, 1), n_classes=4, seed=7)
     gd = {"learning_rate": 0.1}
+    kw = {"max_resident_bytes": 0} if stream else {}
     return StandardWorkflow(
         loader_factory=lambda w: ArrayLoader(
-            w, train=train, valid=valid, minibatch_size=20,
-            name="loader"),
+            w, train=train, valid=valid if validation else None,
+            minibatch_size=20, name="loader", **kw),
         layers=[
             {"type": "all2all_tanh", "->": {"output_sample_shape": 16},
              "<-": gd},
@@ -388,13 +390,13 @@ class TestFusedTelemetry:
         # gauge; the steady-state histogram holds the REST and its
         # p50/p99 are finite and ordered
         g = snap["gauges"]
-        assert g["fused.first_train_dispatch_seconds"] > 0
-        h = snap["histograms"]["fused.train_dispatch_seconds"]
+        assert g["fused.first_train_submit_seconds"] > 0
+        h = snap["histograms"]["fused.train_submit"]
         assert h["count"] > 0
         assert 0 < h["p50"] <= h["p99"] <= h["max"]
         # the first (compile) sample is far above the steady p99 on
         # any jitted backend
-        assert g["fused.first_train_dispatch_seconds"] > h["p99"]
+        assert g["fused.first_train_submit_seconds"] > h["p99"]
         assert telemetry.recent_events("fused.summary")
         # the flushed snapshot renders through obs_report
         telemetry.flush()
@@ -408,8 +410,11 @@ class TestFusedTelemetry:
         assert snaps and events
         text = obs_report.render(str(tmp_path), reg, snaps, journals,
                                  events)
-        assert "fused.train_dispatch_seconds" in text
+        assert "fused.train_submit" in text
         assert "p99" in text and "fused train" in text
+        # the sums of submit time are gone: on an asynchronous device
+        # they are no denominator for a rate
+        assert "fused.train_seconds" not in c
 
     def test_stream_bytes_property_backed_by_registry(self):
         from veles_tpu.backends import JaxDevice
@@ -446,6 +451,277 @@ class TestFusedTelemetry:
         # the property is read-only: the old mutation path is gone
         with pytest.raises(AttributeError):
             w.fused.stream_transfer_bytes = 0
+
+
+# -- the training path's own spans (ISSUE 26) --------------------------
+
+def _run_tiny(tmp_path=None, **kw):
+    """A tiny StandardWorkflow through initialize/run/stop on XLA:CPU,
+    its jitted steps wrapped to count the calls of each kind."""
+    from veles_tpu.backends import JaxDevice
+    if tmp_path is not None:
+        telemetry.configure(str(tmp_path))
+    w = _tiny_workflow(**kw)
+    w.initialize(device=JaxDevice(platform="cpu"))
+    calls = {"train": 0, "eval": 0, "fetch": 0}
+
+    def counted(kind, fn):
+        def call(*args):
+            calls[kind] += 1
+            return fn(*args)
+        return call
+
+    w.fused._train_step = counted("train", w.fused._train_step)
+    w.fused._eval_step = counted("eval", w.fused._eval_step)
+    w.fused.take_class_metrics = counted(
+        "fetch", w.fused.take_class_metrics)
+    w.run()
+    w.stop()
+    return w, calls
+
+
+def _intervals(path, names):
+    """{name: [(thread line, start_ns, end_ns)]} of the host-plane
+    events with these names in a ``*.xplane.pb``."""
+    import jax
+    out = {n: [] for n in names}
+    data = jax.profiler.ProfileData.from_file(path)
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in out:
+                    out[e.name].append(
+                        (line.name, e.start_ns,
+                         e.start_ns + e.duration_ns))
+    return out
+
+
+class TestTrainingPathSpans:
+    def test_a_span_never_imports_jax(self):
+        import subprocess
+        code = ("import sys\n"
+                "from veles_tpu import telemetry, units, workflow\n"
+                "with telemetry.span('t.no_jax') as s:\n"
+                "    pass\n"
+                "assert telemetry.histogram('t.no_jax').count == 1\n"
+                "assert 'jax' not in sys.modules, 'jax imported'\n")
+        p = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                           capture_output=True, text=True, timeout=120)
+        assert p.returncode == 0, p.stderr[-2000:]
+
+    @pytest.mark.parametrize("validation", [False, True],
+                             ids=["train", "train+eval"])
+    @pytest.mark.parametrize("stream", [False, True],
+                             ids=["resident", "stream"])
+    def test_counts_at_every_layer_boundary(self, validation, stream):
+        w, calls = _run_tiny(max_epochs=3, validation=validation,
+                             stream=stream)
+        assert w.fused.streaming == stream
+        h = telemetry.histogram
+        assert h("workflow.initialize").count == 1
+        assert h("workflow.run").count == 1
+        assert h("workflow.run").sum == pytest.approx(w.wall_time)
+        fired = [u for u in w.units if u.run_count]
+        assert {"loader", "fused", "decision"} <= \
+            {u.name for u in fired}
+        for u in fired:
+            assert h(u.name + ".run").count == u.run_count, u.name
+            assert h(u.name + ".run").sum == \
+                pytest.approx(u.run_time), u.name
+            assert h("init." + u.name).count >= 1, u.name
+        # the barrier of the loop: one fetch per class end; the host's
+        # turnaround after each but the last, which ends the run
+        class_ends = 3 * (2 if validation else 1)
+        assert calls["fetch"] == class_ends
+        assert h("fused.fetch_metrics").count == class_ends
+        assert h("loop.turnaround").count == class_ends - 1
+        g = telemetry.gauge
+        for kind in ("train", "eval") if validation else ("train",):
+            assert calls[kind] > 1
+            assert g(f"fused.first_{kind}_submit_seconds").value > 0
+            assert h(f"fused.first_{kind}_submit").count == 1
+            assert h(f"fused.{kind}_submit").count == calls[kind] - 1
+            assert telemetry.counter(
+                f"fused.{kind}_wall_seconds").value > 0
+        if not validation:
+            assert h("fused.eval_submit").count == 0
+        # (an attempt before the forwards are initialized fails with
+        # AttributeError and Workflow.initialize retries it)
+        assert h("fused.build_steps").count >= 1
+        assert h("fused.ensure_params").count == 1
+        assert h("fused.put_carry").count == w.fused.run_count
+
+    def test_spans_nest_on_the_profilers_clock(self, tmp_path):
+        import jax
+        from veles_tpu.backends import JaxDevice
+        w = _tiny_workflow(max_epochs=3)
+        w.initialize(device=JaxDevice(platform="cpu"))
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            w.run()
+        finally:
+            jax.profiler.stop_trace()
+        w.stop()
+        path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                             / "*.xplane.pb"))[0]
+        iv = _intervals(path, ["veles:workflow.run", "veles:fused.run",
+                               "veles:fused.train_submit",
+                               "veles:fused.fetch_metrics",
+                               "veles:decision.run"])
+        (line, lo, hi), = iv["veles:workflow.run"]
+        assert len(iv["veles:fused.run"]) == w.fused.run_count
+        assert iv["veles:fused.train_submit"]
+
+        def inside(inner, outers):
+            return any(o[0] == inner[0] and o[1] <= inner[1]
+                       and inner[2] <= o[2] for o in outers)
+
+        for sub in iv["veles:fused.train_submit"]:
+            assert inside(sub, iv["veles:fused.run"]), sub
+        for run in iv["veles:fused.run"]:
+            assert inside(run, [(line, lo, hi)]), run
+        for fetch in iv["veles:fused.fetch_metrics"]:
+            assert inside(fetch, iv["veles:decision.run"]), fetch
+
+    def test_every_layer_has_its_scope_in_the_lowered_step(self):
+        import jax
+        from veles_tpu.backends import JaxDevice
+        w = _tiny_workflow(max_epochs=1)
+        w.initialize(device=JaxDevice(platform="cpu"))
+        step, seen = w.fused._train_step, []
+
+        def capture(*args):
+            seen.append(jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(np.shape(a),
+                                               np.asarray(a).dtype),
+                args))
+            return step(*args)
+
+        w.fused._train_step = capture
+        w.run()
+        w.stop()
+        import re
+        text = step.lower(*seen[0]).as_text(debug_info=True)
+        scopes = [kind + f.name for f in w.forwards
+                  for kind in ("fwd/", "bwd/", "update/")]
+        # (``cast_params`` holds no op where the compute dtype is f32)
+        for scope in scopes + ["gather", "loss"]:
+            # the name stack of an op, whole or below the scan's body
+            assert re.search(r'["/]' + re.escape(scope) + "/", text), \
+                scope
+
+    def test_compile_is_counted_with_the_spans_it_fell_in(self):
+        import jax
+        import jax.numpy as jnp
+        from veles_tpu.engine import core as engine_core
+        engine_core.watch_compiles()
+        x = jnp.arange(8.0)
+        salt = float(np.random.default_rng().integers(1, 1 << 30))
+        fresh = jax.jit(lambda v: v * 3.0 + salt)
+        n0 = telemetry.counter("xla.compiles").value
+        with telemetry.span("t.compile_probe"):
+            fresh(x).block_until_ready()
+        mine = [e for e in telemetry.recent_events("xla.compile")
+                if "t.compile_probe" in e["during"]]
+        assert len(mine) == 1, mine
+        assert mine[0]["seconds"] > 0 and mine[0]["cached"] is False
+        assert telemetry.counter("xla.compiles").value == n0 + 1
+        assert telemetry.counter("xla.compile_seconds").value > 0
+        # outside any fused.* span: none of it is the step's
+        assert telemetry.counter("fused.compile_seconds").value == 0
+        for _ in range(10):
+            fresh(x).block_until_ready()
+        assert telemetry.counter("xla.compiles").value == n0 + 1
+
+    def test_step_compiles_fall_inside_fused_spans(self):
+        w, _ = _run_tiny(max_epochs=2)
+        evs = [e for e in telemetry.recent_events("xla.compile")
+               if "fused.first_train_submit" in e["during"]]
+        assert evs and evs[0]["during"][:2] == ["workflow.run",
+                                                "fused.run"]
+        assert 0 < telemetry.counter("fused.compile_seconds").value \
+            <= telemetry.counter("xla.compile_seconds").value
+        # steady firings compile nothing: every step compile of the
+        # run fell inside a first submit
+        for e in telemetry.recent_events("xla.compile"):
+            assert not any(s.endswith("_submit")
+                           and ".first_" not in s
+                           for s in e["during"]), e
+
+    def test_disabled_records_nothing_and_opens_no_annotation(
+            self, monkeypatch):
+        import jax
+        opened = []
+
+        class Spy:
+            def __init__(self, name):
+                opened.append(name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return None
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Spy)
+        telemetry.set_enabled(False)
+        try:
+            with telemetry.span("t.off") as s:
+                assert telemetry.span_stack() == []
+        finally:
+            telemetry.set_enabled(True)
+        assert opened == [] and s.seconds >= 0
+        assert telemetry.histogram("t.off").count == 0
+        with telemetry.span("t.on"):
+            pass
+        assert opened == ["veles:t.on"]
+        assert telemetry.histogram("t.on").count == 1
+
+    def test_operators_train_row_divides_by_wall_time(self, tmp_path):
+        """obs_report's "fused train" row is images over wall seconds
+        between barriers — not over the host's submit seconds, which
+        on an asynchronous device read orders of magnitude high."""
+        import re
+        from veles_tpu import obs
+        w, _ = _run_tiny(tmp_path, n_train=320, max_epochs=12,
+                         validation=False)
+        telemetry.flush()
+        reg, snaps, journals, evs = obs.load_dir(str(tmp_path))
+        text = obs.render(str(tmp_path), reg, snaps, journals, evs)
+        row = re.search(r"fused train: \S+ over \S+ engine-s -> "
+                        r"(\S+) img/s", text)
+        assert row, text
+        shown = float(row.group(1).replace(",", ""))
+        delivered = w.fused.processed_images / w.wall_time
+        assert delivered / 2 < shown < delivered * 2
+        c = telemetry.snapshot()["counters"]
+        assert c["fused.train_images"] / c["fused.train_wall_seconds"] \
+            == pytest.approx(shown, rel=0.01)
+
+    def test_mfu_counts_mxu_work_only(self):
+        """``mxu_train`` of the shipped AlexNet is the benchmark's
+        independent count: 1 135 256 096 MACs x 2 x 3."""
+        from veles_tpu import profiling
+        from veles_tpu.backends import JaxDevice
+        from veles_tpu.models import alexnet
+
+        class Launcher:
+            workflow = None
+
+        w = alexnet.create_workflow(
+            Launcher(),
+            loader={"minibatch_size": 2, "n_train": 4, "n_valid": 2,
+                    "shape": (227, 227, 3), "n_classes": 1000,
+                    "noise": 0.5, "max_shift": 8, "seed": 1})
+        w.initialize(device=JaxDevice(platform="cpu"))
+        flops = profiling.model_flops_per_sample(w.forwards)
+        assert flops["mxu_train"] == 6.0 * 1_135_256_096
+        assert flops["mxu_train"] / 1e9 == pytest.approx(6.8115,
+                                                         abs=5e-5)
+        assert flops["train"] > flops["mxu_train"]
+        w.stop()
 
 
 # -- the real --serve round-trip merge --------------------------------

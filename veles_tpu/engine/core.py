@@ -39,9 +39,60 @@ per-subsystem budget fictions.
 
 from __future__ import annotations
 
+import threading
 from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
+
+from veles_tpu import events, telemetry
+
+# -- compiles, seen from inside the program ----------------------------
+
+#: jax.monitoring's names: the duration of one ``compile_or_get_cached``
+#: (a backend compile OR a persistent-cache load), and the retrieval
+#: time jax reports just before it when the cache held the program
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+_watching_compiles = False
+_compiling = threading.local()     # .hit: the cache held the program
+
+
+def _on_jax_duration(event: str, duration: float, **kw: Any) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _compiling.hit = True
+        return
+    if event != _COMPILE_EVENT:
+        return
+    cached = getattr(_compiling, "hit", False)
+    _compiling.hit = False
+    # the listener runs on the compiling thread, so its open spans say
+    # which step compiled and inside what
+    during = telemetry.span_stack()
+    telemetry.counter(events.CTR_XLA_COMPILES).inc()
+    telemetry.counter(events.CTR_XLA_COMPILE_SECONDS).inc(duration)
+    if any(name.startswith("fused.") for name in during):
+        telemetry.counter(events.CTR_FUSED_COMPILE_SECONDS).inc(
+            duration)
+    telemetry.event(events.EV_XLA_COMPILE,
+                    seconds=round(duration, 6),
+                    fun=kw.get("fun_name"), cached=cached,
+                    during=during)
+
+
+def watch_compiles() -> None:
+    """Register the process's ONE jax.monitoring listener (jax keeps a
+    listener for the life of the process, so: once).  Called where
+    this module first touches jax."""
+    global _watching_compiles
+    if _watching_compiles:
+        return
+    _watching_compiles = True
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(
+        _on_jax_duration)
+
 
 # -- the two seam primitives -------------------------------------------
 # Every host->device placement and every buffer donation in the repo
@@ -56,6 +107,7 @@ def put(array: Any, where: Any = None):
     residency decisions must not scatter back across the repo."""
     import jax
 
+    watch_compiles()
     if where is None:
         return jax.device_put(array)
     return jax.device_put(array, where)
@@ -71,6 +123,7 @@ def donating_jit(fn, donate: Tuple[int, ...] = (),
     ``jax.jit(fn, donate_argnums=...)``."""
     import jax
 
+    watch_compiles()
     kw: Dict[str, Any] = {}
     if donate:
         kw["donate_argnums"] = tuple(donate)
@@ -94,6 +147,7 @@ def build_ingest(dequant: Any):
     """The wire-format prologue: identity for f32/bf16 batches, the
     affine uint8 dequantize (f32 arithmetic, host normalization order)
     for quantized loaders."""
+    import jax
     import jax.numpy as jnp
 
     if dequant is None:
@@ -102,7 +156,8 @@ def build_ingest(dequant: Any):
     q_bias = jnp.asarray(dequant.bias, jnp.float32)
 
     def ingest(x):
-        return x.astype(jnp.float32) * q_scale + q_bias
+        with jax.named_scope("ingest"):
+            return x.astype(jnp.float32) * q_scale + q_bias
 
     return ingest
 
@@ -120,14 +175,19 @@ def build_forward(forwards, seed: int, compute_dtype):
     def forward_pass(params, x, rng_counter, train: bool):
         residuals = []
         if mixed:
-            x = x.astype(compute_dtype)
+            with jax.named_scope("ingest"):
+                x = x.astype(compute_dtype)
         for i, f in enumerate(forwards):
-            rng = jax.random.fold_in(
-                jax.random.fold_in(jax.random.key(seed),
-                                   rng_counter), i) \
-                if f.stochastic else None
-            x, res = f.apply_fwd(params[f.name], x, rng=rng,
-                                 train=train)
+            # every device op carries its layer in its metadata
+            # (``fwd/<layer>``; the backward walk: ``bwd/``,
+            # ``update/``) — metadata only, the program is the same
+            with jax.named_scope("fwd/" + f.name):
+                rng = jax.random.fold_in(
+                    jax.random.fold_in(jax.random.key(seed),
+                                       rng_counter), i) \
+                    if f.stochastic else None
+                x, res = f.apply_fwd(params[f.name], x, rng=rng,
+                                     train=train)
             residuals.append(res)
         return x, residuals
 
@@ -147,6 +207,8 @@ def build_backward(forwards, gds, compute_dtype):
     per-member (wd, bias-wd) row when the caller supplies one (the
     population engine's decays contract; ``decays=None`` omits the
     kwarg entirely, matching the single-model loops exactly)."""
+    import jax
+
     n_fwd = len(forwards)
     first_gd = next((i for i, g in enumerate(gds) if g is not None),
                     -1)
@@ -162,30 +224,31 @@ def build_backward(forwards, gds, compute_dtype):
             f, gd = forwards[i], gds[i]
             if gd is None:
                 continue
-            if i == first_gd and gd.can_skip_err_input:
-                # nothing consumes the chain-head err_input; for conv1
-                # this skips the input-dilated transposed conv (the
-                # worst MXU op here)
-                _, grads = gd.backward_from_saved(
-                    cparams[f.name], residuals[i], err,
-                    need_err_input=False)
-                err_in = None
-            else:
-                err_in, grads = gd.backward_from_saved(
-                    cparams[f.name], residuals[i], err)
-            if grads:
-                if wd is None:
-                    p, v = gd.update_params(params[f.name], grads,
-                                            opt.get(gd.name, {}),
-                                            rates=(lr[i, 0],
-                                                   lr[i, 1]))
+            with jax.named_scope("bwd/" + f.name):
+                if i == first_gd and gd.can_skip_err_input:
+                    # nothing consumes the chain-head err_input; for
+                    # conv1 this skips the input-dilated transposed
+                    # conv (the worst MXU op here)
+                    _, grads = gd.backward_from_saved(
+                        cparams[f.name], residuals[i], err,
+                        need_err_input=False)
+                    err_in = None
                 else:
-                    p, v = gd.update_params(params[f.name], grads,
-                                            opt.get(gd.name, {}),
-                                            rates=(lr[i, 0],
-                                                   lr[i, 1]),
-                                            decays=(wd[i, 0],
-                                                    wd[i, 1]))
+                    err_in, grads = gd.backward_from_saved(
+                        cparams[f.name], residuals[i], err)
+            if grads:
+                with jax.named_scope("update/" + f.name):
+                    if wd is None:
+                        p, v = gd.update_params(
+                            params[f.name], grads,
+                            opt.get(gd.name, {}),
+                            rates=(lr[i, 0], lr[i, 1]))
+                    else:
+                        p, v = gd.update_params(
+                            params[f.name], grads,
+                            opt.get(gd.name, {}),
+                            rates=(lr[i, 0], lr[i, 1]),
+                            decays=(wd[i, 0], wd[i, 1]))
                 new_params[f.name] = p
                 if gd.name in opt:
                     new_opt[gd.name] = v

@@ -10,12 +10,13 @@ assertions could previously only catch at runtime (an emitter and an
 asserter disagreeing on a name means the drill reads an event that
 never fires) is now a parse-time finding.
 
-A few hot-path names are *families* keyed by the fused step kind and
-are necessarily built dynamically (``fused.<kind>_dispatch_seconds``
-histograms, ``fused.first_<kind>_dispatch_seconds`` gauges,
-``fused.<kind>_seconds`` / ``fused.<kind>_images`` counters); the lint
-rule checks literals only, and the families are documented here so the
-registry stays the one place a name is looked up.
+A few hot-path names are *families* keyed by the fused step kind or by
+a unit's name and are necessarily built dynamically
+(``fused.<kind>_submit`` spans, ``fused.first_<kind>_submit_seconds``
+gauges, ``fused.<kind>_images`` counters, ``<unit>.run`` /
+``init.<unit>`` spans); the lint rule checks literals only, and the
+families are documented here (``DYNAMIC_FAMILIES``) so the registry
+stays the one place a name is looked up.
 """
 
 from __future__ import annotations
@@ -58,10 +59,24 @@ def _span(name: str) -> str:
     return name
 
 
+def _timed(name: str) -> str:
+    # a span that never journals: the histogram of its name, and the
+    # ``veles:<name>`` annotation in a profiler trace
+    SPANS.add(name)
+    HISTOGRAMS.add(name)
+    return name
+
+
 # -- journal events ----------------------------------------------------
 
 EV_FUSED_FIRST_DISPATCH = _ev("fused.first_dispatch")
 EV_FUSED_SUMMARY = _ev("fused.summary")
+
+#: one per backend compile OR persistent-cache load of a program
+#: (jax.monitoring reports both under one name): ``seconds``, the
+#: jitted function's name, ``cached``, and ``during`` = the spans open
+#: on the compiling thread — which step compiled, and inside what
+EV_XLA_COMPILE = _ev("xla.compile")
 
 EV_DEVICE_OOM_RETRY = _ev("device.oom_retry")
 EV_DEVICE_OOM_DEGRADED = _ev("device.oom_degraded")
@@ -154,6 +169,12 @@ CTR_FUSED_STREAM_TRANSFER_BYTES = _ctr("fused.stream_transfer_bytes")
 CTR_FUSED_STREAM_TRANSFER_SECONDS = _ctr(
     "fused.stream_transfer_seconds")
 CTR_FUSED_STREAM_OOM_RETRIES = _ctr("fused.stream_oom_retries")
+
+#: backend compiles + cache loads of this process, their seconds, and
+#: the part of those seconds spent inside a ``fused.*`` span
+CTR_XLA_COMPILES = _ctr("xla.compiles")
+CTR_XLA_COMPILE_SECONDS = _ctr("xla.compile_seconds")
+CTR_FUSED_COMPILE_SECONDS = _ctr("fused.compile_seconds")
 
 CTR_ENSEMBLE_CHUNKS = _ctr("ensemble.chunks")
 CTR_ENSEMBLE_SECONDS = _ctr("ensemble.seconds")
@@ -299,6 +320,10 @@ HIST_SERVE_WAIT_SECONDS = _hist("serve.wait_seconds")
 HIST_ONLINE_STEP_DISPATCH_SECONDS = _hist(
     "online.step_dispatch_seconds")
 HIST_ONLINE_GATE_SECONDS = _hist("online.gate_seconds")
+#: class-end metric fetch returned -> next superstep submitted: the
+#: host time the device idles on at each class end (crosses
+#: decision.run, the workflow loop, loader.run, the head of fused.run)
+HIST_LOOP_TURNAROUND = _hist("loop.turnaround")
 
 # -- journaled spans (event + histogram of the same name) --------------
 
@@ -306,11 +331,29 @@ SPAN_GA_COHORT_TRAIN = _span("ga.cohort_train")
 SPAN_SOM_COHORT_TRAIN = _span("som.cohort_train")
 SPAN_EVALUATOR_JOB_SECONDS = _span("evaluator.job_seconds")
 
+# -- spans of the training path (histogram + profiler annotation) ------
+
+SPAN_WORKFLOW_INITIALIZE = _timed("workflow.initialize")
+SPAN_WORKFLOW_RUN = _timed("workflow.run")
+SPAN_FUSED_BUILD_STEPS = _timed("fused.build_steps")
+SPAN_FUSED_ENSURE_PARAMS = _timed("fused.ensure_params")
+SPAN_FUSED_PUT_CARRY = _timed("fused.put_carry")
+#: the host's wait for every queued superstep of the class: the
+#: barrier of the training loop
+SPAN_FUSED_FETCH_METRICS = _timed("fused.fetch_metrics")
+
 #: dynamic name families (built with f-strings at the call site; the
-#: lint rule checks literals only): ``fused.<kind>_dispatch_seconds``
-#: histograms, ``fused.first_<kind>_dispatch_seconds`` gauges, and
-#: ``fused.<kind>_seconds`` / ``fused.<kind>_images`` counters, where
-#: <kind> is the fused step kind (train/eval/...)
+#: lint rule checks literals only), where <kind> is the fused step
+#: kind (train/eval) and <unit> a unit's name:
+#: ``fused.<kind>_submit`` spans round the jitted call alone (host
+#: SUBMIT time of one superstep — the device works on after it);
+#: the first call of a kind (trace + compile or cache load + upload)
+#: is the span ``fused.first_<kind>_submit`` and the gauge
+#: ``fused.first_<kind>_submit_seconds`` instead;
+#: ``fused.<kind>_images`` counters over ``fused.<kind>_wall_seconds``
+#: (first submit of a class -> its metric fetch returned, summed) are
+#: the delivered rate; ``<unit>.run`` spans for every firing
+#: (Unit.fire) and ``init.<unit>`` spans under ``workflow.initialize``
 #: ...plus the fleet router's per-model traffic split (the canary A/B
 #: read): ``fleet.model.<name>.requests`` / ``.errors`` / ``.shed`` /
 #: ``.mirrored`` counters and a ``fleet.model.<name>.request_seconds``
@@ -320,10 +363,13 @@ SPAN_EVALUATOR_JOB_SECONDS = _span("evaluator.job_seconds")
 #: ``fleet.replica.<i>.hedge_wins`` counter, where <i> is the replica
 #: index
 DYNAMIC_FAMILIES = (
-    "fused.<kind>_dispatch_seconds",
-    "fused.first_<kind>_dispatch_seconds",
-    "fused.<kind>_seconds",
+    "fused.<kind>_submit",
+    "fused.first_<kind>_submit",
+    "fused.first_<kind>_submit_seconds",
+    "fused.<kind>_wall_seconds",
     "fused.<kind>_images",
+    "<unit>.run",
+    "init.<unit>",
     "fleet.model.<name>.requests",
     "fleet.model.<name>.errors",
     "fleet.model.<name>.shed",

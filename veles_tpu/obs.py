@@ -24,12 +24,16 @@ import time
 
 from veles_tpu.telemetry import Registry
 
-#: (label, images counter, seconds counter, unit) rows of the derived
-#: throughput table — only pairs present in the merged registry print
+#: (label, work counter, seconds counter, unit) rows of the derived
+#: throughput table — only pairs present in the merged registry print.
+#: The fused rows divide by WALL seconds between barriers (first
+#: submit of a class -> its metric fetch returned): the device is
+#: asynchronous, so the host's submit seconds are no denominator
 THROUGHPUT_ROWS = (
-    ("fused train", "fused.train_images", "fused.train_seconds",
+    ("fused train", "fused.train_images", "fused.train_wall_seconds",
      "img/s"),
-    ("fused eval", "fused.eval_images", "fused.eval_seconds", "img/s"),
+    ("fused eval", "fused.eval_images", "fused.eval_wall_seconds",
+     "img/s"),
     ("ensemble", "ensemble.member_images", "ensemble.seconds",
      "member-img/s"),
     ("ga", "ga.evaluations", "ga.eval_seconds", "genomes/s"),

@@ -27,6 +27,8 @@ exactly what they would in eager mode.
 
 from __future__ import annotations
 
+import contextlib
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -116,9 +118,16 @@ class FusedStepRunner(AcceleratedUnit):
         #: the double-buffer (Faultline telemetry; see _run_streaming)
         self.stream_oom_retries = 0
         #: which step kinds ("train"/"eval") have dispatched — the
-        #: first firing of each is the compile+execute sample and is
-        #: recorded apart from the steady-state dispatch histogram
+        #: first submit of each traces + compiles (or loads) and is
+        #: recorded apart from the steady-state submit histogram
         self._dispatch_seen: set = set()
+        #: (kind, perf_counter at its first submit) of the class now
+        #: in flight; take_class_metrics closes it into
+        #: ``fused.<kind>_wall_seconds``
+        self._class_open: Optional[Tuple[str, float]] = None
+        #: perf_counter when the last class-end fetch returned, until
+        #: the next submit records ``loop.turnaround``
+        self._fetch_returned: Optional[float] = None
         #: monotonic timestamp of the first firing (end-of-run
         #: throughput/MFU summary, see _record_telemetry_summary)
         self._first_run_ts = None
@@ -188,6 +197,7 @@ class FusedStepRunner(AcceleratedUnit):
                                               self.device)
 
     def _build_steps(self) -> None:
+        import jax
         import jax.numpy as jnp
         from jax import lax
 
@@ -215,16 +225,22 @@ class FusedStepRunner(AcceleratedUnit):
         backward_update = engine_core.build_backward(forwards, gds, cd)
 
         cast = batching.make_caster(cd)
+        # the parts of a step round the layers' own ``fwd/``, ``bwd/``
+        # and ``update/`` scopes (engine/core.py): ``gather``,
+        # ``ingest``, ``cast_params``, ``loss`` — ``with`` blocks in
+        # place, not wrappers (see Workflow.initialize)
+        scope = jax.named_scope
 
         def metrics_of(out, target, mask):
-            m = evaluator.metrics_fn(out.astype(jnp.float32), target,
-                                     mask)
-            if want_confusion:
-                n = evaluator.n_classes
-                conf = jnp.zeros((n, n), jnp.int32)
-                conf = conf.at[target, m["max_idx"]].add(
-                    mask.astype(jnp.int32))
-                m["confusion"] = conf
+            with scope("loss"):
+                m = evaluator.metrics_fn(out.astype(jnp.float32),
+                                         target, mask)
+                if want_confusion:
+                    n = evaluator.n_classes
+                    conf = jnp.zeros((n, n), jnp.int32)
+                    conf = conf.at[target, m["max_idx"]].add(
+                        mask.astype(jnp.int32))
+                    m["confusion"] = conf
             return m
 
         data_sharded = self.data_sharded and self.mesh is not None
@@ -238,14 +254,16 @@ class FusedStepRunner(AcceleratedUnit):
             _mb_rows = core.row_sharding
 
         def gather(dataset, target_store, indices):
-            if data_sharded:
-                x, t = sharded_gather(indices, dataset, target_store)
-                x = lax.with_sharding_constraint(x, _mb_rows)
-                t = lax.with_sharding_constraint(t, _mb_rows)
+            with scope("gather"):
+                if data_sharded:
+                    x, t = sharded_gather(indices, dataset,
+                                          target_store)
+                    x = lax.with_sharding_constraint(x, _mb_rows)
+                    t = lax.with_sharding_constraint(t, _mb_rows)
+                    return x, t
+                x = jnp.take(dataset, indices, axis=0)
+                t = jnp.take(target_store, indices, axis=0)
                 return x, t
-            x = jnp.take(dataset, indices, axis=0)
-            t = jnp.take(target_store, indices, axis=0)
-            return x, t
 
         def accumulate(acc, conf, m):
             acc = acc + jnp.stack([m["n_err"], m["loss_sum"],
@@ -268,7 +286,8 @@ class FusedStepRunner(AcceleratedUnit):
                     indices, mask, lr = xs
                     x, target = gather(dataset, target_store, indices)
                 x = ingest(x)
-                cparams = cast(params)
+                with scope("cast_params"):
+                    cparams = cast(params)
                 out, residuals = forward_pass(cparams, x, rc, True)
                 m = metrics_of(out, target, mask)
                 err = m.pop("err_output")
@@ -306,7 +325,8 @@ class FusedStepRunner(AcceleratedUnit):
 
         def eval_step(params, acc, conf, dataset, target_store,
                       indices, mask, rng_counter):
-            cparams = cast(params)
+            with scope("cast_params"):
+                cparams = cast(params)
 
             def body(carry, xs):
                 acc, conf, _, rc = carry
@@ -326,7 +346,8 @@ class FusedStepRunner(AcceleratedUnit):
 
         def eval_step_stream(params, acc, conf, xb, tb, mask,
                              rng_counter):
-            cparams = cast(params)
+            with scope("cast_params"):
+                cparams = cast(params)
 
             def body(carry, xs):
                 acc, conf, _, rc = carry
@@ -429,7 +450,8 @@ class FusedStepRunner(AcceleratedUnit):
                     f"max_minibatch_size) not divisible by mesh size "
                     f"{n}; lower minibatch_size or pad the dataset")
         if self._train_step is None:
-            self._build_steps()
+            with telemetry.span(events.SPAN_FUSED_BUILD_STEPS):
+                self._build_steps()
 
     def _target_store(self):
         ld = self.loader
@@ -450,11 +472,11 @@ class FusedStepRunner(AcceleratedUnit):
                 np.asarray(ld.minibatch_mask.map_read())[None])
 
     def run(self) -> None:
-        import time
         ld = self.loader
         self._ensure_params()
         if self._train_step is None:   # invalidated (e.g. a resize)
-            self._build_steps()
+            with telemetry.span(events.SPAN_FUSED_BUILD_STEPS):
+                self._build_steps()
         if self._acc is None:
             self._acc, self._conf = self._fresh_acc()
         if self.mesh is None:
@@ -466,8 +488,9 @@ class FusedStepRunner(AcceleratedUnit):
             # of the one train program.  PR 21, on the chip: the
             # SECOND dispatch of a cold AlexNet run re-compiled for
             # 23 s.  A committed device array passes through as is.
-            self._acc = self._core.put(self._acc)
-            self._conf = self._core.put(self._conf)
+            with telemetry.span(events.SPAN_FUSED_PUT_CARRY):
+                self._acc = self._core.put(self._acc)
+                self._conf = self._core.put(self._conf)
         indices, mask = self._superstep_arrays()
         k = indices.shape[0]
         train = ld.minibatch_class == TRAIN
@@ -478,48 +501,54 @@ class FusedStepRunner(AcceleratedUnit):
             self.processed_eval_images += images
         if self._first_run_ts is None:
             self._first_run_ts = time.monotonic()
-        t0 = time.perf_counter()
         if self.streaming:
             self._run_streaming(ld, k, mask, train)
         else:
             self._run_resident(ld, k, indices, mask, train)
         self._rng_counter += k
-        self._record_dispatch("train" if train else "eval",
-                              time.perf_counter() - t0, k, images)
+        telemetry.counter(events.CTR_FUSED_DISPATCHES).inc()
+        telemetry.counter(events.CTR_FUSED_MINIBATCHES).inc(k)
+        telemetry.counter(
+            f"fused.{'train' if train else 'eval'}_images").inc(images)
 
-    def _record_dispatch(self, kind: str, dt: float, k: int,
-                         images: float) -> None:
-        """Per-dispatch wall time into the registry.  The FIRST firing
-        of each step kind traces + compiles, so it lands in its own
-        gauge (and the journal) instead of polluting the steady-state
-        histogram the p50/p99 report reads.  Wall here is host-observed
-        submission time — on an async backend the device may still be
-        chewing; the honest end-to-end barrier remains the metric-carry
-        fetch (take_class_metrics / bench.py sync_images)."""
-        if not telemetry.enabled():
-            return
-        if kind not in self._dispatch_seen:
+    @contextlib.contextmanager
+    def _submit(self, kind: str, k: int):
+        """Round the jitted call alone: the ``fused.<kind>_submit``
+        span, host SUBMIT time of one superstep — on an asynchronous
+        backend the device works on after it; the barrier is the
+        class-end fetch (``fused.fetch_metrics``).  The FIRST call of
+        a kind traces + compiles (or loads the cached program) and
+        uploads the parameters, so it is a span of its own name and a
+        gauge (and a journal event), kept out of the steady-state
+        histogram the p50/p99 report reads.  A context manager, so
+        that the jitted call stays in its caller's frame (see
+        Workflow.initialize)."""
+        first = kind not in self._dispatch_seen
+        now = time.perf_counter()
+        if self._class_open is None:
+            self._class_open = (kind, now)
+        if self._fetch_returned is not None:
+            telemetry.histogram(events.HIST_LOOP_TURNAROUND).record(
+                now - self._fetch_returned)
+            self._fetch_returned = None
+        with telemetry.span(f"fused.first_{kind}_submit" if first
+                            else f"fused.{kind}_submit") as span:
+            yield
+        if first:
             self._dispatch_seen.add(kind)
             telemetry.gauge(
-                f"fused.first_{kind}_dispatch_seconds").set(dt)
+                f"fused.first_{kind}_submit_seconds").set(span.seconds)
             # the run record's "where and what": the device as JAX
             # reports it and the static step shape, next to the one
-            # dispatch that traced + compiled (or loaded) the program
+            # call that traced + compiled (or loaded) the program
             telemetry.event(events.EV_FUSED_FIRST_DISPATCH, kind=kind,
-                            seconds=round(dt, 4),
+                            seconds=round(span.seconds, 4),
                             streaming=bool(self.streaming),
                             minibatches=k,
                             batch_shape=list(
                                 self.loader.minibatch_data.shape),
                             output_shape=list(self._out_shape),
                             **self.device.describe())
-        else:
-            telemetry.histogram(
-                f"fused.{kind}_dispatch_seconds").record(dt)
-        telemetry.counter(events.CTR_FUSED_DISPATCHES).inc()
-        telemetry.counter(f"fused.{kind}_seconds").inc(dt)
-        telemetry.counter(events.CTR_FUSED_MINIBATCHES).inc(k)
-        telemetry.counter(f"fused.{kind}_images").inc(images)
 
     def _run_resident(self, ld, k, indices, mask, train: bool) -> None:
         dataset = ld.original_data.unmap()
@@ -531,16 +560,19 @@ class FusedStepRunner(AcceleratedUnit):
             indices = self._core.put(indices, self._batch_sharding)
             mask = self._core.put(mask, self._batch_sharding)
         if train:
-            self._params, self._opt, self._acc, self._conf = \
-                self._train_step(
-                    self._params, self._opt, self._acc, self._conf,
-                    dataset, targets, indices, mask,
-                    self._lr_rates_array(k), self._rng_counter)
+            lr = self._lr_rates_array(k)
+            with self._submit("train", k):
+                self._params, self._opt, self._acc, self._conf = \
+                    self._train_step(
+                        self._params, self._opt, self._acc, self._conf,
+                        dataset, targets, indices, mask, lr,
+                        self._rng_counter)
             self._scatter_params(self._params, self._opt)
         else:
-            self._acc, self._conf, out = self._eval_step(
-                self._params, self._acc, self._conf, dataset, targets,
-                indices, mask, self._rng_counter)
+            with self._submit("eval", k):
+                self._acc, self._conf, out = self._eval_step(
+                    self._params, self._acc, self._conf, dataset,
+                    targets, indices, mask, self._rng_counter)
             self.forwards[-1].output.devmem = out
 
     def _run_streaming(self, ld, k, mask, train: bool) -> None:
@@ -554,7 +586,6 @@ class FusedStepRunner(AcceleratedUnit):
         falls behind the host (or a link that falls behind the
         dispatch loop) back-pressures the loop instead of piling
         unsent host batches into RAM without bound."""
-        import time
         xb = ld.superstep_data
         tb = ld.superstep_targets if self._has_targets() \
             else ld.superstep_labels
@@ -616,16 +647,18 @@ class FusedStepRunner(AcceleratedUnit):
             events.CTR_FUSED_STREAM_TRANSFER_SECONDS).inc(
             dt_transfer)
         if train:
-            self._params, self._opt, self._acc, self._conf = \
-                self._train_step(
-                    self._params, self._opt, self._acc, self._conf,
-                    xb, tb, mask, self._lr_rates_array(k),
-                    self._rng_counter)
+            lr = self._lr_rates_array(k)
+            with self._submit("train", k):
+                self._params, self._opt, self._acc, self._conf = \
+                    self._train_step(
+                        self._params, self._opt, self._acc, self._conf,
+                        xb, tb, mask, lr, self._rng_counter)
             self._scatter_params(self._params, self._opt)
         else:
-            self._acc, self._conf, out = self._eval_step(
-                self._params, self._acc, self._conf, xb, tb, mask,
-                self._rng_counter)
+            with self._submit("eval", k):
+                self._acc, self._conf, out = self._eval_step(
+                    self._params, self._acc, self._conf, xb, tb, mask,
+                    self._rng_counter)
             self.forwards[-1].output.devmem = out
 
     def _lr_rates_array(self, k: int) -> np.ndarray:
@@ -671,12 +704,13 @@ class FusedStepRunner(AcceleratedUnit):
     def _record_telemetry_summary(self) -> None:
         """End-of-run throughput gauges: wall-clock images/sec since
         the first firing and — where the device's peak is known —
-        achieved MFU via profiling.py.  Wall includes host time between
+        achieved MFU via profiling.py, over MXU work alone (conv +
+        dense MACs: pool/LRN/activation passes are HBM traffic and
+        would raise a utilisation).  Wall includes host time between
         dispatches, so this is the run's DELIVERED rate (a lower bound
         on engine efficiency), the number an operator reads off
         obs_report; bench.py's barriered windows remain the measured
         engine rate."""
-        import time
         if self._first_run_ts is None or not telemetry.enabled():
             return
         elapsed = time.monotonic() - self._first_run_ts
@@ -691,7 +725,7 @@ class FusedStepRunner(AcceleratedUnit):
         try:
             from veles_tpu import profiling
             flops = profiling.model_flops_per_sample(
-                self.forwards)["train"]
+                self.forwards)["mxu_train"]
             telemetry.gauge(
                 events.GAUGE_FUSED_TRAIN_GFLOPS_PER_IMAGE).set(
                 round(flops / 1e9, 4))
@@ -771,15 +805,28 @@ class FusedStepRunner(AcceleratedUnit):
         last call — ONE small device fetch, then reset."""
         if self._acc is None:
             return 0.0, 0.0, 0.0, None
-        acc = np.asarray(self._acc)
-        conf = np.asarray(self._conf) if self._want_confusion() else None
+        with telemetry.span(events.SPAN_FUSED_FETCH_METRICS):
+            acc = np.asarray(self._acc)
+            conf = np.asarray(self._conf) \
+                if self._want_confusion() else None
+        # the fetch drained every queued superstep of the class: its
+        # images over first submit -> now is the delivered rate, and
+        # from now to the next submit the device waits for the host
+        self._fetch_returned = time.perf_counter()
+        if self._class_open is not None:
+            kind, t_open = self._class_open
+            self._class_open = None
+            telemetry.counter(f"fused.{kind}_wall_seconds").inc(
+                self._fetch_returned - t_open)
         self._acc, self._conf = self._fresh_acc()
         return float(acc[0]), float(acc[1]), float(acc[2]), conf
 
     # -- zmq DCN compat mode (server.py / client.py) -------------------
 
     def _ensure_params(self) -> None:
-        if self._params is None:
+        if self._params is not None:
+            return
+        with telemetry.span(events.SPAN_FUSED_ENSURE_PARAMS):
             self._params = self._collect_params()
             self._opt = self._collect_opt()
             if self._core is not None:
@@ -824,8 +871,9 @@ class FusedStepRunner(AcceleratedUnit):
         # snapshots carried the plain attribute); dispatch bookkeeping
         # is process-local
         d.pop("_stream_bytes", None)
-        d.pop("_dispatch_seen", None)
-        d.pop("_first_run_ts", None)
+        for k in ("_dispatch_seen", "_first_run_ts", "_class_open",
+                  "_fetch_returned"):
+            d.pop(k, None)
         d["stream_transfer_bytes"] = self.stream_transfer_bytes
         # the on-device metric/confusion accumulators are device
         # buffers (hence _unpicklable), but their VALUES are run state:
@@ -858,6 +906,8 @@ class FusedStepRunner(AcceleratedUnit):
         self._stream_bytes = int(restored)
         self._dispatch_seen = set()
         self._first_run_ts = None
+        self._class_open = None
+        self._fetch_returned = None
         # mid-class metric carry written by __getstate__ (absent in
         # pre-fix snapshots): plain numpy arrays are exactly what
         # run() hands a fresh dispatch, so resume continues the class

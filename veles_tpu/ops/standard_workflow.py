@@ -64,7 +64,7 @@ class StandardWorkflow(NNWorkflow):
         self._create_snapshotter(snapshotter_config)
         self.fused = FusedStepRunner(
             self, loader=self.loader, forwards=self.forwards,
-            evaluator=self.evaluator, gds=self.gds, name="fused_step")
+            evaluator=self.evaluator, gds=self.gds, name="fused")
         self.lr_adjust = None
         if lr_adjust_config:
             from veles_tpu.ops.lr_adjust import LearningRateAdjust

@@ -92,16 +92,29 @@ def unit_has_weights(unit) -> bool:
     return w is not None and getattr(w, "mem", None) is not None
 
 
+def _is_mxu(unit) -> bool:
+    """Conv and dense layers: the MACs the MXU runs."""
+    return (hasattr(unit, "n_kernels") and hasattr(unit, "kx")) \
+        or hasattr(unit, "output_sample_shape")
+
+
 def model_flops_per_sample(forwards: List[Any]) -> Dict[str, float]:
-    """{"forward": F, "train": T} FLOPs for one sample, with the 3x/2x
-    weighted/weightless training multipliers."""
+    """{"forward": F, "train": T, "mxu_train": M} FLOPs for one
+    sample.  ``train`` uses the 3x/2x weighted/weightless multipliers
+    over EVERY layer (a conservative work estimate); ``mxu_train`` is
+    conv + dense MACs x 2 x 3 alone — the count a utilisation of the
+    MXU's peak divides by (the convention of benchmarks/lib/flops.py,
+    whose independent count a tier-1 test pins this one to)."""
     fwd = 0.0
     train = 0.0
+    mxu = 0.0
     for u in forwards:
         f = forward_flops_per_sample(u)
         fwd += f
         train += f * (3.0 if unit_has_weights(u) else 2.0)
-    return {"forward": fwd, "train": train}
+        if _is_mxu(u):
+            mxu += 3.0 * f
+    return {"forward": fwd, "train": train, "mxu_train": mxu}
 
 
 def layer_flops_table(forwards: List[Any]) -> List[Dict[str, Any]]:

@@ -25,9 +25,12 @@ Three primitives, one process-wide registry:
   far less), bucket-exact min/max/count/sum.  ``record()`` costs one
   ``log10`` + a list increment.
 
-**Spans** (``with span("fused.dispatch"):``) are nestable (a
+**Spans** (``with span(events.SPAN_WORKFLOW_RUN):``) are nestable (a
 thread-local stack) and feed the histogram of the same name; spans
-opened with ``journal=True`` also append an event line.
+opened with ``journal=True`` also append an event line.  In a process
+that has imported jax a span is also a ``jax.profiler.TraceAnnotation``
+named ``veles:<name>``, so a profiler trace shows the program's own
+spans on the device trace's clock (see :class:`span`).
 
 **The journal** is an append-only JSONL file of notable run events
 (``event("ga.hang_detected", kind=...)``): hang detections, restarts,
@@ -52,17 +55,18 @@ dir into the human-readable summary.
 
 Telemetry must never take down a run: file errors drop the sink and
 keep counting in memory; ``set_enabled(False)`` reduces every call to
-one module-attribute load + falsy check (bench.py measures the on/off
-delta as ``telemetry_overhead_pct``).
+one module-attribute load + falsy check (a span keeps its two clock
+reads for ``seconds``; bench.py measures the on/off delta as
+``telemetry_overhead_pct``).
 """
 
 from __future__ import annotations
 
 import atexit
-import contextlib
 import json
 import math
 import os
+import sys
 import tempfile
 import threading
 import time
@@ -72,6 +76,9 @@ from typing import Any, Dict, List, Optional
 from veles_tpu.analysis import witness
 
 ENV_DIR = "VELES_METRICS_DIR"
+
+#: what a span is called in a ``jax.profiler`` trace's host plane
+ANNOTATION_PREFIX = "veles:"
 
 #: histogram bucket layout: log-spaced, 32 per decade over
 #: [10^LOG_LO, 10^LOG_HI); bucket 0 is the underflow bin (x < lo,
@@ -503,31 +510,72 @@ def recent_events(name: Optional[str] = None) -> List[Dict[str, Any]]:
     return evs
 
 
-@contextlib.contextmanager
-def span(name: str, journal: bool = False, **fields: Any):
+class span:
     """Time a block into ``histogram(name)``.  Spans nest through a
     thread-local stack (``span_stack()``); ``journal=True`` also emits
     an event at exit carrying the duration, the parent span, and the
-    caller's fields."""
-    if not _enabled:
-        yield name
-        return
-    stack = getattr(_tls, "stack", None)
-    if stack is None:
-        stack = _tls.stack = []
-    parent = stack[-1] if stack else None
-    stack.append(name)
-    t0 = time.perf_counter()
-    try:
-        yield name
-    finally:
-        dt = time.perf_counter() - t0
-        if stack and stack[-1] == name:
+    caller's fields.
+
+    Where ``jax`` is ALREADY imported in this process the block also
+    runs under ``jax.profiler.TraceAnnotation("veles:" + name)``: with
+    no profiler session that is well under a microsecond; with one,
+    the span sits in the trace's host plane beside the device's ops,
+    on the profiler's clock, nested as the stack nests.  This module
+    never imports jax itself (supervisor, launcher and GA pool parents
+    must stay off it).
+
+    ``seconds`` holds the block's duration after exit — also with
+    telemetry disabled, when the two clock reads are all a span costs
+    — so a caller that keeps its own total (``Unit.run_time``) reads
+    the same clock pair."""
+
+    __slots__ = ("name", "journal", "fields", "seconds", "_t0",
+                 "_stack", "_parent", "_annotation")
+
+    def __init__(self, name: str, journal: bool = False,
+                 **fields: Any) -> None:
+        self.name = name
+        self.journal = journal
+        self.fields = fields
+        self.seconds = 0.0
+        self._stack: Optional[List[str]] = None
+        self._annotation = None
+
+    def __enter__(self) -> "span":
+        if _enabled:
+            stack = getattr(_tls, "stack", None)
+            if stack is None:
+                stack = _tls.stack = []
+            self._parent = stack[-1] if stack else None
+            stack.append(self.name)
+            self._stack = stack
+            # getattr: another thread may be half way through
+            # ``import jax``
+            annotate = getattr(sys.modules.get("jax.profiler"),
+                               "TraceAnnotation", None)
+            if annotate is not None:
+                self._annotation = annotate(ANNOTATION_PREFIX
+                                            + self.name)
+                self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        dt = self.seconds = time.perf_counter() - self._t0
+        stack = self._stack
+        if stack is None:
+            return
+        self._stack = None
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
+        if stack and stack[-1] == self.name:
             stack.pop()
-        histogram(name).record(dt)
-        if journal:
-            event(name, seconds=round(dt, 6), parent=parent,
-                  depth=len(stack), **fields)
+        histogram(self.name).record(dt)
+        if self.journal:
+            event(self.name, seconds=round(dt, 6),
+                  parent=self._parent, depth=len(stack),
+                  **self.fields)
 
 
 def span_stack() -> List[str]:

@@ -18,9 +18,9 @@ timing report (reference: workflow unit-timing table).
 
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, Optional, Set, Tuple, Union
 
+from veles_tpu import telemetry
 from veles_tpu.logger import Logger
 from veles_tpu.mutable import Bool, LinkableAttribute
 
@@ -153,9 +153,11 @@ class Unit(Logger):
         """Execute one firing; returns True if ``run()`` actually ran."""
         if bool(self.gate_skip):
             return False
-        t0 = time.perf_counter()
-        self.run()
-        self.run_time += time.perf_counter() - t0
+        # the ``<unit>.run`` span (events.DYNAMIC_FAMILIES): its two
+        # clock reads also feed the end-of-run timing report
+        with telemetry.span(self.name + ".run") as span:
+            self.run()
+        self.run_time += span.seconds
         self.run_count += 1
         return True
 

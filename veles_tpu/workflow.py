@@ -17,9 +17,9 @@ with device compute.
 from __future__ import annotations
 
 import collections
-import time
 from typing import Any, Optional
 
+from veles_tpu import events, telemetry
 from veles_tpu.mutable import Bool
 from veles_tpu.units import Container, Unit
 
@@ -70,24 +70,30 @@ class Workflow(Container):
         full pass with no progress re-raises.
         """
         self.device = device
-        order = self._dependency_order()
-        pending = [u for u in order if u is not self]
-        while pending:
-            errors = {}
-            still = []
-            for u in pending:
-                try:
-                    u.initialize(device=device, **kwargs)
-                    u._initialized = True
-                except AttributeError as e:
-                    errors[u] = e
-                    still.append(u)
-            if len(still) == len(pending):
-                u, e = next(iter(errors.items()))
-                raise RuntimeError(
-                    f"initialization deadlock: {len(still)} units cannot "
-                    f"initialize; first: {u} -> {e}") from e
-            pending = still
+        # (spans are ``with`` blocks in place, never helper calls: an
+        # extra Python frame between the entry point and a jitted call
+        # moves how long jax takes to lower it — PERF.md, PR 26)
+        with telemetry.span(events.SPAN_WORKFLOW_INITIALIZE):
+            order = self._dependency_order()
+            pending = [u for u in order if u is not self]
+            while pending:
+                errors = {}
+                still = []
+                for u in pending:
+                    try:
+                        # ``init.<unit>``: one span per attempt
+                        with telemetry.span("init." + u.name):
+                            u.initialize(device=device, **kwargs)
+                        u._initialized = True
+                    except AttributeError as e:
+                        errors[u] = e
+                        still.append(u)
+                if len(still) == len(pending):
+                    u, e = next(iter(errors.items()))
+                    raise RuntimeError(
+                        f"initialization deadlock: {len(still)} units "
+                        f"cannot initialize; first: {u} -> {e}") from e
+                pending = still
         self._initialized = True
 
     def _dependency_order(self) -> list:
@@ -118,40 +124,45 @@ class Workflow(Container):
         """Fire the start point and drive the graph until stopped."""
         if not self._initialized:
             raise RuntimeError("workflow.run() before initialize()")
-        t_start = time.perf_counter()
-        self.stopped.set(False)
-        # a stop requested before (or during a previous) run must not
-        # leak into this one — notably a workflow snapshotted by a
-        # graceful stop carries stop_requested=True on disk, and the
-        # RESUMED run would otherwise stop before its first firing
-        self.stop_requested = False
-        queue: collections.deque = collections.deque([self.start_point])
-        firings = 0
-        while queue and not bool(self.stopped):
-            unit = queue.popleft()
-            if self.stop_requested and \
-                    getattr(unit, "iteration_boundary", False):
-                # graceful stop lands HERE: the boundary unit (Repeater)
-                # is about to open the next iteration, so every unit has
-                # completed the current one — identical to the state a
-                # fresh run() reaches right before the same firing,
-                # which is what makes the final snapshot resume exactly
-                break
-            if bool(unit.gate_block):
-                continue
-            unit._reset_trigger_state()
-            unit.fire()
-            firings += 1
-            if firings > self._max_firings:
-                raise RuntimeError("workflow exceeded max firings "
-                                   "(runaway loop?)")
-            if bool(self.stopped):
-                break
-            for succ in sorted(unit.links_to, key=lambda x: x.name):
-                succ.links_from[unit] = True
-                if succ.ready and not bool(succ.gate_block):
-                    queue.append(succ)
-        self.wall_time += time.perf_counter() - t_start
+        # self time of ``workflow.run`` (less its ``<unit>.run``
+        # children) is the loop's own overhead
+        with telemetry.span(events.SPAN_WORKFLOW_RUN) as span:
+            self.stopped.set(False)
+            # a stop requested before (or during a previous) run must
+            # not leak into this one — notably a workflow snapshotted
+            # by a graceful stop carries stop_requested=True on disk,
+            # and the RESUMED run would otherwise stop before its
+            # first firing
+            self.stop_requested = False
+            queue: collections.deque = collections.deque(
+                [self.start_point])
+            firings = 0
+            while queue and not bool(self.stopped):
+                unit = queue.popleft()
+                if self.stop_requested and \
+                        getattr(unit, "iteration_boundary", False):
+                    # graceful stop lands HERE: the boundary unit
+                    # (Repeater) is about to open the next iteration,
+                    # so every unit has completed the current one —
+                    # identical to the state a fresh run() reaches
+                    # right before the same firing, which is what
+                    # makes the final snapshot resume exactly
+                    break
+                if bool(unit.gate_block):
+                    continue
+                unit._reset_trigger_state()
+                unit.fire()
+                firings += 1
+                if firings > self._max_firings:
+                    raise RuntimeError("workflow exceeded max firings "
+                                       "(runaway loop?)")
+                if bool(self.stopped):
+                    break
+                for succ in sorted(unit.links_to, key=lambda x: x.name):
+                    succ.links_from[unit] = True
+                    if succ.ready and not bool(succ.gate_block):
+                        queue.append(succ)
+        self.wall_time += span.seconds
         self.on_workflow_finished()
 
     def stop(self) -> None:
