@@ -38,7 +38,7 @@ class Device(Logger):
         #: residency win), never silently widen to the compute dtype.
         self.h2d_bytes = 0
 
-    def put(self, array: np.ndarray) -> Any:
+    def put(self, array: np.ndarray, where: Any = None) -> Any:
         return array
 
     def get(self, buf: Any) -> np.ndarray:
@@ -155,7 +155,10 @@ class JaxDevice(Device):
         self.compute_dtype = compute_dtype
         self._jit_cache: Dict[Any, Callable] = {}
 
-    def put(self, array: np.ndarray) -> Any:
+    def put(self, array: np.ndarray, where: Any = None) -> Any:
+        # ``where``: a placement of this device other than its default
+        # one (a ``Format``: the device layout a resident store keeps,
+        # see ``FullBatchLoader.reside_as``).
         # Copy before upload: device_put may alias host memory (XLA:CPU
         # is zero-copy) or defer the H2D transfer, and the map/unmap
         # protocol lets callers mutate the host buffer right after
@@ -166,7 +169,8 @@ class JaxDevice(Device):
         arr = np.array(array, copy=True)
         self.h2d_bytes += arr.nbytes
         from veles_tpu.engine import core as engine_core
-        return engine_core.put(arr, self.jax_device)
+        return engine_core.put(
+            arr, self.jax_device if where is None else where)
 
     def get(self, buf: Any) -> np.ndarray:
         return np.asarray(buf)

@@ -39,6 +39,7 @@ per-subsystem budget fictions.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 from typing import Any, Dict, Optional, Tuple
 
@@ -100,16 +101,42 @@ def watch_compiles() -> None:
 # serve/residency.py, the other two allowlisted seam modules).
 
 
+@contextlib.contextmanager
+def not_persisted():
+    """Compile what this thread jits inside WITHOUT writing it to the
+    persistent compile cache.  For the programs whose OUTPUT lies in a
+    device layout of its own (a ``Format``): loaded back from the
+    cache, jax 0.9.0 / libtpu 0.0.34 hand out buffers that lie in
+    that layout but REPORT the default one, so the next program is
+    compiled for the layout reported and refuses the buffer
+    (``INVALID_ARGUMENT: expected parameter ... of size ...``; PR 27,
+    on the chip).  Compiled fresh, the same program reports the truth;
+    a program whose INPUT has such a layout loads back sound.  jax has
+    no switch per program, so the cache's write threshold is raised
+    for this thread (the context-manager form of a jax option is
+    thread-local, and only ``jax._src`` has this one's)."""
+    from jax._src import config as jax_config
+
+    with jax_config.persistent_cache_min_compile_time_secs(float("inf")):
+        yield
+
+
 def put(array: Any, where: Any = None):
     """THE ``jax.device_put`` seam: place ``array`` on ``where`` (a
-    jax device or a Sharding; the backend's default device when
-    None).  Call sites outside the seam modules are lint findings —
-    residency decisions must not scatter back across the repo."""
+    jax device, a Sharding, or a ``Format`` — a sharding with a device
+    layout; the backend's default device when None).  Call sites
+    outside the seam modules are lint findings — residency decisions
+    must not scatter back across the repo."""
     import jax
+    from jax.experimental.layout import Format
 
     watch_compiles()
     if where is None:
         return jax.device_put(array)
+    if isinstance(where, Format):
+        # jax re-lays the upload out with a jitted identity
+        with not_persisted():
+            return jax.device_put(array, where)
     return jax.device_put(array, where)
 
 

@@ -248,6 +248,10 @@ CTR_EVALUATOR_JOB_ERRORS = _ctr("evaluator.job_errors")
 CTR_LOADER_EPOCHS = _ctr("loader.epochs")
 CTR_LOADER_IMAGES_DECODED = _ctr("loader.images_decoded")
 CTR_LOADER_CORRUPT_SKIPPED = _ctr("loader.corrupt_skipped")
+#: whole-store passes that put the resident dataset into the form the
+#: step reads (``FullBatchLoader.reside_as``): 1 a run; a value that
+#: grows with the firings is the per-superstep re-cast come back
+CTR_LOADER_RESIDENT_CASTS = _ctr("loader.resident_casts")
 
 CTR_SNAPSHOT_SAVES = _ctr("snapshot.saves")
 CTR_SNAPSHOT_FALLBACKS = _ctr("snapshot.fallbacks")
@@ -295,6 +299,9 @@ GAUGE_ONLINE_TIME_TO_SERVE = _gauge("online.time_to_serve")
 GAUGE_LOCKSTEP_EDGES = _gauge("lockstep.edges_observed")
 GAUGE_LOCKSTEP_ACQUIRES = _gauge("lockstep.acquires")
 
+#: device bytes of the resident data store once its dtype is decided
+#: (0 = streaming)
+GAUGE_LOADER_RESIDENT_BYTES = _gauge("loader.resident_bytes")
 GAUGE_GA_LAST_HANG_WAIT = _gauge("ga.last_hang_wait")
 GAUGE_PREEMPT_SNAPSHOT_SECONDS = _gauge("preempt.snapshot_seconds")
 GAUGE_MULTIHOST_PEER_HEARTBEAT_AGE = _gauge(
@@ -330,6 +337,11 @@ HIST_LOOP_TURNAROUND = _hist("loop.turnaround")
 SPAN_GA_COHORT_TRAIN = _span("ga.cohort_train")
 SPAN_SOM_COHORT_TRAIN = _span("som.cohort_train")
 SPAN_EVALUATOR_JOB_SECONDS = _span("evaluator.job_seconds")
+#: the decision about the resident store's dtype, once per initialize:
+#: ``from``, ``to``, ``bytes_before``, ``bytes_after``, ``seconds`` and,
+#: where the store was left as it is, ``reason`` (``streaming`` /
+#: ``dequant`` / ``same_dtype`` / ``targets_alias`` / ``oom``)
+SPAN_LOADER_RESIDENT_DTYPE = _span("loader.resident_dtype")
 
 # -- spans of the training path (histogram + profiler annotation) ------
 

@@ -39,8 +39,8 @@ Registered points (the call sites document their context keys):
 ``checkpoint.corrupt``      the GA generation checkpoint is truncated
                             (``gen``)
 ``device.oom_on_put``       a device upload raises RESOURCE_EXHAUSTED
-                            (``site`` = resident_dataset / stream /
-                            cohort)
+                            (``site`` = resident_dataset /
+                            resident_cast / stream / cohort)
 ``multihost.peer_exit``     this process hard-exits after multihost
                             init (``process``; knob: ``after`` secs)
 ``preempt.sigterm``         this process sends ITSELF a real SIGTERM

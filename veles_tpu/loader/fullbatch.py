@@ -9,6 +9,14 @@ fused step receives minibatch *indices* and gathers rows on-device
 (``jnp.take``) — minibatch assembly never touches the host after
 initialization.  The host ``fill_minibatch`` path remains for the numpy
 backend.
+
+In which form the store resides: the loader uploads it as loaded
+(float32, or uint8 under quantized ingest) and owns budget and
+placement; the consumer then names the dtype its step ingests
+(``FusedStepRunner.initialize`` -> ``reside_as``) and the loader casts
+the device buffer ONCE, at set-up, laying whole rows out contiguously
+for the in-step gather.  The host copy, labels and targets keep their
+dtype.
 """
 
 from __future__ import annotations
@@ -23,7 +31,11 @@ from veles_tpu.memory import Vector
 
 class FullBatchLoader(Loader):
     """Dataset fully resident; subclasses fill ``original_data`` /
-    ``original_labels`` in ``load_data``."""
+    ``original_labels`` in ``load_data``.  On a jax device the data
+    store sits in HBM in the dtype of the step that reads it, decided
+    at set-up by ``reside_as`` (bfloat16 under a bf16 step; float32 on
+    an f32 device, for a quantized uint8 store, and where the targets
+    alias the data)."""
 
     def __init__(self, workflow=None, **kwargs: Any) -> None:
         super().__init__(workflow, **kwargs)
@@ -75,6 +87,11 @@ class FullBatchLoader(Loader):
         #: float path computes ``normalizer.apply(mem * pre_scale)``
         #: (image decoders set 1/255; raw-byte arrays leave 1.0)
         self._quant_pre_scale = 1.0
+        #: (dtype, Future) of ``reside_as``'s program, compiling ahead
+        #: on a thread since ``initialize`` (see _compile_reside_ahead)
+        self._reside_ahead = None
+
+    _unpicklable = Loader._unpicklable + ("_reside_ahead",)
 
     def __setstate__(self, state: dict) -> None:
         super().__setstate__(state)
@@ -83,6 +100,7 @@ class FullBatchLoader(Loader):
         self.__dict__.setdefault("_quant_pre_scale", 1.0)
         self.__dict__.setdefault("mesh_shard", None)
         self.__dict__.setdefault("shard_resident", False)
+        self.__dict__.setdefault("_reside_ahead", None)
 
     @property
     def has_labels(self) -> bool:
@@ -272,6 +290,7 @@ class FullBatchLoader(Loader):
                         else:
                             v.initialize(device)
                             v.unmap()  # one-time HBM upload
+                self._compile_reside_ahead(device)
                 return
             except (KeyboardInterrupt, SystemExit):
                 raise
@@ -300,6 +319,132 @@ class FullBatchLoader(Loader):
                   self.original_targets):
             if v:
                 v.initialize(device if resident else None)
+
+    def _reside_bypass(self, dtype) -> Optional[str]:
+        """Why ``reside_as(dtype)`` leaves the store as it is (None:
+        it does not) — all of it read off the loader's own state."""
+        import jax.numpy as jnp
+        dev = self.original_data.devmem
+        if not self.device_resident or dev is None:
+            return "streaming"
+        if self.dequant is not None:
+            # a uint8 store is 1 byte a pixel already, and its ingest
+            # is f32 arithmetic
+            return "dequant"
+        if not jnp.issubdtype(dev.dtype, jnp.floating) or \
+                np.dtype(dev.dtype).itemsize <= np.dtype(dtype).itemsize:
+            return "same_dtype"
+        targets = self.original_targets
+        if targets and (targets.devmem is dev or (
+                targets.mem is not None
+                and targets.mem is self.original_data.mem)):
+            # autoencoders: the loss reads the SAME rows as f32 targets
+            return "targets_alias"
+        return None
+
+    def reside_as(self, dtype) -> None:
+        """Keep the resident ``original_data`` in HBM in the form the
+        step reads it: cast to ``dtype`` — the step's ingest dtype —
+        ONCE, here, on the device, with whole rows contiguous (axis 0
+        major-most, a row in the device's own layout of one sample).
+        Left to the step, the compiler hoists both out of its scan and
+        converts and transposes the WHOLE store on every superstep.
+        The values the step gathers are bit for bit the same: an
+        elementwise convert commutes with a row gather.
+
+        Placement survives (replicated, or row-sharded and padded),
+        labels and targets are not touched, a valid host copy stays
+        valid and float32.  Engages on what the loader can observe (no
+        knob): a floating store wider than ``dtype``, resident, not
+        quantized, not aliased by the targets.  If the device cannot
+        hold both stores for the moment of the cast, the wide one
+        stays.  Either way the decision is journaled
+        (``loader.resident_dtype``)."""
+        from veles_tpu import events, telemetry
+        store = self.original_data
+
+        def device_buffer():
+            dev = store.devmem if self.device_resident else None
+            if dev is None:
+                return None, 0
+            return np.dtype(dev.dtype).name, int(dev.nbytes)
+
+        with telemetry.span(events.SPAN_LOADER_RESIDENT_DTYPE,
+                            journal=True) as decision:
+            have, nbytes = device_buffer()
+            reason = self._reside_bypass(dtype)
+            if reason is None:
+                reason = self._cast_resident(dtype)
+            self._reside_ahead = None
+            now, after = device_buffer()
+            telemetry.gauge(events.GAUGE_LOADER_RESIDENT_BYTES).set(after)
+            decision.fields.update({
+                "from": have, "to": now,
+                "bytes_before": nbytes, "bytes_after": after})
+            if reason is not None:
+                decision.fields["reason"] = reason
+
+    def _compile_reside_ahead(self, device) -> None:
+        """Start compiling ``reside_as``'s program on a thread, for
+        the device's own compute dtype — what a step asks for unless
+        told otherwise.  The program may not come from the persistent
+        cache (``engine_core.not_persisted``) and a process's first
+        fresh compile takes half a second on the chip; begun here, it
+        runs beside the units' host-side parameter fill instead of in
+        front of the first step."""
+        dtype = np.dtype(device.compute_dtype)
+        if self._reside_bypass(dtype) is not None:
+            return
+        from concurrent.futures import ThreadPoolExecutor
+        pool = ThreadPoolExecutor(
+            1, thread_name_prefix=f"{self.name}-reside")
+        self._reside_ahead = (dtype, pool.submit(
+            _reside_program, self.original_data.devmem, dtype))
+        pool.shutdown(wait=False)
+
+    def _cast_resident(self, dtype) -> Optional[str]:
+        """``reside_as``'s one pass over the store: a jitted ``astype``
+        whose output keeps the buffer's sharding and lies rows
+        major-most; the Vector's device buffer is rebound to it and the
+        wide buffer goes with its last reference.  ``"oom"`` where the
+        device cannot hold both for the moment (the wide store stays,
+        as ``initialize`` degrades an upload), else None."""
+        import jax
+
+        from veles_tpu import events, faults, telemetry
+        store = self.original_data
+        dev = store.devmem
+        ahead = self._reside_ahead
+        if ahead is not None and ahead[0] == dtype:
+            reside, where = ahead[1].result()
+        else:
+            reside, where = _reside_program(dev, dtype)
+        try:
+            if faults.fire("device.oom_on_put", site="resident_cast"):
+                raise RuntimeError(
+                    "RESOURCE_EXHAUSTED: fault-injected OOM on the "
+                    "resident dataset cast")
+            narrow = reside(dev)
+            jax.block_until_ready(narrow)
+        except (KeyboardInterrupt, SystemExit):
+            raise
+        except Exception as e:  # noqa: BLE001 — degrade, see above
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            self.warning("casting the resident dataset to %s hit "
+                         "device OOM (%s) — it stays %s", dtype, e,
+                         dev.dtype)
+            return "oom"
+        # a re-upload after a host write lands in the same layout; of
+        # a row-sharded store it goes replicated through
+        # ``Device.put`` (see Vector.upload_row_sharded)
+        store.retype_devmem(
+            narrow, None if self.shard_resident else where)
+        telemetry.counter(events.CTR_LOADER_RESIDENT_CASTS).inc()
+        self.info("resident dataset cast %s -> %s on the device: "
+                  "%.1f -> %.1f MiB", dev.dtype, narrow.dtype,
+                  dev.nbytes / 2 ** 20, narrow.nbytes / 2 ** 20)
+        return None
 
     def create_minibatch_data(self) -> None:
         mb = self.max_minibatch_size
@@ -346,6 +491,10 @@ class FullBatchLoader(Loader):
         rows = self.original_data.map_read()[indices]
         if self.dequant is not None:
             rows = self.dequant.apply_host(rows)
+        elif rows.dtype.itemsize < 4 and rows.dtype.kind not in "iub":
+            # a device-born store that resides narrower than float32
+            # (``reside_as``) reads back in the device's dtype
+            rows = rows.astype(np.float32)
         return rows
 
     def assemble_rows(self, indices: np.ndarray):
@@ -359,6 +508,57 @@ class FullBatchLoader(Loader):
         targets = self.original_targets.mem[indices] \
             if self.has_targets else None
         return data, labels, targets
+
+
+def _reside_program(dev, dtype):
+    """(program, where): ``reside_as``'s cast compiled for ``dev``'s
+    shape and sharding — ``program(dev)`` is ``dev`` as ``dtype`` with
+    rows major-most — and the ``Format`` a re-upload of such a store
+    goes to (None: the device's default layout serves)."""
+    import jax
+
+    from veles_tpu.engine import core as engine_core
+    layout = _row_major_layout(dev, dtype)
+    out = dev.sharding
+    if layout is not None:
+        from jax.experimental.layout import Format
+        out = Format(layout, out)
+
+    def reside(rows):
+        return rows.astype(dtype)
+
+    # an output in a layout of its own: see not_persisted
+    with engine_core.not_persisted():
+        program = engine_core.donating_jit(
+            reside, out_shardings=out).lower(jax.ShapeDtypeStruct(
+                dev.shape, dev.dtype, sharding=dev.sharding)).compile()
+    return program, out if layout is not None else None
+
+
+def _row_major_layout(dev, dtype):
+    """The device layout in which a store of ``dev``'s shape lies best
+    for a row gather: axis 0 major-most, each row in the layout the
+    device gives ONE sample of ``dtype`` by default (its padding-free
+    choice).  The device's default for the whole array is the other
+    way round wherever the row count tiles better than the sample
+    (TPU: rows minor-most for every rank-4 array), and a gather of
+    whole rows then re-lays the whole store out.  None where that
+    default has rows major-most already (XLA:CPU), or the backend
+    shows no layouts."""
+    from jax.experimental.layout import Layout
+
+    from veles_tpu.engine import core as engine_core
+    sample = engine_core.put(
+        np.zeros(dev.shape[1:], dtype),
+        next(iter(dev.sharding.device_set))).format.layout
+    whole = dev.format.layout
+    if sample is None or whole is None:
+        return None
+    major_to_minor = (0,) + tuple(
+        1 + axis for axis in sample.major_to_minor)
+    if major_to_minor == tuple(whole.major_to_minor):
+        return None
+    return Layout(major_to_minor=major_to_minor, tiling=sample.tiling)
 
 
 class DeviceArrayLoader(FullBatchLoader):

@@ -13,6 +13,12 @@ donated, giving in-place update semantics in HBM; SURVEY.md §7 "in-place
 weight updates").  The map/unmap protocol survives as the host-coherence
 contract, and its invariant checks catch stale-host-read bugs that the
 reference's assertions caught.
+
+The device mirror may be NARROWER than the host array: a resident
+dataset sits in HBM in the dtype (and row layout) the fused step reads
+(``retype_devmem``, asked for by ``FullBatchLoader.reside_as``) while
+its host copy stays float32.  ``unmap()`` after a host write then
+re-uploads in the mirror's form, cast on the host.
 """
 
 from __future__ import annotations
@@ -34,6 +40,10 @@ class Vector:
         self.name = name
         self._mem: Optional[np.ndarray] = None
         self._devmem: Any = None
+        #: (dtype, placement) of a device mirror that ``retype_devmem``
+        #: made narrower than the host array; None = the mirror is the
+        #: host array as uploaded
+        self._mirror: Optional[Tuple[np.dtype, Any]] = None
         self._valid = 0
         self.device = None
         if data is not None:
@@ -47,6 +57,7 @@ class Vector:
 
     @mem.setter
     def mem(self, value: Optional[np.ndarray]) -> None:
+        self._mirror = None     # a new host array: a new vector
         if value is None:
             self._mem = None
             self._valid = 0
@@ -152,7 +163,20 @@ class Vector:
         """Rebind the device buffer (a jitted step's output) and mark the
         host copy stale — the TPU analogue of a device-side write."""
         self._devmem = value
+        self._mirror = None
         self._valid = DEVICE if value is not None else (self._valid & HOST)
+
+    def retype_devmem(self, value: Any, where: Any = None) -> None:
+        """Rebind the device buffer to the SAME values in a narrower
+        dtype (``value``: the old buffer cast on the device).  Not a
+        device write: a valid host copy stays valid and keeps its own
+        dtype.  From here on ``unmap()`` after a host write uploads in
+        ``value``'s dtype, cast on the host, to ``where`` (a placement
+        ``Device.put`` takes; None = the device's own) — a stale
+        mirror never comes back wider than the one it replaces.  Holds
+        until the host array or the device buffer is replaced."""
+        self._devmem = value
+        self._mirror = (np.dtype(value.dtype), where)
 
     # -- coherence protocol -------------------------------------------
 
@@ -195,7 +219,12 @@ class Vector:
         if not self._valid & DEVICE:
             if self._mem is None:
                 raise RuntimeError(f"Vector '{self.name}': nothing valid")
-            self._devmem = self.device.put(self._mem)
+            if self._mirror is None:
+                self._devmem = self.device.put(self._mem)
+            else:
+                dtype, where = self._mirror
+                self._devmem = self.device.put(
+                    self._mem.astype(dtype), where)
             self._valid = HOST | DEVICE
         return self._devmem
 
@@ -210,6 +239,7 @@ class Vector:
         self.name = state["name"]
         self._mem = state["mem"]
         self._devmem = None
+        self._mirror = None
         self.device = None
         self._valid = HOST if self._mem is not None else 0
 

@@ -18,6 +18,14 @@ class end instead of 3 scalars per minibatch.  Matmuls/convs run in
 the device's ``compute_dtype`` (bfloat16 on TPU — the MXU's native
 format) against float32 master weights.
 
+The input arrives in that dtype too, and the adapter asks for it at
+``initialize``: a streaming loader assembles its batches in it
+(``loader.stream_dtype``), a resident loader casts its HBM store to it
+once (``loader.reside_as``; the loader decides and owns the store).
+The trace's own ``astype`` is then a no-op on every row — traced over
+a float32 store it is hoisted out of the scan and re-casts the whole
+store on every superstep.
+
 ``FusedStepRunner`` is a drop-in graph node: it sits where the
 forwards+evaluator+gds chain would, reads the loader's minibatch
 indices, and rebinds every unit's Vectors (weights, output, metrics) to
@@ -409,6 +417,12 @@ class FusedStepRunner(AcceleratedUnit):
     # -- lifecycle -----------------------------------------------------
 
     def initialize(self, device=None, **kwargs) -> None:
+        """Resolve how the data reaches the step — streaming or
+        resident, replicated or row-sharded: the loader's decisions,
+        followed here — build the jitted steps, and tell the loader
+        the dtype the step ingests: ``stream_dtype`` for the batches a
+        streaming loader assembles, ``reside_as`` for the HBM store of
+        a resident one, which from then on IS in that dtype."""
         super().initialize(device=device, **kwargs)
         if not any(self.loader.class_lengths):
             # Workflow.initialize retries on AttributeError — the
@@ -452,6 +466,14 @@ class FusedStepRunner(AcceleratedUnit):
         if self._train_step is None:
             with telemetry.span(events.SPAN_FUSED_BUILD_STEPS):
                 self._build_steps()
+        reside_as = getattr(self.loader, "reside_as", None)
+        if reside_as is not None and self.device.is_jax:
+            # the resident counterpart of ``stream_dtype``; the loader
+            # decides whether it engages (never while streaming) and
+            # journals why not.  Left as uploaded, the store is cast
+            # and re-laid out WHOLE on every superstep: XLA hoists both
+            # out of the scan (AlexNet, v5e: 14.7 % of device time)
+            reside_as(np.dtype(self._resolved_dtype()))
 
     def _target_store(self):
         ld = self.loader

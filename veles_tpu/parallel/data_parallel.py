@@ -54,7 +54,7 @@ class MeshJaxDevice(JaxDevice):
     def n_devices(self) -> int:
         return int(self.mesh.devices.size)
 
-    def put(self, array) -> Any:
+    def put(self, array, where: Any = None) -> Any:
         import numpy as np
         # dtype-preserving like JaxDevice.put: a quantized loader's
         # uint8 dataset replicates at 1 byte/element per device, and
@@ -64,7 +64,8 @@ class MeshJaxDevice(JaxDevice):
         # replicated = one physical copy PER device
         self.h2d_bytes += arr.nbytes * self.n_devices
         from veles_tpu.engine import core as engine_core
-        return engine_core.put(arr, self._repl)
+        return engine_core.put(
+            arr, self._repl if where is None else where)
 
     def put_sharded(self, array) -> Any:
         """Upload with the leading axis split 1/N per device (rows
