@@ -189,36 +189,141 @@ def build_ingest(dequant: Any):
     return ingest
 
 
-def build_forward(forwards, seed: int, compute_dtype):
+def build_forward(forwards, seed: int, compute_dtype,
+                  recompute: bool = False):
     """One model's forward chain WITH residuals — the train-mode body
     every loop traces.  The rng key chain (``fold_in(fold_in(key(seed),
     rc), i)`` per stochastic layer) is the repo-wide dropout contract:
     cohort members, the online shadow, and the oracle replay all hash
-    the same stream."""
+    the same stream.
+
+    The chain's shape is the units' own (:func:`chain_of`): a layer,
+    or a **residual** entry ``x + f_k(...f_1(x))`` (the add in f32).
+    With no residual entry it is the line, and traces the program it
+    always did.  With ``recompute`` a residual
+    entry keeps only its INPUT (and the rng counter) as the residual
+    of its first layer: :func:`build_backward` re-runs the entry's
+    forward inside its backward instead of keeping every layer's
+    residuals for the whole chain."""
     import jax
 
     mixed = _not_f32(compute_dtype)
+    chain = chain_of(forwards)
 
     def forward_pass(params, x, rng_counter, train: bool):
-        residuals = []
-        if mixed:
-            with jax.named_scope("ingest"):
+        residuals = [None] * len(forwards)
+        if mixed and not _is_ids(x):
+            with jax.named_scope(events.SCOPE_INGEST):
                 x = x.astype(compute_dtype)
-        for i, f in enumerate(forwards):
-            # every device op carries its layer in its metadata
-            # (``fwd/<layer>``; the backward walk: ``bwd/``,
-            # ``update/``) — metadata only, the program is the same
-            with jax.named_scope("fwd/" + f.name):
-                rng = jax.random.fold_in(
-                    jax.random.fold_in(jax.random.key(seed),
-                                       rng_counter), i) \
-                    if f.stochastic else None
-                x, res = f.apply_fwd(params[f.name], x, rng=rng,
-                                     train=train)
-            residuals.append(res)
+        for entry in chain:
+            skip = x
+            for i in _layers_of(entry):
+                f = forwards[i]
+                # every device op carries its layer in its metadata
+                # (``fwd/<layer>``; the backward walk: ``bwd/``,
+                # ``update/``) — metadata only, the program is the same
+                with jax.named_scope("fwd/" + f.name):
+                    rng = jax.random.fold_in(
+                        jax.random.fold_in(jax.random.key(seed),
+                                           rng_counter), i) \
+                        if f.stochastic else None
+                    x, residuals[i] = f.apply_fwd(
+                        params[f.name], x, rng=rng, train=train)
+            if isinstance(entry, int):
+                continue
+            x = _skip_add(skip, x)
+            if recompute and train:
+                for i in entry:
+                    residuals[i] = None
+                residuals[entry[0]] = {"recompute_from": skip,
+                                       "rng_counter": rng_counter}
         return x, residuals
 
     return forward_pass
+
+
+def chain_of(forwards):
+    """The chain's entries over the indices of ``forwards``: an int for
+    a layer of the line, a tuple of ints for a residual entry — the
+    run of neighbours that carry the same ``residual_of`` mark
+    (``StandardWorkflow`` sets it from the ``layers`` list).  Read from
+    the units, so that no engine that walks a list of forwards can
+    drop a skip path."""
+    chain: list = []
+    for i, f in enumerate(forwards):
+        mark = getattr(f, "residual_of", None)
+        if mark is None:
+            chain.append(i)
+        elif i and isinstance(chain[-1], tuple) and \
+                getattr(forwards[i - 1], "residual_of", None) == mark:
+            chain[-1] += (i,)
+        else:
+            chain.append((i,))
+    return chain
+
+
+def has_residual(forwards) -> bool:
+    return any(getattr(f, "residual_of", None) is not None
+               for f in forwards)
+
+
+def _is_ids(x) -> bool:
+    """ids stay ids: an integer row is no activation to be cast (bf16
+    cannot hold 257)."""
+    return np.dtype(x.dtype).kind in "iub"
+
+
+def _layers_of(entry):
+    return (entry,) if isinstance(entry, int) else entry
+
+
+def _skip_add(skip, x):
+    """A residual entry's add (and its backward's: the error splits
+    and adds), in f32 whatever the compute dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope(events.SCOPE_SKIP):
+        return (skip.astype(jnp.float32)
+                + x.astype(jnp.float32)).astype(skip.dtype)
+
+
+def kept_activation_bytes(forwards, compute_dtype, params, x):
+    """(kept, kept_recomputing): bytes of the residuals the forward
+    chain keeps for its backward when every layer keeps its own, and
+    when every residual entry keeps only its input (plus, at any one
+    time, the residuals of the ONE entry being re-run).  From shapes
+    alone (``jax.eval_shape``; ``params`` / ``x`` may be
+    ``ShapeDtypeStruct``); a residual leaf shaped like one of the
+    layer's own parameters is the parameter, not an activation.  What
+    jax would keep, before XLA fuses or re-makes any of it: an upper
+    bound, good for deciding, not a reading of the allocator."""
+    import jax
+
+    def residuals(recompute):
+        fwd = build_forward(forwards, 0, compute_dtype, recompute)
+        return jax.eval_shape(lambda p, xx: fwd(p, xx, 0, True)[1],
+                              params, x)
+
+    def nbytes(tree, skip=()):
+        return sum(int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
+                   for a in jax.tree_util.tree_leaves(tree)
+                   if (a.shape, a.dtype) not in skip)
+
+    per_layer = []
+    for f, r in zip(forwards, residuals(False)):
+        own = {(a.shape, a.dtype)
+               for a in jax.tree_util.tree_leaves(params[f.name])}
+        per_layer.append(nbytes(r, own))
+    inputs = residuals(True)
+    recomputing = rerun = 0
+    for entry in chain_of(forwards):
+        if isinstance(entry, int):
+            recomputing += per_layer[entry]
+        else:
+            recomputing += nbytes(inputs[entry[0]])
+            rerun = max(rerun, sum(per_layer[i] for i in entry))
+    return sum(per_layer), recomputing + rerun
 
 
 def _not_f32(compute_dtype) -> bool:
@@ -227,16 +332,26 @@ def _not_f32(compute_dtype) -> bool:
     return compute_dtype != jnp.float32
 
 
-def build_backward(forwards, gds, compute_dtype):
+def build_backward(forwards, gds, compute_dtype, seed: int = 0):
     """The backward + SGD chain: walk the gradient units in reverse,
     skip the chain-head err_input when nothing consumes it, and apply
     ``update_params`` with the per-call (lr, bias-lr) row — plus the
     per-member (wd, bias-wd) row when the caller supplies one (the
     population engine's decays contract; ``decays=None`` omits the
-    kwarg entirely, matching the single-model loops exactly)."""
-    import jax
+    kwarg entirely, matching the single-model loops exactly).
 
-    n_fwd = len(forwards)
+    The chain is :func:`build_forward`'s.  Behind a residual
+    entry the error splits — one copy walks back through the entry's
+    layers, the other round them — and the two add at its input.  An
+    entry whose forward kept only its input (``recompute``) first
+    re-runs its layers' forward here, under ``bwd/<layer>/recompute``,
+    behind a barrier that ties the re-run to the error's arrival (so
+    the compiler can neither share it with the first forward nor run
+    it early); ``seed`` is the forward's, for the same rng keys."""
+    import jax
+    from jax import lax
+
+    chain = chain_of(forwards)
     first_gd = next((i for i, g in enumerate(gds) if g is not None),
                     -1)
     mixed = _not_f32(compute_dtype)
@@ -247,39 +362,58 @@ def build_backward(forwards, gds, compute_dtype):
             err = err.astype(compute_dtype)
         new_params = dict(params)
         new_opt = dict(opt)
-        for i in range(n_fwd - 1, -1, -1):
-            f, gd = forwards[i], gds[i]
-            if gd is None:
-                continue
-            with jax.named_scope("bwd/" + f.name):
-                if i == first_gd and gd.can_skip_err_input:
-                    # nothing consumes the chain-head err_input; for
-                    # conv1 this skips the input-dilated transposed
-                    # conv (the worst MXU op here)
-                    _, grads = gd.backward_from_saved(
-                        cparams[f.name], residuals[i], err,
-                        need_err_input=False)
-                    err_in = None
-                else:
-                    err_in, grads = gd.backward_from_saved(
-                        cparams[f.name], residuals[i], err)
-            if grads:
-                with jax.named_scope("update/" + f.name):
-                    if wd is None:
-                        p, v = gd.update_params(
-                            params[f.name], grads,
-                            opt.get(gd.name, {}),
-                            rates=(lr[i, 0], lr[i, 1]))
+        for entry in reversed(chain):
+            skip_err = err
+            saved = residuals[_layers_of(entry)[0]]
+            if isinstance(saved, dict) and "recompute_from" in saved:
+                x, err = lax.optimization_barrier(
+                    (saved["recompute_from"], err))
+                residuals = list(residuals)
+                for i in entry:
+                    f = forwards[i]
+                    with jax.named_scope("bwd/" + f.name), \
+                            jax.named_scope(events.SCOPE_RECOMPUTE):
+                        rng = jax.random.fold_in(
+                            jax.random.fold_in(jax.random.key(seed),
+                                               saved["rng_counter"]),
+                            i) if f.stochastic else None
+                        x, residuals[i] = f.apply_fwd(
+                            cparams[f.name], x, rng=rng, train=True)
+            for i in reversed(_layers_of(entry)):
+                f, gd = forwards[i], gds[i]
+                if gd is None:
+                    continue
+                with jax.named_scope("bwd/" + f.name):
+                    if i == first_gd and gd.can_skip_err_input:
+                        # nothing consumes the chain-head err_input;
+                        # for conv1 this skips the input-dilated
+                        # transposed conv (the worst MXU op here)
+                        _, grads = gd.backward_from_saved(
+                            cparams[f.name], residuals[i], err,
+                            need_err_input=False)
+                        err_in = None
                     else:
-                        p, v = gd.update_params(
-                            params[f.name], grads,
-                            opt.get(gd.name, {}),
-                            rates=(lr[i, 0], lr[i, 1]),
-                            decays=(wd[i, 0], wd[i, 1]))
-                new_params[f.name] = p
-                if gd.name in opt:
-                    new_opt[gd.name] = v
-            err = err_in
+                        err_in, grads = gd.backward_from_saved(
+                            cparams[f.name], residuals[i], err)
+                if grads:
+                    with jax.named_scope("update/" + f.name):
+                        if wd is None:
+                            p, v = gd.update_params(
+                                params[f.name], grads,
+                                opt.get(gd.name, {}),
+                                rates=(lr[i, 0], lr[i, 1]))
+                        else:
+                            p, v = gd.update_params(
+                                params[f.name], grads,
+                                opt.get(gd.name, {}),
+                                rates=(lr[i, 0], lr[i, 1]),
+                                decays=(wd[i, 0], wd[i, 1]))
+                    new_params[f.name] = p
+                    if gd.name in opt:
+                        new_opt[gd.name] = v
+                err = err_in
+            if not isinstance(entry, int) and err is not None:
+                err = _skip_add(skip_err, err)
         return new_params, new_opt
 
     return backward_update
@@ -288,31 +422,40 @@ def build_backward(forwards, gds, compute_dtype):
 def build_member_forward(forwards, compute_dtype):
     """One member's pure inference chain (no rng, f32 output) — the
     body vmapped over a stacked member axis by the ensemble
-    dispatchers and the shadow scorer."""
+    dispatchers and the shadow scorer.  The chain and the integer
+    rows are :func:`build_forward`'s: a residual entry adds its skip,
+    ids stay ids."""
     import jax
     import jax.numpy as jnp
 
     mixed = _not_f32(compute_dtype)
+    chain = chain_of(forwards)
+
+    def layer(f, params, x):
+        if getattr(f, "activation_mode", None) == "softmax":
+            # a softmax head under the stacked member axis is
+            # split at its logits: libtpu 0.0.34's compiler
+            # overflows its stack (SIGSEGV, no Python error)
+            # fusing the softmax reduction into the
+            # member-batched matmul for some member counts —
+            # every 3-member head tried, 3 to 1000 classes (PR 21;
+            # tests/test_tpu_compile.py compiles them without a
+            # chip).  The barrier keeps the two apart; the
+            # single-model train path is untouched.
+            return f.activation(jax.lax.optimization_barrier(
+                f.pre_activation(params[f.name], x)))
+        return f.apply_fwd(params[f.name], x, rng=None,
+                           train=False)[0]
 
     def member_forward(params, x):
-        if mixed:
+        if mixed and not _is_ids(x):
             x = x.astype(compute_dtype)
-        for f in forwards:
-            if getattr(f, "activation_mode", None) == "softmax":
-                # a softmax head under the stacked member axis is
-                # split at its logits: libtpu 0.0.34's compiler
-                # overflows its stack (SIGSEGV, no Python error)
-                # fusing the softmax reduction into the
-                # member-batched matmul for some member counts —
-                # every 3-member head tried, 3 to 1000 classes (PR 21;
-                # tests/test_tpu_compile.py compiles them without a
-                # chip).  The barrier keeps the two apart; the
-                # single-model train path is untouched.
-                x = f.activation(jax.lax.optimization_barrier(
-                    f.pre_activation(params[f.name], x)))
-            else:
-                x, _ = f.apply_fwd(params[f.name], x, rng=None,
-                                   train=False)
+        for entry in chain:
+            skip = x
+            for i in _layers_of(entry):
+                x = layer(forwards[i], params, x)
+            if not isinstance(entry, int):
+                x = _skip_add(skip, x)
         return x.astype(jnp.float32)
 
     return member_forward

@@ -71,6 +71,7 @@ def _timed(name: str) -> str:
 
 EV_FUSED_FIRST_DISPATCH = _ev("fused.first_dispatch")
 EV_FUSED_SUMMARY = _ev("fused.summary")
+EV_FUSED_RECOMPUTE = _ev("fused.recompute")
 
 #: one per backend compile OR persistent-cache load of a program
 #: (jax.monitoring reports both under one name): ``seconds``, the
@@ -165,6 +166,7 @@ EV_SUPERVISOR_GIVEUP = _ev("supervisor.giveup")
 
 CTR_FUSED_DISPATCHES = _ctr("fused.dispatches")
 CTR_FUSED_MINIBATCHES = _ctr("fused.minibatches")
+CTR_FUSED_TRAIN_TOKENS = _ctr("fused.train_tokens")
 CTR_FUSED_STREAM_TRANSFER_BYTES = _ctr("fused.stream_transfer_bytes")
 CTR_FUSED_STREAM_TRANSFER_SECONDS = _ctr(
     "fused.stream_transfer_seconds")
@@ -265,6 +267,10 @@ CTR_SUPERVISOR_RESTARTS = _ctr("supervisor.restarts")
 # -- gauges ------------------------------------------------------------
 
 GAUGE_FUSED_MFU = _gauge("fused.mfu")
+GAUGE_FUSED_KEPT_ACTIVATION_BYTES = _gauge("fused.kept_activation_bytes")
+GAUGE_EVA_WINDOW = _gauge("eva.window")
+GAUGE_EVA_CHUNK = _gauge("eva.chunk")
+GAUGE_EVA_SUMMARIES_PER_ROW = _gauge("eva.summaries_per_row")
 GAUGE_FUSED_TRAIN_GFLOPS_PER_IMAGE = _gauge(
     "fused.train_gflops_per_image")
 GAUGE_FUSED_TRAIN_IMAGES_PER_SEC_WALL = _gauge(
@@ -374,6 +380,32 @@ SPAN_FUSED_FETCH_METRICS = _timed("fused.fetch_metrics")
 #: health column): a ``fleet.replica.<i>.health_score`` gauge and a
 #: ``fleet.replica.<i>.hedge_wins`` counter, where <i> is the replica
 #: index
+# -- device scopes ------------------------------------------------------
+# ``jax.named_scope`` names: metadata of the device ops the step
+# program traces (the ``tf_op`` stat of a profiler trace's events), not
+# registry entries.  Per layer: ``fwd/<layer>``, ``bwd/<layer>``,
+# ``update/<layer>`` (engine/core.py); a recomputed forward runs under
+# ``bwd/<layer>/recompute``.
+
+SCOPES: Set[str] = set()
+
+
+def _scope(name: str) -> str:
+    SCOPES.add(name)
+    return name
+
+
+SCOPE_GATHER = _scope("gather")
+SCOPE_INGEST = _scope("ingest")
+SCOPE_CAST_PARAMS = _scope("cast_params")
+SCOPE_LOSS = _scope("loss")
+SCOPE_SKIP = _scope("skip")
+SCOPE_RECOMPUTE = _scope("recompute")
+SCOPE_EVA_SUMMARIES = _scope("eva/summaries")
+SCOPE_EVA_LOCAL = _scope("eva/local")
+SCOPE_EVA_REMOTE = _scope("eva/remote")
+
+
 DYNAMIC_FAMILIES = (
     "fused.<kind>_submit",
     "fused.first_<kind>_submit",
@@ -399,7 +431,7 @@ DYNAMIC_FAMILIES = (
 def known(name: str) -> bool:
     """Is ``name`` declared in any telemetry namespace?"""
     return name in EVENTS or name in COUNTERS or name in GAUGES \
-        or name in HISTOGRAMS or name in SPANS
+        or name in HISTOGRAMS or name in SPANS or name in SCOPES
 
 
 def all_names() -> frozenset:

@@ -108,6 +108,10 @@ def export_model(workflow, path: str) -> str:
     forwards: List[Any] = list(workflow.forwards)
     if not forwards:
         raise ValueError("workflow has no forward units")
+    if any(getattr(u, "residual_of", None) is not None
+           for u in forwards):
+        raise ValueError("a residual entry has no native inference "
+                         "equivalent (the format is a plain line)")
     fused = getattr(workflow, "fused", None)
     if fused is not None and fused._params is not None:
         fused.sync_params_to_vectors()  # pull trained HBM state to host

@@ -166,12 +166,16 @@ def _structure_sig(workflow):
     otherwise silently train as somebody else's genome."""
     from veles_tpu.genetics.core import LIFTABLE_HYPERS
     sig = []
-    for cfg in getattr(workflow, "layers_config", []):
+    for cfg in getattr(workflow, "flat_layers", None) or \
+            getattr(workflow, "layers_config", []):
         back = {k: v for k, v in dict(cfg.get("<-", {})).items()
                 if k not in LIFTABLE_HYPERS}
         sig.append((cfg.get("type"),
                     repr(sorted(dict(cfg.get("->", {})).items())),
                     repr(sorted(back.items()))))
+    # which layers a residual entry spans is structure too
+    sig.append(tuple(getattr(f, "residual_of", None)
+                     for f in getattr(workflow, "forwards", [])))
     return tuple(sig)
 
 

@@ -331,8 +331,11 @@ class FullBatchLoader(Loader):
             # a uint8 store is 1 byte a pixel already, and its ingest
             # is f32 arithmetic
             return "dequant"
-        if not jnp.issubdtype(dev.dtype, jnp.floating) or \
-                np.dtype(dev.dtype).itemsize <= np.dtype(dtype).itemsize:
+        if not jnp.issubdtype(dev.dtype, jnp.floating):
+            # token ids and other integer rows are no activations: the
+            # step reads them as they are (320 ids do not fit bfloat16)
+            return "integer"
+        if np.dtype(dev.dtype).itemsize <= np.dtype(dtype).itemsize:
             return "same_dtype"
         targets = self.original_targets
         if targets and (targets.devmem is dev or (

@@ -165,3 +165,32 @@ class Cifar10Loader(_RealFileMixin, SyntheticClassificationLoader):
 
     def load_data(self) -> None:
         self._load_real_or_synthetic(datasets.try_load_real_cifar10())
+
+
+class PackedBytesLoader(FullBatchLoader):
+    """Rows of packed byte documents (``datasets.
+    synthetic_packed_bytes``): an INTEGER store ``[rows, seq_len]`` and
+    nothing else — a next-token loss reads its targets off the rows
+    themselves, so there is no label and no target store.  Regenerated
+    in ``load_data`` from the constructor args."""
+
+    def __init__(self, workflow=None, n_train: int = 4,
+                 n_valid: int = 0, seq_len: int = 128,
+                 median_len: int = 2048, seed: int = 320320,
+                 **kwargs: Any) -> None:
+        super().__init__(workflow, **kwargs)
+        self.gen_args = dict(n_train=n_train, n_valid=n_valid,
+                             seq_len=seq_len, median_len=median_len,
+                             seed=seed)
+
+    def load_data(self) -> None:
+        a = self.gen_args
+        self.class_lengths[TEST] = 0
+        self.class_lengths[VALID] = a["n_valid"]
+        self.class_lengths[TRAIN] = a["n_train"]
+        self.original_data.mem = datasets.synthetic_packed_bytes(
+            a["n_valid"] + a["n_train"], a["seq_len"], a["seed"],
+            median_len=a["median_len"])
+
+    def __getstate__(self) -> dict:
+        return self.getstate_dropping("original_data")

@@ -223,8 +223,11 @@ class Vector:
                 self._devmem = self.device.put(self._mem)
             else:
                 dtype, where = self._mirror
-                self._devmem = self.device.put(
-                    self._mem.astype(dtype), where)
+                # only a floating host array is ever narrowed on its
+                # way up: integer rows (token ids) upload as they are
+                host = self._mem.astype(dtype) \
+                    if self._mem.dtype.kind not in "iub" else self._mem
+                self._devmem = self.device.put(host, where)
             self._valid = HOST | DEVICE
         return self._devmem
 

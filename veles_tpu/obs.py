@@ -32,6 +32,11 @@ from veles_tpu.telemetry import Registry
 THROUGHPUT_ROWS = (
     ("fused train", "fused.train_images", "fused.train_wall_seconds",
      "img/s"),
+    # rows of a sequence model are packed sequences: tokens a second
+    # beside rows a second (the counter stays 0 for image models, and
+    # a row of zero work does not print)
+    ("fused train tokens", "fused.train_tokens",
+     "fused.train_wall_seconds", "tokens/s"),
     ("fused eval", "fused.eval_images", "fused.eval_wall_seconds",
      "img/s"),
     ("ensemble", "ensemble.member_images", "ensemble.seconds",

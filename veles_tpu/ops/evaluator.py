@@ -40,7 +40,11 @@ class EvaluatorBase(AcceleratedUnit):
         in_shape = self.input.shape
         if not self.err_output:
             self.err_output.mem = np.zeros(in_shape, np.float32)
-        for v in (self.err_output, self.n_err, self.loss, self.count):
+        # err_output is scratch (written by every firing before a read):
+        # no upload of its zeros — at a sequence model's logits they are
+        # hundreds of MB of HBM that nothing would ever read
+        self.err_output.initialize(device, upload=False)
+        for v in (self.n_err, self.loss, self.count):
             v.initialize(device)
 
     def metrics_fn(self, output: Any, target: Any, mask: Any) \
@@ -138,6 +142,60 @@ class EvaluatorSoftmax(EvaluatorBase):
             mask = np.asarray(self.mask.map_read())
             valid = mask > 0
             np.add.at(self.confusion.mem, (target[valid], pred[valid]), 1)
+
+
+class EvaluatorNextByte(EvaluatorBase):
+    """Next-byte cross-entropy at every position for every prediction
+    head (``loss_function="next_byte"``).
+
+    ``input`` holds the head's f32 logits ``[rows, T, heads, vocab]``;
+    the targets are the row of ids itself (``targets_from_data``: the
+    fused step hands the data store in as the target store, no second
+    store exists): head j at position n predicts id n + 1 + j, valid
+    where that id exists.  ``loss_sum`` is the summed cross-entropy of
+    the valid (row, n, j), ``count`` their number — so Decision's loss
+    is the mean per prediction — ``n_err`` the wrong arg-max ones.
+    err_output = (softmax - onehot) * valid / count: d(mean CE)/d
+    logits."""
+
+    targets_from_data = True
+
+    def __init__(self, workflow=None, **kwargs: Any) -> None:
+        super().__init__(workflow, **kwargs)
+        self.target = Vector(name=f"{self.name}.target")
+        self.mask = Vector(name=f"{self.name}.mask")
+
+    def metrics_fn(self, output, target, mask):
+        import jax
+        import jax.numpy as jnp
+        t, heads = output.shape[1], output.shape[2]
+        pos = jnp.arange(t)[:, None] + 1 + jnp.arange(heads)[None, :]
+        tgt = jnp.asarray(target)[:, jnp.minimum(pos, t - 1)]
+        valid = (pos < t)[None] & (jnp.asarray(mask) > 0)[:, None, None]
+        n = valid.sum().astype(jnp.float32)
+        logp = jax.nn.log_softmax(jnp.asarray(output), axis=-1)
+        picked = jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
+        loss_sum = -jnp.sum(jnp.where(valid, picked, 0.0))
+        onehot = jax.nn.one_hot(tgt, output.shape[-1], dtype=logp.dtype)
+        err = (jnp.exp(logp) - onehot) \
+            * (valid[..., None] / jnp.maximum(n, 1.0))
+        n_err = ((logp.argmax(-1) != tgt) & valid).sum()
+        return {"err_output": err.astype(jnp.float32),
+                "n_err": n_err.astype(jnp.float32),
+                "loss_sum": loss_sum.astype(jnp.float32),
+                "count": n}
+
+    def run(self) -> None:
+        if self._compiled is None:
+            self._compiled = self.device.compile(self.metrics_fn) \
+                if self.device is not None and self.device.is_jax \
+                else self.metrics_fn
+        m = self._compiled(self.input.unmap(), self.target.unmap(),
+                           self.mask.unmap())
+        self.err_output.devmem = m["err_output"]
+        self.n_err.devmem = m["n_err"]
+        self.loss.devmem = m["loss_sum"]
+        self.count.devmem = m["count"]
 
 
 class EvaluatorMSE(EvaluatorBase):

@@ -189,6 +189,10 @@ class FusedStepRunner(AcceleratedUnit):
     def _has_targets(self) -> bool:
         return hasattr(self.evaluator, "target")
 
+    def _targets_are_rows(self) -> bool:
+        """A next-token loss reads its targets off the data rows."""
+        return getattr(self.evaluator, "targets_from_data", False)
+
     def _want_confusion(self) -> bool:
         ev = self.evaluator
         return bool(getattr(ev, "compute_confusion", False)) and \
@@ -229,8 +233,11 @@ class FusedStepRunner(AcceleratedUnit):
         # pre-refactor loop did (parity pinned by test_engine_core)
         ingest = engine_core.build_ingest(
             getattr(self.loader, "dequant", None))
-        forward_pass = engine_core.build_forward(forwards, seed, cd)
-        backward_update = engine_core.build_backward(forwards, gds, cd)
+        recompute = self._decide_recompute(cd)
+        forward_pass = engine_core.build_forward(
+            forwards, seed, cd, recompute)
+        backward_update = engine_core.build_backward(
+            forwards, gds, cd, seed)
 
         cast = batching.make_caster(cd)
         # the parts of a step round the layers' own ``fwd/``, ``bwd/``
@@ -414,6 +421,69 @@ class FusedStepRunner(AcceleratedUnit):
                                         donate=(0, 1, 2, 3))
             self._eval_step = core.jit(eval_step, donate=(1, 2))
 
+    def _device_bytes_limit(self) -> Optional[int]:
+        """One device's memory as its allocator reports it (None where
+        it reports none: XLA:CPU)."""
+        jdev = getattr(self.device, "jax_device", None)
+        stats = jdev.memory_stats() if jdev is not None else None
+        return int(stats["bytes_limit"]) \
+            if stats and stats.get("bytes_limit") else None
+
+    def _decide_recompute(self, cd) -> bool:
+        """Whether the chain's residual entries keep only their inputs
+        and re-run their forward inside the backward walk.  Decided
+        from what the program can observe, no knob: the bytes of
+        residuals the chain would keep (from shapes) against what the
+        device has left beside the state — the units' parameters and
+        optimiser state as they hold them, the parameters' copy in the
+        compute dtype, a resident store.  Kept residuals may take half
+        of that (the other half is the backward's own working set).
+        Journaled either way (``fused.recompute``)."""
+        from veles_tpu.engine import core as engine_core
+        if not engine_core.has_residual(self.forwards):
+            return False
+        import jax
+
+        n_dev = int(self.mesh.devices.size) if self.mesh is not None \
+            else 1
+        ld = self.loader
+        x = jax.ShapeDtypeStruct(
+            (max(1, ld.max_minibatch_size // n_dev),)
+            + tuple(ld.minibatch_data.shape[1:]),
+            ld.minibatch_data.dtype)
+        pvecs = {f.name: {k: v for k, v in f.param_vectors().items()
+                          if v} for f in self.forwards}
+        cparams = {
+            name: {k: jax.ShapeDtypeStruct(tuple(v.shape), cd)
+                   for k, v in vecs.items()}
+            for name, vecs in pvecs.items()}
+        kept, kept_recomputing = engine_core.kept_activation_bytes(
+            self.forwards, cd, cparams, x)
+        state = sum(v.nbytes + v.size * np.dtype(cd).itemsize
+                    for vecs in pvecs.values() for v in vecs.values())
+        state += sum(gd.opt_nbytes() for gd in self.gds
+                     if gd is not None)
+        if not self.streaming and ld.original_data:
+            state += ld.original_data.nbytes
+        limit = self._device_bytes_limit()
+        if limit is None:
+            recompute, reason = False, "no_limit"
+        elif 2 * kept <= limit - state:
+            recompute, reason = False, "fits"
+        else:
+            recompute, reason = True, "kept_exceeds_free"
+        blocks = sum(isinstance(e, tuple)
+                     for e in engine_core.chain_of(self.forwards))
+        now = kept_recomputing if recompute else kept
+        telemetry.gauge(events.GAUGE_FUSED_KEPT_ACTIVATION_BYTES).set(now)
+        telemetry.event(
+            events.EV_FUSED_RECOMPUTE,
+            policy="recompute" if recompute else "keep",
+            blocks=blocks if recompute else 0, kept_bytes=int(now),
+            recomputed_bytes=int(kept - now) if recompute else 0,
+            state_bytes=int(state), limit_bytes=limit, reason=reason)
+        return recompute
+
     # -- lifecycle -----------------------------------------------------
 
     def initialize(self, device=None, **kwargs) -> None:
@@ -432,11 +502,13 @@ class FusedStepRunner(AcceleratedUnit):
         self.streaming = not getattr(self.loader, "device_resident",
                                      True)
         if self.streaming and self.device.is_jax:
-            if getattr(self.loader, "dequant", None) is None:
+            if getattr(self.loader, "dequant", None) is None and \
+                    self.loader.minibatch_data.dtype.kind not in "iub":
                 # assemble streaming batches directly in the compute
                 # dtype (prefetch thread): the trace's first op is this
                 # cast anyway, and doing it host-side halves H2D bytes
                 # on the bf16 platforms where the transfer bottlenecks
+                # (never an integer store: token ids stay ids)
                 self.loader.stream_dtype = \
                     np.dtype(self._resolved_dtype())
             # else: quantized ingest — the wire is uint8 (1 byte/px,
@@ -477,6 +549,8 @@ class FusedStepRunner(AcceleratedUnit):
 
     def _target_store(self):
         ld = self.loader
+        if self._targets_are_rows():
+            return ld.original_data.unmap()    # the one store, twice
         if self._has_targets():
             return ld.original_targets.unmap()
         return ld.original_labels.unmap()
@@ -532,6 +606,11 @@ class FusedStepRunner(AcceleratedUnit):
         telemetry.counter(events.CTR_FUSED_MINIBATCHES).inc(k)
         telemetry.counter(
             f"fused.{'train' if train else 'eval'}_images").inc(images)
+        if train and self._targets_are_rows():
+            # an "image" of a sequence model is a row: one packed
+            # sequence of this many tokens
+            telemetry.counter(events.CTR_FUSED_TRAIN_TOKENS).inc(
+                images * int(np.prod(ld.minibatch_data.shape[1:])))
 
     @contextlib.contextmanager
     def _submit(self, kind: str, k: int):
@@ -609,8 +688,11 @@ class FusedStepRunner(AcceleratedUnit):
         dispatch loop) back-pressures the loop instead of piling
         unsent host batches into RAM without bound."""
         xb = ld.superstep_data
-        tb = ld.superstep_targets if self._has_targets() \
-            else ld.superstep_labels
+        if self._targets_are_rows():
+            tb = xb
+        else:
+            tb = ld.superstep_targets if self._has_targets() \
+                else ld.superstep_labels
         if xb is None or tb is None:
             raise RuntimeError(
                 f"{self.name}: streaming mode but the loader produced "
@@ -1843,9 +1925,13 @@ class PopulationTrainEngine:
             # replicated copy lives next to the member-sharded stacks
             # regardless of which single device built the workflow
             dataset = self._put_replicated(ld.original_data.map_read())
-            tvec = ld.original_targets if self.fused._has_targets() \
-                else ld.original_labels
-            targets = self._put_replicated(tvec.map_read())
+            if self.fused._targets_are_rows():
+                targets = dataset
+            else:
+                tvec = ld.original_targets \
+                    if self.fused._has_targets() \
+                    else ld.original_labels
+                targets = self._put_replicated(tvec.map_read())
         else:
             dataset = ld.original_data.unmap()
             targets = self.fused._target_store()
@@ -1859,9 +1945,12 @@ class PopulationTrainEngine:
                 mask_dev = self._put_replicated(mask)
                 if streaming:
                     xb = ld.superstep_data
-                    tb = ld.superstep_targets \
-                        if self.fused._has_targets() \
-                        else ld.superstep_labels
+                    if self.fused._targets_are_rows():
+                        tb = xb
+                    else:
+                        tb = ld.superstep_targets \
+                            if self.fused._has_targets() \
+                            else ld.superstep_labels
                     if xb is None or tb is None:
                         raise RuntimeError(
                             "cohort streaming mode but the loader "

@@ -49,6 +49,12 @@ class ForwardUnit(AcceleratedUnit):
     #: derivative is fused into the evaluator's err_output contract).
     activation_mode = "linear"
     has_params = True
+    #: the index, among its workflow's forwards, of the first layer of
+    #: the **residual** entry ``x + f_k(...f_1(x))`` this unit is part
+    #: of; None for a layer of the plain line.  The unit carries it so
+    #: that every engine that walks a list of forwards walks the same
+    #: chain (engine/core.py ``chain_of``).
+    residual_of: Optional[int] = None
     _unpicklable = AcceleratedUnit._unpicklable + ("_last_residual",)
 
     def __init__(self, workflow=None, **kwargs: Any) -> None:
@@ -111,7 +117,7 @@ class ForwardUnit(AcceleratedUnit):
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device=device, **kwargs)
         in_shape = tuple(self.input.shape)
-        if not self.weights and self.param_shapes(in_shape):
+        if not self.param_vectors() and self.param_shapes(in_shape):
             self.fill_params(in_shape)
         out_shape = self.output_shape_for(in_shape)
         if not self.output or tuple(self.output.shape) != out_shape:
@@ -232,19 +238,32 @@ class GradientUnit(AcceleratedUnit):
             # consumer rebinds/overwrites before reading (upload=False).
             self.err_input.mem = np.zeros(f.input.shape, np.float32)
             self.err_input.initialize(device, upload=False)
-        if self.gradient_moment and f is not None:
-            for pname, vec in f.param_vectors().items():
-                if vec and pname not in self.accumulated_grads:
-                    acc = Vector(name=f"{self.name}.vel_{pname}")
-                    acc.initialize(device)
-                    if device is not None and device.is_jax:
-                        # zeros are born on the device (XLA generates
-                        # them) — uploading host zeros the size of the
-                        # params wastes link bandwidth and wall clock
-                        acc.devmem = device.zeros(vec.shape, np.float32)
-                    else:
-                        acc.mem = np.zeros(vec.shape, np.float32)
-                    self.accumulated_grads[pname] = acc
+        for pname, shape in self.opt_shapes().items():
+            if pname not in self.accumulated_grads:
+                acc = Vector(name=f"{self.name}.vel_{pname}")
+                acc.initialize(device)
+                if device is not None and device.is_jax:
+                    # zeros are born on the device (XLA generates
+                    # them) — uploading host zeros the size of the
+                    # params wastes link bandwidth and wall clock
+                    acc.devmem = device.zeros(shape, np.float32)
+                else:
+                    acc.mem = np.zeros(shape, np.float32)
+                self.accumulated_grads[pname] = acc
+
+    def opt_shapes(self) -> Dict[str, Tuple[int, ...]]:
+        """The optimiser state this unit keeps beside its forward's
+        parameters: one f32 velocity per allocated parameter when
+        there is momentum, nothing otherwise."""
+        if not self.gradient_moment or self.forward is None:
+            return {}
+        return {pname: tuple(vec.shape)
+                for pname, vec in self.forward.param_vectors().items()
+                if vec}
+
+    def opt_nbytes(self) -> int:
+        return sum(4 * int(np.prod(shape))
+                   for shape in self.opt_shapes().values())
 
     def reconcile_velocities(self) -> None:
         """Re-shape momentum buffers whose parameter changed shape
@@ -291,6 +310,10 @@ class GradientUnit(AcceleratedUnit):
     #: MXU relative to its FLOPs).
     can_skip_err_input = False
 
+    #: parameters that take ``learning_rate`` / ``weight_decay``; every
+    #: other one takes the bias's rate and decay
+    weight_names: Tuple[str, ...] = ("weights",)
+
     def backward_from_saved(self, params: Dict[str, Any],
                             saved: Tuple[Any, Any], err_output: Any) \
             -> Tuple[Any, Dict[str, Any]]:
@@ -324,8 +347,8 @@ class GradientUnit(AcceleratedUnit):
             self.weight_decay, self.weight_decay_bias)
         for pname, w in params.items():
             g = grads[pname]
-            lr = lr_w if pname == "weights" else lr_b
-            wd = wd_w if pname == "weights" else wd_b
+            lr = lr_w if pname in self.weight_names else lr_b
+            wd = wd_w if pname in self.weight_names else wd_b
             g = g + wd * w
             if self.gradient_moment:
                 v = velocities[pname]

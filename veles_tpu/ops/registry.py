@@ -102,6 +102,16 @@ def _populate() -> None:
         register("depooling", depooling.Depooling,
                  depooling.GDDepooling)
 
+    @family("sequence")
+    def _sequence():
+        from veles_tpu.ops import sequence as seq
+        register("embedding", seq.Embedding, seq.GDSequence)
+        register("rmsnorm", seq.RMSNorm, seq.GDSequence)
+        register("dense", seq.Dense, seq.GDSequence)
+        register("swiglu", seq.SwiGLU, seq.GDSequence)
+        register("eva_attention", seq.EvaAttention, seq.GDSequence)
+        register("lm_head", seq.LMHead, seq.GDSequence)
+
     for name, fn in families:
         try:
             fn()

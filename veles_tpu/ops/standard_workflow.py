@@ -23,7 +23,8 @@ from veles_tpu.backends import Device
 from veles_tpu.loader.base import TRAIN, Loader
 from veles_tpu.mutable import Bool
 from veles_tpu.ops.decision import DecisionGD
-from veles_tpu.ops.evaluator import EvaluatorMSE, EvaluatorSoftmax
+from veles_tpu.ops.evaluator import (EvaluatorMSE, EvaluatorNextByte,
+                                      EvaluatorSoftmax)
 from veles_tpu.ops.fused import FusedStepRunner
 from veles_tpu.ops.nn_units import NNWorkflow
 from veles_tpu.ops.registry import forward_registry
@@ -64,7 +65,8 @@ class StandardWorkflow(NNWorkflow):
         self._create_snapshotter(snapshotter_config)
         self.fused = FusedStepRunner(
             self, loader=self.loader, forwards=self.forwards,
-            evaluator=self.evaluator, gds=self.gds, name="fused")
+            evaluator=self.evaluator, gds=self.gds,
+            name="fused")
         self.lr_adjust = None
         if lr_adjust_config:
             from veles_tpu.ops.lr_adjust import LearningRateAdjust
@@ -116,10 +118,35 @@ class StandardWorkflow(NNWorkflow):
 
     # -- unit creation -------------------------------------------------
 
+    def _flatten_layers(self) -> List[Optional[int]]:
+        """``layers`` may hold **residual** entries ``{"type":
+        "residual", "layers": [...]}``: ``x + f_k(...f_1(x))`` round
+        the inner layers.  Every inner layer is a unit of its own, in
+        order, so ``flat_layers`` / ``forwards`` / ``gds`` stay lists
+        over ONE index; each inner layer's unit carries the index of
+        its entry's first layer (``ForwardUnit.residual_of``), which
+        is how every walk of the forwards finds the skip path
+        (engine/core.py ``chain_of``); returned here, a mark a layer."""
+        self.flat_layers: List[Dict[str, Any]] = []
+        residual_of: List[Optional[int]] = []
+        for cfg in self.layers_config:
+            if cfg["type"] != "residual":
+                self.flat_layers.append(cfg)
+                residual_of.append(None)
+                continue
+            inner = cfg["layers"]
+            if not inner or any(c["type"] == "residual" for c in inner):
+                raise ValueError("a residual entry wraps a non-empty "
+                                 "list of plain layers")
+            residual_of += [len(self.flat_layers)] * len(inner)
+            self.flat_layers.extend(inner)
+        return residual_of
+
     def _create_forwards(self) -> None:
+        residual_of = self._flatten_layers()
         self.forwards = []
         prev = None
-        for i, cfg in enumerate(self.layers_config):
+        for i, cfg in enumerate(self.flat_layers):
             kind = cfg["type"]
             if kind not in forward_registry:
                 raise ValueError(f"unknown layer type {kind!r}; have "
@@ -127,6 +154,7 @@ class StandardWorkflow(NNWorkflow):
             fwd_cls, _ = forward_registry[kind]
             fwd_kwargs = dict(cfg.get("->", {}))
             unit = fwd_cls(self, name=f"fwd{i}_{kind}", **fwd_kwargs)
+            unit.residual_of = residual_of[i]
             if prev is None:
                 unit.link_attrs(self.loader, ("input", "minibatch_data"))
             else:
@@ -141,6 +169,13 @@ class StandardWorkflow(NNWorkflow):
             ev.link_attrs(last, ("input", "output"))
             ev.link_attrs(self.loader, ("labels", "minibatch_labels"),
                           ("mask", "minibatch_mask"))
+        elif self.loss_function == "next_byte":
+            # the targets are the rows themselves: the fused step
+            # hands the data store in as the target store
+            ev = EvaluatorNextByte(self, name="evaluator")
+            ev.link_attrs(last, ("input", "output"))
+            ev.link_attrs(self.loader, ("target", "minibatch_data"),
+                          ("mask", "minibatch_mask"))
         elif self.loss_function == "mse":
             ev = EvaluatorMSE(self, name="evaluator")
             ev.link_attrs(last, ("input", "output"))
@@ -153,7 +188,7 @@ class StandardWorkflow(NNWorkflow):
     def _create_gds(self) -> None:
         self.gds = []
         loader = self.loader
-        for i, (cfg, fwd) in enumerate(zip(self.layers_config,
+        for i, (cfg, fwd) in enumerate(zip(self.flat_layers,
                                            self.forwards)):
             kind = cfg["type"]
             _, gd_cls = forward_registry[kind]
@@ -217,6 +252,11 @@ class StandardWorkflow(NNWorkflow):
 
     def wire_eager(self) -> None:
         """Classic per-unit graph (numpy golden path)."""
+        from veles_tpu.engine.core import has_residual
+        if has_residual(self.forwards):
+            raise NotImplementedError(
+                "a layers list with residual entries runs fused only "
+                "(the per-unit graph has no skip path)")
         self._clear_control_links()
         self.loader.host_fill_enabled = True
         self.loader.superstep = 1
@@ -268,6 +308,7 @@ class StandardWorkflow(NNWorkflow):
         # or pre-existing snapshots become unresumable
         self.__dict__.setdefault("_extra_after_decision", [])
         self.__dict__.setdefault("plotters", [])
+        self.__dict__.setdefault("flat_layers", self.layers_config)
 
     def initialize(self, device: Optional[Device] = None, **kwargs) -> None:
         use_fused = device is not None and device.is_jax \

@@ -62,6 +62,9 @@ def _numel(shape: Iterable[int]) -> int:
 def forward_flops_per_sample(unit) -> float:
     """Analytic forward-pass FLOPs for ONE sample through a forward
     unit.  Shapes must be resolved (call after workflow.initialize)."""
+    if hasattr(unit, "mxu_flops_per_sample"):
+        # the sequence family counts its own matmuls (ops/sequence.py)
+        return float(unit.mxu_flops_per_sample())
     out_shape = tuple(unit.output.shape)
     out_elems = _numel(out_shape[1:])
     kind = type(unit).__name__
@@ -88,6 +91,8 @@ def forward_flops_per_sample(unit) -> float:
 
 
 def unit_has_weights(unit) -> bool:
+    if getattr(unit, "matrix_names", ()):
+        return True               # the sequence family's projections
     w = getattr(unit, "weights", None)
     return w is not None and getattr(w, "mem", None) is not None
 
@@ -95,7 +100,8 @@ def unit_has_weights(unit) -> bool:
 def _is_mxu(unit) -> bool:
     """Conv and dense layers: the MACs the MXU runs."""
     return (hasattr(unit, "n_kernels") and hasattr(unit, "kx")) \
-        or hasattr(unit, "output_sample_shape")
+        or hasattr(unit, "output_sample_shape") \
+        or hasattr(unit, "mxu_flops_per_sample")
 
 
 def model_flops_per_sample(forwards: List[Any]) -> Dict[str, float]:
