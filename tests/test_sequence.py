@@ -23,6 +23,7 @@ from veles_tpu.engine import core as engine_core  # noqa: E402
 from veles_tpu.loader import ArrayLoader  # noqa: E402
 from veles_tpu.loader.synthetic import PackedBytesLoader  # noqa: E402
 from veles_tpu.models.evabyte import TINY, evabyte_layers  # noqa: E402
+from veles_tpu.ops import eva_pallas  # noqa: E402
 from veles_tpu.ops import sequence as seq  # noqa: E402
 from veles_tpu.ops.fused import FusedStepRunner  # noqa: E402
 from veles_tpu.ops.registry import forward_registry  # noqa: E402
@@ -96,36 +97,23 @@ def test_layer_forward_and_backward_match_the_reference(kind):
         _close(grads[name], want_p[name])
 
 
-def _brute_force_eva(x, p, fw):
+def _brute_force_rows(q, k, v, phi, mu, win, c):
     """Per query, in numpy float64: the local keys of its window up to
-    itself, and the summaries of every chunk of every earlier window."""
-    x = np.asarray(x, np.float64)
-    p = {k: np.asarray(v, np.float64) for k, v in p.items()}
-    b, t, _ = x.shape
-    nh, d = fw["n_heads"], fw["head_size"]
-    win, c = min(fw["window_size"], t), fw["chunk_size"]
+    itself, and the summaries of every chunk of every earlier window;
+    q, k, v ``[rows, T, heads, d]``."""
+    q, k, v, phi, mu = (np.asarray(a, np.float64)
+                        for a in (q, k, v, phi, mu))
+    b, t, nh, d = q.shape
     s = d ** -0.5
-    inv = fw["rope_theta"] ** (-np.arange(0, d, 2) / d)
-
-    def rope(y):
-        ang = np.arange(t)[:, None] * inv
-        cos = np.concatenate([np.cos(ang)] * 2, -1)[None, :, None]
-        sin = np.concatenate([np.sin(ang)] * 2, -1)[None, :, None]
-        rot = np.concatenate([-y[..., d // 2:], y[..., :d // 2]], -1)
-        return y * cos + rot * sin
-
-    q = rope((x @ p["wq"]).reshape(b, t, nh, d))
-    k = rope((x @ p["wk"]).reshape(b, t, nh, d))
-    v = (x @ p["wv"]).reshape(b, t, nh, d)
     out = np.zeros((b, t, nh, d))
     for r in range(b):
         for h in range(nh):
             ks, vs = [], []
             for j in range(t // c):
                 kc, vc = k[r, j * c:(j + 1) * c, h], v[r, j * c:(j + 1) * c, h]
-                a = np.exp(s * kc @ p["phi"][h])
+                a = np.exp(s * kc @ phi[h])
                 a /= a.sum()
-                ks.append(a @ kc + p["mu"][h])
+                ks.append(a @ kc + mu[h])
                 vs.append(a @ vc)
             for n in range(t):
                 w0 = (n // win) * win
@@ -135,6 +123,29 @@ def _brute_force_eva(x, p, fw):
                     + vs[:w0 // c]
                 e = np.exp(s * np.asarray(keys) @ q[r, n, h])
                 out[r, n, h] = (e / e.sum()) @ np.asarray(vals)
+    return out
+
+
+def _brute_force_eva(x, p, fw):
+    """:func:`_brute_force_rows` behind the projections and RoPE."""
+    x = np.asarray(x, np.float64)
+    p = {k: np.asarray(v, np.float64) for k, v in p.items()}
+    b, t, _ = x.shape
+    nh, d = fw["n_heads"], fw["head_size"]
+    inv = fw["rope_theta"] ** (-np.arange(0, d, 2) / d)
+
+    def rope(y):
+        ang = np.arange(t)[:, None] * inv
+        cos = np.concatenate([np.cos(ang)] * 2, -1)[None, :, None]
+        sin = np.concatenate([np.sin(ang)] * 2, -1)[None, :, None]
+        rot = np.concatenate([-y[..., d // 2:], y[..., :d // 2]], -1)
+        return y * cos + rot * sin
+
+    out = _brute_force_rows(
+        rope((x @ p["wq"]).reshape(b, t, nh, d)),
+        rope((x @ p["wk"]).reshape(b, t, nh, d)),
+        (x @ p["wv"]).reshape(b, t, nh, d), p["phi"], p["mu"],
+        min(fw["window_size"], t), fw["chunk_size"])
     return out.reshape(b, t, nh * d)
 
 
@@ -169,6 +180,122 @@ def test_eva_attention_is_causal():
     y1 = unit.forward(params, x.at[:, n].add(1.0))
     np.testing.assert_array_equal(y0[:, :n], y1[:, :n])
     assert float(jnp.abs(y0[:, n:] - y1[:, n:]).max()) > 1e-3
+
+
+# -- the fused kernels (ISSUE 29), in Pallas interpret mode -----------------
+
+FUSED_WIN, FUSED_CHUNK, FUSED_D = 256, 16, 128
+
+
+def _attend(fused, tiles=None):
+    """q, k, v, phi, mu -> the heads' outputs, by the kernels
+    (interpret mode: the program itself never interprets) or by
+    ``eva_rows`` (``eva_window``, a window at a time), as off the
+    chip."""
+    def attend(q, k, v, phi, mu):
+        ks, vs = seq.eva_summaries(k, v, phi, mu, FUSED_CHUNK)
+        if fused:
+            return eva_pallas.eva_fused(q, k, v, ks, vs, FUSED_WIN,
+                                        FUSED_CHUNK, tiles, True)
+        return seq.eva_rows(q, k, v, ks, vs, FUSED_WIN, FUSED_CHUNK)
+    return attend
+
+
+@pytest.mark.parametrize("t,rows,tiles", [
+    (256, 1, (128, 128, 16, 16)),     # the first window: no summaries
+    (256, 2, (256, 128, 16, 16)),
+    (1024, 1, (128, 128, 32, 16)),    # later windows: 16, 32, 48 of them
+    (1024, 2, (256, 128, 32, 16)),    # ... a whole window a grid step
+])
+def test_fused_eva_matches_the_window_path_and_the_loop(t, rows, tiles):
+    """Output and the gradients to q, k, v, phi, mu of the fused
+    kernels against the ``eva_window`` path, and the output against
+    the per-query loop: head size 128, windows of 256 = 1 or 2 query
+    blocks of 1 or 2 key tiles, summaries in tiles of 32 with a tail
+    of 16."""
+    keys = jax.random.split(jax.random.key(t + rows), 6)
+    q, k, v, err = (jax.random.normal(keys[i], (rows, t, 2, FUSED_D),
+                                      jnp.float32) for i in range(4))
+    phi, mu = (0.3 * jax.random.normal(keys[i], (2, FUSED_D),
+                                       jnp.float32) for i in (4, 5))
+    tiles = eva_pallas.Tiles(*tiles)
+    got, back = jax.vjp(_attend(True, tiles), q, k, v, phi, mu)
+    want, want_back = jax.vjp(_attend(False), q, k, v, phi, mu)
+    _close(got, want)
+    np.testing.assert_allclose(
+        got, _brute_force_rows(q, k, v, phi, mu, FUSED_WIN, FUSED_CHUNK),
+        rtol=2e-4, atol=2e-5)
+    for name, g, w in zip(("q", "k", "v", "phi", "mu"), back(err),
+                          want_back(err)):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        _close(g, w, 2e-5)
+
+
+def test_fused_eva_refuses_tiles_that_do_not_tile():
+    x = jnp.zeros((1, 512, 1, FUSED_D))
+    s = jnp.zeros((1, 32, 1, FUSED_D))
+    with pytest.raises(ValueError, match="do not tile"):
+        eva_pallas.eva_fused(x, x, x, s, s, 256, 16,
+                             eva_pallas.Tiles(128, 128, 48, 16), True)
+
+
+@pytest.mark.parametrize("platform,head,win,chunk,t,batched,want", [
+    ("cpu", 128, 2048, 16, 32768, False, ("xla", "platform")),
+    ("tpu", 16, 32, 4, 128, False, ("xla", "head_size")),
+    ("tpu", 128, 2048, 16, 32768, True, ("xla", "batched")),
+    ("tpu", 128, 2048, 32, 32768, False, ("xla", "window")),
+    ("tpu", 128, 96, 16, 192, False, ("xla", "window")),
+    ("tpu", 128, 2048, 16, 32768, False, ("fused", None)),
+    ("tpu", 128, 256, 2, 512, False, ("fused", None)),
+])
+def test_eva_path_is_chosen_from_platform_and_shapes(
+        platform, head, win, chunk, t, batched, want):
+    path = seq.eva_path(platform, head, win, chunk, t, batched)
+    assert (path["path"], path.get("reason")) == want
+    if want[0] == "fused":
+        tiles = path["tiles"]
+        assert tiles == eva_pallas.tiles_for(head, win, chunk, t)
+        assert win % tiles.q == 0 and tiles.q % tiles.k == 0
+        assert (win // chunk) % tiles.rs == 0 and tiles.r <= t // chunk
+        if win == 2048:
+            assert tiles == (2048, 512, 512, 128)
+
+
+def test_eva_path_is_journaled_and_a_vmap_leaves_the_kernels():
+    """On the CPU every unit journals ``xla`` / ``platform`` once at
+    ``initialize``; a unit that believes it is on a TPU at head size
+    128 takes the kernels, and under ``vmap`` (a cohort, an ensemble)
+    falls back to the window path and says why."""
+    from types import SimpleNamespace
+    telemetry.reset()
+    w = _workflow(_rows())
+    w.initialize(device=make_device("cpu"))
+    seen = telemetry.recent_events(events.EV_EVA_PATH)
+    assert [(e["path"], e["reason"]) for e in seen] \
+        == [("xla", "platform")] * TINY["n_layers"]
+    assert len({e["unit"] for e in seen}) == TINY["n_layers"]
+    assert telemetry.gauge(events.GAUGE_EVA_FUSED_LAYERS).value == 0
+    w.stop()
+
+    unit = forward_registry["eva_attention"][0](
+        None, name="wide", n_heads=1, head_size=128, window_size=256,
+        chunk_size=2)
+    shapes = unit.param_shapes((1, 512, 8))
+    params = {n: 0.3 * jax.random.normal(jax.random.key(j), (3,) + sh)
+              for j, (n, sh) in enumerate(sorted(shapes.items()))}
+    x = jax.random.normal(jax.random.key(9), (1, 512, 8), jnp.float32)
+    one = lambda i: {n: p[i] for n, p in params.items()}  # noqa: E731
+    want = jnp.stack([unit.forward(one(i), x) for i in range(3)])
+    assert unit.path == {"path": "xla", "reason": "platform"}
+    unit.device = SimpleNamespace(platform="tpu")
+    assert unit._path(512)["path"] == "fused"
+    got = jax.vmap(unit.forward, in_axes=(0, None))(params, x)
+    _close(got, want)
+    assert [(e["unit"], e["path"], e["reason"]) for e in
+            telemetry.recent_events(events.EV_EVA_PATH)[-3:]] == [
+        ("wide", "xla", "platform"), ("wide", "fused", None),
+        ("wide", "xla", "batched")]
+    assert seq.under_vmap(x) is False
 
 
 # -- the whole model through StandardWorkflow -----------------------------

@@ -74,6 +74,29 @@ jax.jit(lambda x, e: lrn_pallas.lrn_bwd(x, e, 5, 2.0, 1e-4)
 print("COMPILED")
 """
 
+PALLAS_EVA = PRELUDE + """
+from veles_tpu.ops import eva_pallas
+
+t, nh, d, win, chunk = (int(a) for a in sys.argv[1:6])
+tiles = eva_pallas.tiles_for(d, win, chunk, t)
+assert tiles is not None
+x = spec((1, t, nh, d), jnp.bfloat16)
+s = spec((1, t // chunk, nh, d), jnp.bfloat16)
+
+
+def attend(q, k, v, ks, vs):
+    return eva_pallas.eva_fused(q, k, v, ks, vs, win, chunk, tiles)
+
+
+def both(q, k, v, ks, vs, do):
+    return jax.vjp(attend, q, k, v, ks, vs)[1](do)
+
+
+hlo = jax.jit(both).lower(x, x, x, s, s, x).compile().as_text()
+assert hlo.count("tpu_custom_call") >= 2, hlo[-2000:]
+print("COMPILED")
+"""
+
 
 def _compile(src, *argv):
     res = subprocess.run(
@@ -107,3 +130,17 @@ def test_pallas_lrn_compiles_at_the_alexnet_shapes(shape):
     compiler takes them for bf16 too).  tests_tpu/ runs them on the
     chip against the XLA form."""
     _compile(PALLAS_LRN, *shape)
+
+
+@pytest.mark.parametrize("t,window", [(32768, 2048), (8192, 2048),
+                                      (1024, 512)])
+def test_pallas_eva_attention_compiles_at_the_published_widths(
+        t, window):
+    """The fused EVA attention kernels (ISSUE 29), forward and fused
+    backward, through Mosaic in bf16 at EvaByte's 32 heads x 128 and
+    chunk 16: the cell's row (a whole window a grid step, 64 MiB of
+    scoped VMEM), the chip test's row, and a small window whose query
+    block is one key tile.  Interpret mode (tests/test_sequence.py)
+    cannot see an unaligned slice or a VMEM overrun; this can.
+    tests_tpu/test_eva_kernel.py runs them on the chip."""
+    _compile(PALLAS_EVA, t, 32, 128, window, 4 if window == 512 else 16)

@@ -72,6 +72,12 @@ def _timed(name: str) -> str:
 EV_FUSED_FIRST_DISPATCH = _ev("fused.first_dispatch")
 EV_FUSED_SUMMARY = _ev("fused.summary")
 EV_FUSED_RECOMPUTE = _ev("fused.recompute")
+#: which form of EVA attention a unit runs (``ops/sequence.py``
+#: ``eva_path``): ``unit``, ``path`` (``fused`` / ``xla``), ``reason``
+#: where it is ``xla`` (``platform`` / ``batched`` / ``head_size`` /
+#: ``window``), the kernels' ``tiles`` where it is ``fused``; once a
+#: unit at ``initialize``, again only where a later trace must differ
+EV_EVA_PATH = _ev("eva.path")
 
 #: one per backend compile OR persistent-cache load of a program
 #: (jax.monitoring reports both under one name): ``seconds``, the
@@ -271,6 +277,8 @@ GAUGE_FUSED_KEPT_ACTIVATION_BYTES = _gauge("fused.kept_activation_bytes")
 GAUGE_EVA_WINDOW = _gauge("eva.window")
 GAUGE_EVA_CHUNK = _gauge("eva.chunk")
 GAUGE_EVA_SUMMARIES_PER_ROW = _gauge("eva.summaries_per_row")
+#: ``eva_attention`` units of the workflow on the fused kernels
+GAUGE_EVA_FUSED_LAYERS = _gauge("eva.fused_layers")
 GAUGE_FUSED_TRAIN_GFLOPS_PER_IMAGE = _gauge(
     "fused.train_gflops_per_image")
 GAUGE_FUSED_TRAIN_IMAGES_PER_SEC_WALL = _gauge(

@@ -42,7 +42,13 @@ set {m : wW <= m <= n} exactly and the remote set {j : (j + 1) c <= wW}
 ``o_n = (sum_local exp(.) v_m + sum_remote exp(.) vs_j) / Z_n``.
 Scores and softmax in f32, products in bf16 (``mixedp_attn``).  A
 query scores at most 2048 + 1920 keys, never 32 768; with T <= W this
-is exactly causal softmax attention.
+is exactly causal softmax attention.  What runs it: on a TPU at these
+sizes the fused Pallas kernels of ``ops/eva_pallas.py`` — a row's local
+and remote scores tile by tile in VMEM under one running softmax,
+forward and backward, nothing of them in HBM; on XLA:CPU, at ``TINY``
+or under ``vmap`` the same sums as plain XLA ops a window at a time
+(``ops/sequence.py`` ``eva_window``).  The unit chooses from its
+platform and shapes and journals which (``eva.path``).
 
 **Assumed** — the published config is silent on each; the form is
 arXiv:2302.04542 section 4 (EVA with the exact set E = the local

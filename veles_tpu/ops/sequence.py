@@ -17,13 +17,31 @@ Wang, Kong: Efficient Attention via Control Variates, ICLR 2023,
 arXiv:2302.04542, section 4) as ``veles_tpu/models/evabyte.py`` states
 it: a query scores the keys of its own window exactly (causal) and one
 learned summary of every chunk of every earlier window, under one
-softmax.  Plain XLA ops, one window's scores at a time
-(``jax.checkpoint`` round a window: its backward re-makes the scores
-from q, k, v instead of keeping sixteen windows' worth); no masked-out
-remote block is computed — the remote set of a window is whole earlier
-windows.  Scores and softmax in f32, products in the compute dtype.
-Device ops carry ``eva/summaries``, ``eva/local``, ``eva/remote`` in
-their metadata, forward and backward alike.
+softmax.  Two forms of the one algorithm, chosen by
+:func:`eva_path` from what the unit observes — the platform and the
+shapes, never a setting:
+
+- on a TPU, where the shapes tile (head size whole 128-lane columns,
+  the window whole query blocks, a window's summaries whole lane
+  tiles): ``ops/eva_pallas.py`` — one fused kernel over the whole row,
+  forward and backward (``jax.custom_vjp``): a tile of scores lives in
+  VMEM under a running softmax and is never written to HBM; the
+  forward keeps ``o`` and the row's log-sum-exp, the backward re-makes
+  a tile's probabilities from q, k and that number.  No
+  ``jax.checkpoint``, no tie between windows;
+- everywhere else (XLA:CPU, small or ragged shapes, under ``vmap``):
+  plain XLA ops, one window's scores at a time (``eva_window``;
+  ``jax.checkpoint`` round a window: its backward re-makes the scores
+  from q, k, v instead of keeping sixteen windows' worth).  It is the
+  oracle the kernel is tested against.
+
+No masked-out remote block is computed in either — the remote set of a
+window is whole earlier windows.  Scores and softmax in f32, products
+in the compute dtype.  Device ops carry ``eva/summaries``,
+``eva/local``, ``eva/remote`` in their metadata, forward and backward
+alike; the fused kernels, which hold local and remote under their one
+softmax, run under ``eva/local``.  Which form a unit took is journaled
+once at ``initialize`` (``eva.path``; gauge ``eva.fused_layers``).
 """
 
 from __future__ import annotations
@@ -34,6 +52,7 @@ import numpy as np
 
 from veles_tpu import events, prng, telemetry
 from veles_tpu.memory import Vector
+from veles_tpu.ops import eva_pallas
 from veles_tpu.ops.nn_units import ForwardUnit, GradientUnit
 
 
@@ -333,6 +352,59 @@ def eva_window(q, k, v, ks, vs):
     return o.astype(v.dtype)
 
 
+def eva_rows(q, k, v, ks, vs, win: int, chunk: int):
+    """Whole rows ``[rows, T, heads, d]`` by :func:`eva_window`, a
+    window at a time: the form every platform but the TPU's fused
+    kernels runs, and their oracle."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    window = jax.checkpoint(eva_window)
+    out = []
+    for lo in range(0, q.shape[1], win):
+        r = lo // chunk        # every chunk of every earlier window
+        qw = q[:, lo:lo + win]
+        if out:
+            # one window at a time, forward and backward: without
+            # the tie the scheduler holds several windows' f32
+            # scores at once (0.5 GB each at the published sizes)
+            qw, out[-1] = lax.optimization_barrier((qw, out[-1]))
+        out.append(window(qw, k[:, lo:lo + win], v[:, lo:lo + win],
+                          ks[:, :r], vs[:, :r]))
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
+
+
+def eva_path(platform: str, head_size: int, window: int, chunk: int,
+             t: int, batched: bool = False) -> Dict[str, Any]:
+    """Which form of EVA attention runs, from what the code observes:
+    ``{"path": "fused", "tiles": Tiles}`` on a TPU where the shapes
+    tile, else ``{"path": "xla", "reason": ...}`` — ``platform`` (not
+    a TPU), ``batched`` (under ``vmap``: the kernels' accumulators
+    have no member axis), ``head_size`` (not whole 128-lane columns),
+    ``window`` (the window or its summaries are not whole tiles)."""
+    if platform != "tpu":
+        return {"path": "xla", "reason": "platform"}
+    if batched:
+        return {"path": "xla", "reason": "batched"}
+    tiles = eva_pallas.tiles_for(head_size, window, chunk, t)
+    if tiles is None:
+        return {"path": "xla", "reason": "head_size"
+                if head_size % eva_pallas.LANES else "window"}
+    return {"path": "fused", "tiles": tiles}
+
+
+def under_vmap(*arrays) -> bool:
+    """True where one of ``arrays`` is traced under ``jax.vmap``
+    (directly or inside a ``jvp`` / ``vjp`` of it)."""
+    import jax
+    for a in arrays:
+        while isinstance(a, jax.core.Tracer):
+            if hasattr(a, "batch_dim"):     # vmap's tracer alone
+                return True
+            a = getattr(a, "primal", None)  # through jvp / linearize
+    return False
+
+
 class EvaAttention(SequenceUnit):
     """RoPE + EVA attention over ``[rows, T, hidden]``; the heads'
     outputs side by side ``[rows, T, heads * head_size]`` (the output
@@ -349,6 +421,8 @@ class EvaAttention(SequenceUnit):
         self.n_heads, self.head_size = n_heads, head_size
         self.window_size, self.chunk_size = window_size, chunk_size
         self.rope_theta = rope_theta
+        #: the last :func:`eva_path` journaled ({} before the first)
+        self.path: Dict[str, Any] = {}
 
     def output_shape_for(self, input_shape):
         return tuple(input_shape[:-1]) + (self.n_heads * self.head_size,)
@@ -366,6 +440,28 @@ class EvaAttention(SequenceUnit):
                 f"windows of {win} made of chunks of {self.chunk_size}")
         return win
 
+    def _path(self, t: int, batched: bool = False) -> Dict[str, Any]:
+        """:func:`eva_path` of this unit for rows of ``t`` positions,
+        journaled (``eva.path``) whenever it differs from the last
+        one journaled: once at ``initialize``, and again only where a
+        later trace must leave it (a ``vmap``)."""
+        if self.device is None:         # walked without ``initialize``
+            import jax
+            platform = jax.default_backend()
+        else:
+            platform = getattr(self.device, "platform", None) \
+                or self.device.backend_name
+        path = eva_path(platform, self.head_size, self._window(t),
+                        self.chunk_size, t, batched)
+        if path != self.path:
+            self.path = path
+            tiles = path.get("tiles")
+            telemetry.event(
+                events.EV_EVA_PATH, unit=self.name, path=path["path"],
+                reason=path.get("reason"),
+                tiles=tiles and dict(tiles._asdict()))
+        return path
+
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device=device, **kwargs)
         t = int(self.input.shape[1])
@@ -373,6 +469,12 @@ class EvaAttention(SequenceUnit):
         telemetry.gauge(events.GAUGE_EVA_CHUNK).set(self.chunk_size)
         telemetry.gauge(events.GAUGE_EVA_SUMMARIES_PER_ROW).set(
             t // self.chunk_size)
+        self._path(t)
+        # units initialize in order: the last one sets the whole count
+        peers = getattr(self.workflow, "forwards", None) or [self]
+        telemetry.gauge(events.GAUGE_EVA_FUSED_LAYERS).set(sum(
+            isinstance(f, EvaAttention) and f.path.get("path") == "fused"
+            for f in peers))
 
     def forward(self, params, x):
         import jax
@@ -389,20 +491,15 @@ class EvaAttention(SequenceUnit):
         k = rope(heads(params["wk"]), self.rope_theta)
         v = heads(params["wv"])
         ks, vs = eva_summaries(k, v, params["phi"], params["mu"], chunk)
-        window = jax.checkpoint(eva_window)
-        out = []
-        for lo in range(0, t, win):
-            r = lo // chunk        # every chunk of every earlier window
-            qw = q[:, lo:lo + win]
-            if out:
-                # one window at a time, forward and backward: without
-                # the tie the scheduler holds several windows' f32
-                # scores at once (0.5 GB each at the published sizes)
-                qw, out[-1] = lax.optimization_barrier((qw, out[-1]))
-            out.append(window(qw, k[:, lo:lo + win], v[:, lo:lo + win],
-                              ks[:, :r], vs[:, :r]))
-        o = out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
-        return o.reshape(b, t, nh * d)
+        path = self._path(t, under_vmap(q, k, v))
+        if path["path"] == "fused":
+            # the whole row in one call: local and remote under their
+            # one softmax, no score in HBM, nothing to checkpoint
+            with jax.named_scope(events.SCOPE_EVA_LOCAL):
+                o = eva_pallas.eva_fused(q, k, v, ks, vs, win, chunk,
+                                         path["tiles"])
+            return o.reshape(b, t, nh * d)
+        return eva_rows(q, k, v, ks, vs, win, chunk).reshape(b, t, nh * d)
 
     def mxu_flops_per_sample(self) -> float:
         t, h = int(self.input.shape[1]), int(self.input.shape[2])
