@@ -24,8 +24,6 @@ The last stdout line is one JSON record::
         {"fault": ..., "ok": bool, "recovery_sec": float, "detail":
          ...}, ...]}
 
-bench.py runs this as its ``fault_drill`` phase, so robustness gets a
-measured trajectory in BENCH_r* exactly like performance does.
 ``--only NAME`` (substring match) runs a subset; the multihost drill
 is the only one that spawns a process pair and respects
 ``CHAOS_SKIP_MULTIHOST=1``.

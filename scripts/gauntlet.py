@@ -29,8 +29,7 @@ journals (router process + every ``replica-*/`` subdir) and demands:
   promotion/rollback event in the journals carries its recorded
   cause — an unexplained fleet mutation fails the day.
 
-The last stdout line is one JSON record (adopted by ``bench.py
---gauntlet-only`` as the BENCH_r15 gauntlet phase).  Sizing knobs are
+The last stdout line is one JSON record.  Sizing knobs are
 ``GAUNTLET_*`` env vars; the CI day is ~3 minutes, the ``-m slow``
 pytest wrapper raises GAUNTLET_DURATION to an hours-long soak.
 """

@@ -26,8 +26,8 @@ import pytest  # noqa: E402
 @pytest.fixture(scope="session", autouse=True)
 def _isolated_data_dir(tmp_path_factory):
     """Point root.common.data_dir at a fresh temp dir for the whole
-    session: real datasets materialized on this machine (e.g. bench.py's
-    secondary metric writes MNIST IDX files under ~/.veles_tpu/data)
+    session: real datasets materialized on this machine (MNIST IDX
+    files under ~/.veles_tpu/data)
     must not leak into the suite — MnistLoader would silently switch
     from the tiny synthetic sets to 60k real samples and the suite's
     runtime would triple."""

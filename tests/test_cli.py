@@ -195,35 +195,3 @@ class TestServeFleetCli:
         assert r.returncode == 2
         assert "ghost" in r.stderr
         assert "Traceback" not in r.stderr
-
-
-class TestBenchFleetCli:
-    """bench.py --fleet-only rides the smoke-tested CLI surface like
-    --serve-only: the skip knob must short-circuit the phase cleanly
-    (the measured run lands in BENCH_r07.json)."""
-
-    def test_fleet_only_skip_short_circuits(self):
-        env = dict(os.environ)
-        env["BENCH_SKIP_FLEET"] = "1"
-        r = subprocess.run(
-            [sys.executable, "bench.py", "--fleet-only"],
-            capture_output=True, text=True, cwd=REPO, env=env,
-            timeout=300)
-        assert r.returncode == 0, r.stderr[-2000:]
-        assert json.loads(r.stdout.strip().splitlines()[-1]) is None
-
-
-class TestBenchZooCli:
-    """bench.py --zoo-only rides the same smoke-tested CLI surface as
-    the other fast paths: the skip knob must short-circuit the phase
-    cleanly (the measured run lands in BENCH_r14.json)."""
-
-    def test_zoo_only_skip_short_circuits(self):
-        env = dict(os.environ)
-        env["BENCH_SKIP_ZOO"] = "1"
-        r = subprocess.run(
-            [sys.executable, "bench.py", "--zoo-only"],
-            capture_output=True, text=True, cwd=REPO, env=env,
-            timeout=300)
-        assert r.returncode == 0, r.stderr[-2000:]
-        assert json.loads(r.stdout.strip().splitlines()[-1]) is None

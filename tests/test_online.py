@@ -492,6 +492,29 @@ class TestHiveOnlinePoison:
             c.close()
 
 
+def _hist_window(after, before):
+    """The latency distribution of ONE measurement window from two
+    cumulative histogram snapshots as ``stats`` carries them over the
+    wire (bucket-wise subtraction; min/max are the cumulative ones,
+    which only widens the clamp range the quantile interpolation
+    uses)."""
+    from veles_tpu.telemetry import Histogram
+    a, b = dict(after or {}), dict(before or {})
+    h = Histogram("window")
+    h.count = int(a.get("count", 0)) - int(b.get("count", 0))
+    h.sum = float(a.get("sum", 0.0)) - float(b.get("sum", 0.0))
+    if a.get("min") is not None:
+        h.min = float(a["min"])
+    if a.get("max") is not None:
+        h.max = float(a["max"])
+    bb = b.get("buckets") or {}
+    for i, c in (a.get("buckets") or {}).items():
+        d = int(c) - int(bb.get(i, 0))
+        if d > 0:
+            h.buckets[int(i)] += d
+    return h
+
+
 class TestHiveOnlineLatency:
     """The scavenger must not own the chip: serving p99 with the
     learner active stays bounded vs learner-off on the same box (the
@@ -554,8 +577,7 @@ class TestHiveOnlineLatency:
                 steps = st1["counters"].get("online.steps", 0) - steps0
             finally:
                 c.close()
-            from bench import _serve_hist_window
-            lat = _serve_hist_window(
+            lat = _hist_window(
                 st1["histograms"].get("serve.request_seconds"),
                 st0["histograms"].get("serve.request_seconds"))
             return (lat.quantile(0.99) or 0.0), steps

@@ -138,50 +138,6 @@ class TestConv:
         u = conv_mod.Conv(n_kernels=8, kx=11, ky=11, sliding=4)
         assert u.output_shape_for((1, 227, 227, 3)) == (1, 55, 55, 8)
 
-    @pytest.mark.parametrize("geom", [
-        # (kx, ky, pad, stride, in_shape) — AlexNet conv1 miniature,
-        # stride not dividing kernel, rectangular stride, with padding
-        (11, 11, 0, 4, (2, 31, 31, 3)),
-        (5, 5, 0, 3, (2, 17, 17, 2)),
-        (3, 4, (1, 2), (2, 3), (2, 11, 13, 3)),
-        (2, 2, 0, 2, (1, 8, 8, 4)),
-    ])
-    def test_space_to_depth_exact(self, geom, monkeypatch):
-        """The s2d rewrite must match lax.conv bit-for-bit-ish (f32
-        reassociation only) in forward AND in both vjp cotangents."""
-        import jax
-        kx, ky, pad, stride, shp = geom
-        u = conv_mod.Conv(n_kernels=5, kx=kx, ky=ky, padding=pad,
-                          sliding=stride)
-        assert u._s2d_eligible(shp[-1])
-        wshape = u.param_shapes(shp)["weights"]
-        w = RNG.standard_normal(wshape).astype(np.float32)
-        x = RNG.standard_normal(shp).astype(np.float32)
-
-        def run(s2d):
-            monkeypatch.setenv("VELES_TPU_CONV_S2D", "1" if s2d else "0")
-            y, vjp = jax.vjp(
-                lambda ww, xx: u.pre_activation({"weights": ww}, xx),
-                jnp.asarray(w), jnp.asarray(x))
-            ct = jnp.asarray(
-                RNG2.standard_normal(y.shape).astype(np.float32))
-            dw, dx = vjp(ct)
-            return np.asarray(y), np.asarray(dw), np.asarray(dx)
-
-        RNG2 = np.random.default_rng(0)
-        ref = run(False)
-        RNG2 = np.random.default_rng(0)
-        got = run(True)
-        for a, b in zip(ref, got):
-            assert a.shape == b.shape
-            np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
-
-    def test_s2d_ineligible_for_unit_stride_or_many_channels(self):
-        assert not conv_mod.Conv(n_kernels=4, kx=3, ky=3,
-                                 sliding=1)._s2d_eligible(3)
-        assert not conv_mod.Conv(n_kernels=4, kx=5, ky=5,
-                                 sliding=4)._s2d_eligible(64)
-
 
 class TestPooling:
     def test_max(self):
@@ -262,51 +218,30 @@ class TestLRN:
             np.testing.assert_array_equal(bt, b.T)
             assert b.sum(axis=0).max() == n  # interior taps
 
-    def test_pallas_kernels_match_numpy_oracle(self):
-        """The single-pass TPU kernels (interpret mode on CPU) vs the
-        numpy shifted-adds oracle, forward and backward, both real
-        channel widths (96 aligns to no lane boundary; 256 to two)."""
-        from veles_tpu.ops import lrn_pallas
-        for c, n in ((96, 5), (256, 5), (96, 4)):
-            u = lrn_mod.LRNormalizer(alpha=3e-2, beta=0.75, n=n, k=2.0)
-            x = RNG.standard_normal((16, 3, 3, c)).astype(np.float32)
-            err = RNG.standard_normal(x.shape).astype(np.float32)
-            assert lrn_pallas.usable(x.shape, u.n, u.beta)
-
-            y_np, res_np = u.apply_fwd({}, x)
-            y_pl = np.asarray(lrn_pallas.lrn_fwd(
-                x, u.n, u.k, u.alpha, interpret=True))
-            np.testing.assert_allclose(y_pl, y_np, rtol=2e-5,
-                                       atol=1e-6)
-
-            gd = lrn_mod.GDLRNormalizer(forward=u)
-            ein_np, _ = gd.backward_from_saved({}, res_np, err)
-            ein_pl = np.asarray(lrn_pallas.lrn_bwd(
-                x, err, u.n, u.k, u.alpha, interpret=True))
-            np.testing.assert_allclose(ein_pl, ein_np, rtol=2e-4,
-                                       atol=1e-5)
-
-    def test_jax_banded_matmul_matches_numpy_oracle_both_parities(self):
+    @pytest.mark.parametrize("c,n", [(8, 4), (8, 5), (96, 5), (256, 5),
+                                     (96, 4)])
+    def test_jax_banded_matmul_matches_numpy_oracle_both_parities(
+            self, c, n):
         """The jax path's banded-matmul window sum must agree with the
         independent numpy shifted-adds oracle for ODD and EVEN window
-        sizes (an n+1-tap symmetric band would pass only odd n)."""
-        import jax.numpy as jnp
-        for n in (4, 5):
-            u = lrn_mod.LRNormalizer(alpha=3e-2, beta=0.75, n=n, k=2.0)
-            x = RNG.standard_normal((2, 3, 3, 8)).astype(np.float32)
-            err = RNG.standard_normal(x.shape).astype(np.float32)
+        sizes (an n+1-tap symmetric band would pass only odd n), at
+        AlexNet's real channel widths too (96 aligns to no lane
+        boundary; 256 to two)."""
+        u = lrn_mod.LRNormalizer(alpha=3e-2, beta=0.75, n=n, k=2.0)
+        x = RNG.standard_normal((2, 3, 3, c)).astype(np.float32)
+        err = RNG.standard_normal(x.shape).astype(np.float32)
 
-            y_np, res_np = u.apply_fwd({}, x)
-            y_jx, res_jx = u.apply_fwd({}, jnp.asarray(x))
-            np.testing.assert_allclose(np.asarray(y_jx), y_np,
-                                       rtol=2e-5, atol=1e-6)
+        y_np, res_np = u.apply_fwd({}, x)
+        y_jx, res_jx = u.apply_fwd({}, jnp.asarray(x))
+        np.testing.assert_allclose(np.asarray(y_jx), y_np,
+                                   rtol=2e-5, atol=1e-6)
 
-            gd = lrn_mod.GDLRNormalizer(forward=u)
-            ein_np, _ = gd.backward_from_saved({}, res_np, err)
-            ein_jx, _ = gd.backward_from_saved({}, res_jx,
-                                               jnp.asarray(err))
-            np.testing.assert_allclose(np.asarray(ein_jx), ein_np,
-                                       rtol=2e-4, atol=1e-5)
+        gd = lrn_mod.GDLRNormalizer(forward=u)
+        ein_np, _ = gd.backward_from_saved({}, res_np, err)
+        ein_jx, _ = gd.backward_from_saved({}, res_jx,
+                                           jnp.asarray(err))
+        np.testing.assert_allclose(np.asarray(ein_jx), ein_np,
+                                   rtol=2e-4, atol=1e-5)
 
 
 class TestDropout:
@@ -366,3 +301,24 @@ class TestDepooling:
         y = u.apply({}, {"input": x})["output"]
         assert y.shape == (1, 4, 4, 1)
         assert (y[0, :2, :2, 0] == 0).all()
+
+
+# last in the file: every check above draws from the one module RNG,
+# and the finite-difference probes of the pooling checks depend on
+# where in its stream they start
+@pytest.mark.parametrize("geom", [
+    # (kx, ky, pad, stride, in_shape) — AlexNet conv1 miniature,
+    # stride not dividing kernel, rectangular stride, with padding
+    (11, 11, 0, 4, (2, 31, 31, 3)),
+    (5, 5, 0, 3, (2, 17, 17, 2)),
+    (3, 4, (1, 2), (2, 3), (2, 11, 13, 3)),
+    (2, 2, 0, 2, (1, 8, 8, 4)),
+])
+def test_conv_strided_geometries_match_im2col_oracle(geom):
+    """``lax.conv_general_dilated`` against the numpy im2col /
+    col2im oracle at the strided first-layer geometries: forward
+    and both cotangents (and finite differences of the oracle)."""
+    kx, ky, pad, stride, shp = geom
+    u = conv_mod.Conv(n_kernels=5, kx=kx, ky=ky, padding=pad,
+                      sliding=stride)
+    check_unit(u, conv_mod.GradientDescentConv, shp)

@@ -274,8 +274,9 @@ class TestStreamDtypeAndRelease:
         datasets._synth_cache.clear()
 
     def test_release_device_state_drops_buffers(self):
-        """bench.py relies on this to fit two workflows' HBM on one
-        chip: after release, the runner and its units hold no device
+        """The GA evaluator, the Hive and the benchmark rely on this
+        to fit one workflow's HBM after another's on one chip: after
+        release, the runner and its units hold no device
         arrays and a later run() rebuilds them."""
         w = build_mlp(streaming=True)
         w.initialize(device=JaxDevice(platform="cpu"))
@@ -306,7 +307,7 @@ class TestStreamDtypeAndRelease:
 
 class TestTransferAccounting:
     def test_stream_transfer_seconds_accumulates_and_pickles(self):
-        """bench.py's primary streaming-efficiency metric depends on
+        """The input pipeline's transfer-busy accounting is
         FusedStepRunner.stream_transfer_seconds — it must accumulate
         only in streaming mode and default to 0.0 across snapshots."""
         ws = build_mlp(streaming=True)

@@ -62,18 +62,6 @@ jax.jit(fn).lower(params, spec((8, 6, 6, 1), jnp.float32)).compile()
 print("COMPILED")
 """
 
-PALLAS_LRN = PRELUDE + """
-from veles_tpu.ops import lrn_pallas
-
-shape = tuple(int(a) for a in sys.argv[1:5])
-x = spec(shape, jnp.bfloat16)
-assert lrn_pallas.usable(shape, 5, 0.75)
-jax.jit(lambda x: lrn_pallas.lrn_fwd(x, 5, 2.0, 1e-4)).lower(x).compile()
-jax.jit(lambda x, e: lrn_pallas.lrn_bwd(x, e, 5, 2.0, 1e-4)
-        ).lower(x, x).compile()
-print("COMPILED")
-"""
-
 PALLAS_EVA = PRELUDE + """
 from veles_tpu.ops import eva_pallas
 
@@ -119,17 +107,6 @@ def test_member_stacked_softmax_head_compiles(members, hidden, classes):
     its first request.  engine/core.py build_member_forward now
     splits the head at its logits."""
     _compile(MEMBER_STACKED, members, hidden, classes)
-
-
-@pytest.mark.parametrize("shape", [(128, 55, 55, 96),
-                                   (128, 27, 27, 256)])
-def test_pallas_lrn_compiles_at_the_alexnet_shapes(shape):
-    """The opt-in Pallas LRN kernels through Mosaic, bf16, at the two
-    LRN shapes of AlexNet at its own minibatch — C = 96 as the full
-    (non-lane-multiple) channel axis, row tiles aligned to 8 (the
-    compiler takes them for bf16 too).  tests_tpu/ runs them on the
-    chip against the XLA form."""
-    _compile(PALLAS_LRN, *shape)
 
 
 @pytest.mark.parametrize("t,window", [(32768, 2048), (8192, 2048),

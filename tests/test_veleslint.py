@@ -148,6 +148,21 @@ def test_lock_discipline_clean():
                         path="fx/lock_clean.py", config=cfg) == []
 
 
+def test_traced_env_read_catches_seeded():
+    got = scan_fixture("traced_env_bad.py", "traced-env-read",
+                       path="veles_tpu/ops/_fixture_kernel.py")
+    assert sorted(f.detail for f in got) == [
+        "os.environ", "os.environ", "os.getenv"], got
+
+
+def test_traced_env_read_clean_and_scoped():
+    assert scan_fixture("traced_env_clean.py", "traced-env-read",
+                        path="veles_tpu/engine/_fixture.py") == []
+    # the rule bites the code that builds traced programs only
+    assert scan_fixture("traced_env_bad.py", "traced-env-read",
+                        path="veles_tpu/serve/_fixture.py") == []
+
+
 def test_waivers_suppress_findings():
     found = scan_source("veles_tpu/_fixture_waiver.py",
                         fixture("waiver.py"), Config())
@@ -241,7 +256,8 @@ def test_rule_catalog_is_stable():
     assert rule_names() == [
         "atomic-write", "env-registry", "event-registry",
         "tracer-hygiene", "exit-code-literals", "lock-discipline",
-        "engine-residency-seam", "thread-lifecycle", "wire-protocol",
+        "engine-residency-seam", "traced-env-read",
+        "thread-lifecycle", "wire-protocol",
         "trace-wire-key", "lock-order", "blocking-under-lock",
         "waiter-discipline"]
 

@@ -157,38 +157,6 @@ class TestStreamingOnChip:
         assert len(w.fused._inflight) <= 2
 
 
-class TestPallasLrnOnChip:
-    @pytest.mark.parametrize("shape", [(16, 55, 55, 96),
-                                       (16, 27, 27, 256)])
-    def test_kernels_match_xla_form_at_bf16(self, tpu_device, shape):
-        """The opt-in pallas LRN kernels, COMPILED (not interpreted),
-        vs the default XLA banded form at both AlexNet LRN shapes in
-        bf16 — C = 96 is no lane multiple and rides as the full
-        channel axis."""
-        import jax.numpy as jnp
-        from veles_tpu.ops import lrn as lrn_mod
-        from veles_tpu.ops import lrn_pallas
-        u = lrn_mod.LRNormalizer(alpha=3e-2, beta=0.75, n=5, k=2.0)
-        gd = lrn_mod.GDLRNormalizer(forward=u)
-        rng = np.random.default_rng(8)
-        x = jnp.asarray(rng.standard_normal(shape, np.float32),
-                        jnp.bfloat16)
-        e = jnp.asarray(rng.standard_normal(shape, np.float32),
-                        jnp.bfloat16)
-        assert lrn_pallas.usable(x.shape, u.n, u.beta)
-
-        y_xla, res = u.apply_fwd({}, x)
-        ei_xla, _ = gd.backward_from_saved({}, res, e)
-        y_pl = lrn_pallas.lrn_fwd(x, u.n, u.k, u.alpha)
-        ei_pl = lrn_pallas.lrn_bwd(x, e, u.n, u.k, u.alpha)
-        np.testing.assert_allclose(
-            np.asarray(y_pl, np.float32), np.asarray(y_xla, np.float32),
-            rtol=0.02, atol=0.02)
-        np.testing.assert_allclose(
-            np.asarray(ei_pl, np.float32),
-            np.asarray(ei_xla, np.float32), rtol=0.05, atol=0.05)
-
-
 def _alexnet_probe(tpu_device, name):
     """An AlexNet-1000 workflow small enough to fire by hand (mb 64,
     superstep 2) whose queued steps are real work for the barrier and
@@ -242,8 +210,8 @@ class TestHonestBarrier:
         assert busy > max(5 * idle, 0.05), (idle, dispatch, busy)
 
     def test_block_until_ready_blocks(self, tpu_device):
-        """The fact bench.py's honesty contract and docs/perf.md rely
-        on, re-established on this installation (jax 0.9.0, libtpu
+        """The fact the benchmark's barriers (benchmarks/) and
+        docs/perf.md rely on, re-established on this installation (jax 0.9.0, libtpu
         0.0.34, a directly attached chip): ``block_until_ready`` on the
         metric carry WAITS for the queued steps — after it returns,
         the data-dependent fetch finds nothing left to wait for."""
@@ -291,10 +259,9 @@ class TestProfilerTrace:
 
 class TestDeviceBornDataset:
     def test_device_synthetic_loader_trains_on_chip(self, tpu_device):
-        """The headline benchmark's loader: the dataset must be born
+        """The device-generating loader: the dataset must be born
         in HBM (devmem bound, no host copy) and a fused training
-        firing must consume it (round-5: the device-generation path is
-        what bench.py's resident phase depends on)."""
+        firing must consume it."""
         from veles_tpu.loader.synthetic import DeviceSyntheticLoader
         prng.seed_all(1234)
         w = StandardWorkflow(
@@ -518,11 +485,10 @@ class TestImagePipelineOnChip:
 
 class TestStreamingAccountingOnChip:
     def test_streaming_trains_and_accounts_transfers(self, tpu_device):
-        """The streaming path on the real chip (the benchmark's
-        streaming phase in miniature): residency budget forces
-        host-assembled superstep batches, training proceeds, and the
-        transfer accounting bench.py's efficiency metric reads is
-        live."""
+        """The streaming path on the real chip: residency budget
+        forces host-assembled superstep batches, training proceeds,
+        and the transfer accounting (``stream_transfer_seconds`` /
+        ``_bytes``) is live."""
         prng.seed_all(2026)
         w = StandardWorkflow(
             loader_factory=lambda wf: SyntheticClassificationLoader(

@@ -70,8 +70,7 @@ class Finding:
 
 _DEFAULTS: Dict[str, Any] = {
     # scan roots, relative to the repo root
-    "paths": ["veles_tpu", "scripts", "bench.py",
-              "__graft_entry__.py"],
+    "paths": ["veles_tpu", "scripts", "__graft_entry__.py"],
     # directory basenames never descended into
     "exclude": ["__pycache__", "native", "tests", "tests_tpu",
                 "build", "dist"],
@@ -129,7 +128,7 @@ _DEFAULTS: Dict[str, Any] = {
         "veles_tpu/serve/client.py", "veles_tpu/serve/fleet.py",
         "veles_tpu/serve/router.py", "veles_tpu/serve/sentinel.py",
         "veles_tpu/serve/traffic.py", "veles_tpu/serve/autoscale.py",
-        "veles_tpu/online/trainer.py", "bench.py"],
+        "veles_tpu/online/trainer.py"],
     # the residency/donation seam: the ONLY modules allowed to call
     # jax.device_put or pass donate_argnums — everything else goes
     # through engine.core.ExecutionCore (put / donating_jit)
@@ -660,7 +659,7 @@ def new_findings(findings: List[Finding],
 def repo_scan(root: Optional[str] = None
               ) -> Tuple[List[Finding], Dict[str, str]]:
     """The canonical full-repo scan: (non-baselined findings, the
-    baseline) — what the tier-1 test and bench.py both record."""
+    baseline) — what the tier-1 test records."""
     root = root or repo_root()
     config = load_config(root)
     baseline = load_baseline(os.path.join(root, config.baseline))

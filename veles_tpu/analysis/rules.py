@@ -1,4 +1,4 @@
-"""The six veleslint rules.
+"""The per-file veleslint rules.
 
 Each rule is one class with a ``name``, a one-line ``doc`` (the
 catalog in docs/guide.md section 10 is written from these), and
@@ -461,6 +461,42 @@ class EngineResidencySeamRule:
         return out
 
 
+class TracedEnvReadRule:
+    """Code that builds traced programs chooses a path from what it
+    can observe — platform, shapes, free bytes — never from a user-set
+    environment variable: each such switch doubles the programs a
+    change to the step has to keep correct, and no cell of the
+    benchmark runs its other side (PR 30 deleted four).  Placement
+    settings are declared knobs read through ``knobs.get``."""
+
+    name = "traced-env-read"
+    doc = ("raw `os.environ` / `os.getenv` access in `veles_tpu/ops/` "
+           "or `veles_tpu/engine/` — the code that builds traced "
+           "programs selects a path from what it observes (platform, "
+           "shapes), not from an environment variable")
+
+    _SCOPE = ["veles_tpu/ops", "veles_tpu/engine"]
+
+    def check(self, ctx: ModuleContext) -> List[Finding]:
+        if not _in_scope(ctx.path, self._SCOPE):
+            return []
+        out: List[Finding] = []
+        for node in ast.walk(ctx.tree):
+            if isinstance(node, ast.Attribute) and \
+                    node.attr in ("environ", "getenv") and \
+                    isinstance(node.value, ast.Name) and \
+                    node.value.id == "os":
+                out.append(Finding(
+                    self.name, ctx.path, node.lineno,
+                    node.col_offset, f"os.{node.attr}",
+                    f"os.{node.attr} in code that builds traced "
+                    "programs: select the path from what the code "
+                    "can observe (platform, shapes, free bytes); a "
+                    "placement setting is a declared knob read "
+                    "through knobs.get"))
+        return out
+
+
 from veles_tpu.analysis.concurrency import (  # noqa: E402 — the
     # concurrency module needs Finding/ModuleContext from engine, so
     # it cannot be imported before them
@@ -478,6 +514,7 @@ RULES = [
     ExitCodeLiteralsRule(),
     LockDisciplineRule(),
     EngineResidencySeamRule(),
+    TracedEnvReadRule(),
     ThreadLifecycleRule(),
     WireProtocolRule(),
     TraceWireKeyRule(),

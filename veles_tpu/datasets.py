@@ -480,9 +480,9 @@ def _class_templates(rng: np.random.Generator, n_classes: int,
 
 
 #: OPT-IN one-entry cache of the last LARGE generated dataset
-#: (``VELES_TPU_SYNTH_CACHE=1``, set by bench.py): the benchmark builds
-#: the identical ImageNet-scale set twice (resident + streaming
-#: workflows) and regeneration is minutes of single-core work.  Opt-in
+#: (``VELES_TPU_SYNTH_CACHE=1``, set by scripts/ablate_alexnet.py): an
+#: ablation builds the identical ImageNet-scale set once a variant
+#: and regeneration is minutes of single-core work.  Opt-in
 #: because the cache retains a duplicate multi-GB copy for the process
 #: lifetime — ordinary training runs must not pay that.  Callers must
 #: treat the returned arrays as read-only — every in-tree consumer

@@ -29,7 +29,10 @@ The shared trace builders (:func:`build_forward`,
 :func:`build_mean_probs`) are the EXACT bodies the four loops traced
 before the refactor — adapters compose them into the same jaxprs, so
 f32-bitwise parity with the pre-refactor engines holds by construction
-(pinned by tests/test_engine_core.py).
+(pinned by tests/test_engine_core.py).  The superstep itself — the
+``lax.scan`` of a train or eval minibatch body over a firing — is
+:func:`build_scan_steps`, spelled once for the fused runner and the
+population engine alike.
 
 The core also charges its HBM footprint to the process-wide arbiter
 (serve/residency.py ``process_arbiter()``): training, GA cohorts, and
@@ -417,6 +420,139 @@ def build_backward(forwards, gds, compute_dtype, seed: int = 0):
         return new_params, new_opt
 
     return backward_update
+
+
+def take_rows(dataset, target_store, indices):
+    """The plain gather of a resident feed: one minibatch's rows of
+    the data store and of the target store."""
+    import jax.numpy as jnp
+
+    return (jnp.take(dataset, indices, axis=0),
+            jnp.take(target_store, indices, axis=0))
+
+
+def build_scan_steps(ingest, forward_pass, backward_update,
+                     compute_dtype, metrics_fn, gather=None,
+                     n_classes=None, out_shape=None,
+                     members: bool = False):
+    """THE superstep: ``(train_step, eval_step)``, each one
+    ``lax.scan`` over the minibatches of a firing, composed from the
+    three shared bodies.  Every engine that trains jits these two (the
+    population engine under ``vmap_members``); what an engine owns is
+    placement — shardings, ``in_axes``, donation.
+
+    **Feed.**  ``gather`` given: the feed is ``(dataset, target_store,
+    indices, mask)``, the scanned xs are ``(indices, mask[, lr])`` and
+    the body takes its rows with ``gather(dataset, target_store,
+    indices)`` (:func:`take_rows`, or an engine's row-sharded one).
+    ``gather=None``: the feed is host-assembled rows ``(x, target,
+    mask)`` that ride the scan as they are.
+
+    **Member axis.**  ``members=False`` is one model:
+    ``train_step(params, opt, acc, conf, *feed, lr, rc)`` returns
+    ``(params, opt, acc, conf)`` and ``eval_step(params, acc, conf,
+    *feed, rc)`` returns ``(acc, conf, last_output)``.
+    ``members=True`` is ONE member of a stack: its own rates and
+    decays follow its state, ``train_step(params, opt, acc, lr, wd,
+    *feed, rc)`` returns ``(params, opt, acc)`` and
+    ``eval_step(params, acc, *feed, rc)`` returns ``acc`` — so the
+    member-varying arguments lead and ``in_axes`` is zeros, then
+    ``None`` for the feed and the counter.
+
+    ``acc`` is the ``[n_err, loss_sum, count]`` carry; ``conf`` the
+    confusion carry, counted when ``n_classes`` is given and carried
+    through untouched otherwise (a member has none); the eval carry
+    keeps the last minibatch's f32 output when ``out_shape`` is given;
+    ``lr`` is ``(k, n_gd, 2)`` absolute rates, one row a minibatch;
+    ``wd`` reaches ``backward_update`` only for a member."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from veles_tpu.ops import batching
+
+    cast = batching.make_caster(compute_dtype)
+    # ``with`` blocks in place, not wrappers (see Workflow.initialize)
+    scope = jax.named_scope
+    n_store = 0 if gather is None else 2
+
+    def metrics_of(out, target, mask):
+        with scope(events.SCOPE_LOSS):
+            m = metrics_fn(out.astype(jnp.float32), target, mask)
+            if n_classes is not None:
+                conf = jnp.zeros((n_classes, n_classes), jnp.int32)
+                m["confusion"] = conf.at[target, m["max_idx"]].add(
+                    mask.astype(jnp.int32))
+        return m
+
+    def accumulate(acc, conf, m):
+        acc = acc + jnp.stack([m["n_err"], m["loss_sum"], m["count"]])
+        if n_classes is not None:
+            conf = conf + m["confusion"]
+        return acc, conf
+
+    def train_step(params, opt, acc, *args):
+        if members:
+            (lr, wd, *feed, rc), conf = args, None
+        else:
+            (conf, *feed, lr, rc), wd = args, None
+        store = feed[:n_store]
+
+        def body(carry, xs):
+            params, opt, acc, conf, rc = carry
+            if gather is not None:
+                with scope(events.SCOPE_GATHER):
+                    xs = gather(*store, xs[0]) + xs[1:]
+            # lr: this minibatch's (n_gd, 2) row of absolute (weights,
+            # bias) rates — a schedule stays exact inside a superstep
+            x, target, mask, lr = xs
+            x = ingest(x)
+            with scope(events.SCOPE_CAST_PARAMS):
+                cparams = cast(params)
+            out, residuals = forward_pass(cparams, x, rc, True)
+            m = metrics_of(out, target, mask)
+            err = m.pop("err_output")
+            new_params, new_opt = backward_update(
+                cparams, params, opt, residuals, err, lr, wd)
+            acc, conf = accumulate(acc, conf, m)
+            return (new_params, new_opt, acc, conf, rc + 1), None
+
+        (params, opt, acc, conf, _), _ = lax.scan(
+            body, (params, opt, acc, conf, rc),
+            (*feed[n_store:], lr))
+        return (params, opt, acc) if members \
+            else (params, opt, acc, conf)
+
+    def eval_step(params, acc, *args):
+        if members:
+            (*feed, rc), conf = args, None
+        else:
+            conf, *feed, rc = args
+        store = feed[:n_store]
+        with scope(events.SCOPE_CAST_PARAMS):
+            cparams = cast(params)
+
+        def body(carry, xs):
+            acc, conf, _, rc = carry
+            if gather is not None:
+                with scope(events.SCOPE_GATHER):
+                    xs = gather(*store, xs[0]) + xs[1:]
+            x, target, mask = xs
+            out, _ = forward_pass(cparams, ingest(x), rc, False)
+            m = metrics_of(out, target, mask)
+            m.pop("err_output")
+            acc, conf = accumulate(acc, conf, m)
+            last = None if out_shape is None \
+                else out.astype(jnp.float32)
+            return (acc, conf, last, rc + 1), None
+
+        last = None if out_shape is None \
+            else jnp.zeros(out_shape, jnp.float32)
+        (acc, conf, last, _), _ = lax.scan(
+            body, (acc, conf, last, rc), tuple(feed[n_store:]))
+        return acc if members else (acc, conf, last)
+
+    return train_step, eval_step
 
 
 def build_member_forward(forwards, compute_dtype):

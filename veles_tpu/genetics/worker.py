@@ -66,8 +66,7 @@ def _evaluate(workflow_file: str, backend: str, seed: int,
 def _release(launcher) -> None:
     """Return the device buffers a finished genome run holds — HBM on
     an exclusive chip must not accumulate across the generations a
-    serve-mode evaluator lives through (same hygiene as bench.py's
-    phase transitions)."""
+    serve-mode evaluator lives through."""
     import gc
     w = getattr(launcher, "workflow", None)
     if w is not None:

@@ -424,8 +424,7 @@ TRACE_SAMPLE = _knob(
     "(error diffusion, so the rate is exact, not a coin flip).  A "
     "sampled request carries trace/span/parent wire keys on every "
     "hop and journals trace.* events for cross-process assembly; 0 "
-    "disables causal tracing (the bench trace phase's overhead "
-    "baseline).")
+    "disables causal tracing.")
 FLIGHTREC_CAP = _knob(
     "VELES_FLIGHTREC_CAP", 512, int,
     "Entries the per-process flight-recorder ring retains (recent "
@@ -461,22 +460,6 @@ MAX_RESIDENT_BYTES = _knob(
     "budget degrades to host streaming (on a mesh with "
     "$VELES_MESH_SHARD_DATA, a dataset over one device's budget "
     "first tries the row-sharded placement at total/N per device).")
-TPU_SCAN_UNROLL = _knob(
-    "VELES_TPU_SCAN_UNROLL", 1, int,
-    "Unroll factor of the fused train loop's lax.scan (>1 trades "
-    "compile time for scheduling overlap).")
-TPU_CONV_S2D = _knob(
-    "VELES_TPU_CONV_S2D", False, flag,
-    "Use the space-to-depth conv formulation for stride-matched "
-    "first layers.")
-TPU_LRN_PALLAS = _knob(
-    "VELES_TPU_LRN_PALLAS", False, flag,
-    "Route LRN through the hand-written pallas kernel instead of the "
-    "XLA lowering.")
-TPU_LRN_RECOMPUTE = _knob(
-    "VELES_TPU_LRN_RECOMPUTE", False, flag,
-    "Recompute LRN normalizers in the backward pass instead of "
-    "saving them (HBM for FLOPs).")
 SOM_FUSED = _knob(
     "VELES_SOM_FUSED", True, flag,
     "Train Kohonen SOM workflows as fused donated epoch scans on jax "
@@ -491,7 +474,7 @@ SOM_SUPERSTEP = _knob(
 TPU_SYNTH_CACHE = _knob(
     "VELES_TPU_SYNTH_CACHE", False, flag,
     "Cache large synthetic datasets in-process across loader "
-    "constructions (bench/ablation runs).")
+    "constructions (ablation runs).")
 
 
 def names() -> frozenset:

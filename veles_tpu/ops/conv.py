@@ -16,7 +16,6 @@ Layout: NHWC activations, HWIO weights — the TPU-native choice.
 
 from __future__ import annotations
 
-import os
 from typing import Any, Dict, Tuple
 
 import numpy as np
@@ -98,51 +97,6 @@ class Conv(ForwardUnit):
 
     # -- compute -------------------------------------------------------
 
-    def _s2d_eligible(self, c_in: int) -> bool:
-        """Space-to-depth rewrite pays only for strided convs over few
-        channels (a first layer reading pixels): the MXU's reduction
-        tile is 128+ deep and a C_in=3 conv leaves it almost empty.
-        Blocking trades stride for channels (3 -> s*s*3)."""
-        sy, sx = self.sliding
-        return (sy > 1 or sx > 1) and c_in * sy * sx <= 128
-
-    def _conv_s2d(self, w, x):
-        """Strided conv as a stride-1 conv over space-to-depth blocks.
-
-        Exact rewrite (not an approximation): pad the kernel to a
-        multiple of the stride, then fold each (sy, sx) input block
-        into channels; the zero-padded kernel rows/cols kill every
-        contribution from pad junk, so outputs match
-        ``lax.conv_general_dilated`` bit-for-bit in f32 and the vjp
-        routes gradients back to the original HWIO layout through the
-        (cheap, fusable) reshapes.  The standard TPU first-layer
-        treatment (cf. MLPerf ResNet space-to-depth).
-        """
-        import jax.numpy as jnp
-        from jax import lax
-        b, h, wd, c = x.shape
-        py, px = self.padding
-        sy, sx = self.sliding
-        ky, kx = self.ky, self.kx
-        kyb, kxb = -(-ky // sy), -(-kx // sx)    # kernel size in blocks
-        oh = conv_out_size(h, ky, py, sy)
-        ow = conv_out_size(wd, kx, px, sx)
-        th, tw = (oh + kyb - 1) * sy, (ow + kxb - 1) * sx
-        xp = jnp.pad(x, ((0, 0), (py, max(0, th - h - py)),
-                         (px, max(0, tw - wd - px)), (0, 0)))
-        xp = xp[:, :th, :tw]
-        xs = xp.reshape(b, th // sy, sy, tw // sx, sx, c) \
-            .transpose(0, 1, 3, 2, 4, 5) \
-            .reshape(b, th // sy, tw // sx, sy * sx * c)
-        wp = jnp.pad(w, ((0, kyb * sy - ky), (0, kxb * sx - kx),
-                         (0, 0), (0, 0)))
-        ws = wp.reshape(kyb, sy, kxb, sx, c, self.n_kernels) \
-            .transpose(0, 2, 1, 3, 4, 5) \
-            .reshape(kyb, kxb, sy * sx * c, self.n_kernels)
-        return lax.conv_general_dilated(
-            xs, ws, window_strides=(1, 1), padding="VALID",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-
     def pre_activation(self, params, x):
         if isinstance(x, np.ndarray):
             patches = im2col(x, self.ky, self.kx, self.padding,
@@ -150,9 +104,6 @@ class Conv(ForwardUnit):
             b, oh, ow = patches.shape[:3]
             w2 = params["weights"].reshape(-1, self.n_kernels)
             v = patches.reshape(b, oh, ow, -1) @ w2
-        elif (os.environ.get("VELES_TPU_CONV_S2D", "0") != "0"
-              and self._s2d_eligible(x.shape[-1])):
-            v = self._conv_s2d(params["weights"], x)
         else:
             from jax import lax
             py, px = self.padding

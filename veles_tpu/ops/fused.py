@@ -113,8 +113,9 @@ class FusedStepRunner(AcceleratedUnit):
         #: cumulative seconds this runner spent submitting streaming
         #: uploads and blocked on their drain — the transfer-busy
         #: numerator of the input pipeline's efficiency accounting
-        #: (bench.py): on a link-bound host a perfect pipeline spends
-        #: ~all its wall here, and the remainder is framework overhead
+        #: (the ``fused.stream_transfer_seconds`` counter): on a
+        #: link-bound host a perfect pipeline spends ~all its wall
+        #: here, and the remainder is framework overhead
         self.stream_transfer_seconds = 0.0
         #: this runner's share of the process-wide
         #: ``fused.stream_transfer_bytes`` registry counter — the ONE
@@ -209,56 +210,34 @@ class FusedStepRunner(AcceleratedUnit):
                                               self.device)
 
     def _build_steps(self) -> None:
-        import jax
-        import jax.numpy as jnp
         from jax import lax
 
         from veles_tpu.engine import core as engine_core
 
-        forwards = list(self.forwards)
-        gds = list(self.gds)
         evaluator = self.evaluator
-        want_confusion = self._want_confusion()
         seed = prng.get(self.rng_stream).seed
         cd = self._resolved_dtype()
-        out_shape = self._out_shape = tuple(forwards[-1].output.shape)
+        out_shape = self._out_shape = tuple(
+            self.forwards[-1].output.shape)
         streaming = self.streaming
         if self._core is not None:    # invalidate_trace rebuild: the
             self._core.release()      # old ledger entry must not leak
         core = self._core = engine_core.ExecutionCore(
             self.device, self.mesh, pool="train", name=self.name)
-        # the shared Keel trace bodies: quantized wire ingest, the
-        # forward chain with residuals, and the backward+SGD walk —
-        # composing them here traces the identical jaxpr the
-        # pre-refactor loop did (parity pinned by test_engine_core)
+        # the shared Keel trace bodies — quantized wire ingest, the
+        # forward chain with residuals, the backward+SGD walk — and
+        # the ONE scan that composes them (engine/core.py); what is
+        # decided here is how the data reaches it and where it lies
         ingest = engine_core.build_ingest(
             getattr(self.loader, "dequant", None))
         recompute = self._decide_recompute(cd)
         forward_pass = engine_core.build_forward(
-            forwards, seed, cd, recompute)
+            self.forwards, seed, cd, recompute)
         backward_update = engine_core.build_backward(
-            forwards, gds, cd, seed)
-
-        cast = batching.make_caster(cd)
-        # the parts of a step round the layers' own ``fwd/``, ``bwd/``
-        # and ``update/`` scopes (engine/core.py): ``gather``,
-        # ``ingest``, ``cast_params``, ``loss`` — ``with`` blocks in
-        # place, not wrappers (see Workflow.initialize)
-        scope = jax.named_scope
-
-        def metrics_of(out, target, mask):
-            with scope("loss"):
-                m = evaluator.metrics_fn(out.astype(jnp.float32),
-                                         target, mask)
-                if want_confusion:
-                    n = evaluator.n_classes
-                    conf = jnp.zeros((n, n), jnp.int32)
-                    conf = conf.at[target, m["max_idx"]].add(
-                        mask.astype(jnp.int32))
-                    m["confusion"] = conf
-            return m
+            self.forwards, self.gds, cd, seed)
 
         data_sharded = self.data_sharded and self.mesh is not None
+        gather = None if streaming else engine_core.take_rows
         if data_sharded:
             # row-sharded residency: the gather crosses device shards
             # (local gather + exact psum), and the assembled minibatch
@@ -266,160 +245,41 @@ class FusedStepRunner(AcceleratedUnit):
             # path uses — identical downstream program, so residency
             # placement cannot change the numerics
             sharded_gather = batching.make_sharded_row_gather(self.mesh)
-            _mb_rows = core.row_sharding
+            mb_rows = core.row_sharding
 
-        def gather(dataset, target_store, indices):
-            with scope("gather"):
-                if data_sharded:
-                    x, t = sharded_gather(indices, dataset,
-                                          target_store)
-                    x = lax.with_sharding_constraint(x, _mb_rows)
-                    t = lax.with_sharding_constraint(t, _mb_rows)
-                    return x, t
-                x = jnp.take(dataset, indices, axis=0)
-                t = jnp.take(target_store, indices, axis=0)
-                return x, t
+            def gather(dataset, target_store, indices):
+                x, t = sharded_gather(indices, dataset, target_store)
+                return (lax.with_sharding_constraint(x, mb_rows),
+                        lax.with_sharding_constraint(t, mb_rows))
 
-        def accumulate(acc, conf, m):
-            acc = acc + jnp.stack([m["n_err"], m["loss_sum"],
-                                   m["count"]])
-            if want_confusion:
-                conf = conf + m["confusion"]
-            return acc, conf
-
-        def train_body(dataset, target_store):
-            def body(carry, xs):
-                params, opt, acc, conf, rc = carry
-                # lr is this minibatch's (n_gd, 2) row of absolute
-                # (weights, bias) rates — per-iteration schedules stay
-                # exact inside a superstep (round-1 VERDICT weak #8)
-                if streaming:
-                    # host-assembled batch rows ride the scan directly;
-                    # no HBM-resident dataset exists to gather from
-                    x, target, mask, lr = xs
-                else:
-                    indices, mask, lr = xs
-                    x, target = gather(dataset, target_store, indices)
-                x = ingest(x)
-                with scope("cast_params"):
-                    cparams = cast(params)
-                out, residuals = forward_pass(cparams, x, rc, True)
-                m = metrics_of(out, target, mask)
-                err = m.pop("err_output")
-                new_params, new_opt = backward_update(
-                    cparams, params, opt, residuals, err, lr)
-                acc, conf = accumulate(acc, conf, m)
-                return (new_params, new_opt, acc, conf, rc + 1), None
-            return body
-
-        # scan unroll for the train loop: >1 lets XLA schedule one
-        # minibatch's weight updates behind the next one's matmuls at
-        # the cost of an unroll-times bigger program (slower compile).
-        # Measured on v5e (docs/perf.md): no win at AlexNet scale, so
-        # the default stays 1; the knob remains for smaller nets where
-        # per-step overheads matter more.
-        import os
-        unroll = max(1, int(os.environ.get("VELES_TPU_SCAN_UNROLL",
-                                           "1")))
-
-        def train_step(params, opt, acc, conf, dataset, target_store,
-                       indices, mask, lr_rates, rng_counter):
-            body = train_body(dataset, target_store)
-            (params, opt, acc, conf, _), _ = lax.scan(
-                body, (params, opt, acc, conf, rng_counter),
-                (indices, mask, lr_rates), unroll=unroll)
-            return params, opt, acc, conf
-
-        def train_step_stream(params, opt, acc, conf, xb, tb, mask,
-                              lr_rates, rng_counter):
-            body = train_body(None, None)
-            (params, opt, acc, conf, _), _ = lax.scan(
-                body, (params, opt, acc, conf, rng_counter),
-                (xb, tb, mask, lr_rates), unroll=unroll)
-            return params, opt, acc, conf
-
-        def eval_step(params, acc, conf, dataset, target_store,
-                      indices, mask, rng_counter):
-            with scope("cast_params"):
-                cparams = cast(params)
-
-            def body(carry, xs):
-                acc, conf, _, rc = carry
-                indices, mask = xs
-                x, target = gather(dataset, target_store, indices)
-                out, _ = forward_pass(cparams, ingest(x), rc, False)
-                m = metrics_of(out, target, mask)
-                m.pop("err_output")
-                acc, conf = accumulate(acc, conf, m)
-                return (acc, conf, out.astype(jnp.float32), rc + 1), None
-
-            init_out = jnp.zeros(out_shape, jnp.float32)
-            (acc, conf, out, _), _ = lax.scan(
-                body, (acc, conf, init_out, rng_counter),
-                (indices, mask))
-            return acc, conf, out
-
-        def eval_step_stream(params, acc, conf, xb, tb, mask,
-                             rng_counter):
-            with scope("cast_params"):
-                cparams = cast(params)
-
-            def body(carry, xs):
-                acc, conf, _, rc = carry
-                x, target, mask = xs
-                out, _ = forward_pass(cparams, ingest(x), rc, False)
-                m = metrics_of(out, target, mask)
-                m.pop("err_output")
-                acc, conf = accumulate(acc, conf, m)
-                return (acc, conf, out.astype(jnp.float32), rc + 1), None
-
-            init_out = jnp.zeros(out_shape, jnp.float32)
-            (acc, conf, out, _), _ = lax.scan(
-                body, (acc, conf, init_out, rng_counter),
-                (xb, tb, mask))
-            return acc, conf, out
-
+        train_step, eval_step = engine_core.build_scan_steps(
+            ingest, forward_pass, backward_update, cd,
+            evaluator.metrics_fn, gather=gather,
+            n_classes=evaluator.n_classes if self._want_confusion()
+            else None, out_shape=out_shape)
+        train_in = eval_in = None
         if self.mesh is not None:
             # SPMD data parallelism: minibatch rows sharded over the
-            # data axis, params/dataset replicated.  mask.sum() and the
+            # data axis, params replicated.  mask.sum() and the
             # per-param batch reductions cross the sharded axis, so the
             # partitioner emits the gradient allreduce (ICI psum) —
             # this IS the master-slave aggregation, in-compiler.
             repl = core.replicated
-            # streaming batch rows ride the batch sharding — each
+            # every scanned array rides the batch sharding — each
             # device receives only its slice of every minibatch
             batch = self._batch_sharding = core.batch_sharding
-            if streaming:
-                self._train_step = core.jit(
-                    train_step_stream, donate=(0, 1, 2, 3),
-                    in_shardings=(repl, repl, repl, repl, batch,
-                                  batch, batch, repl, repl))
-                self._eval_step = core.jit(
-                    eval_step_stream, donate=(1, 2),
-                    in_shardings=(repl, repl, repl, batch, batch,
-                                  batch, repl))
-            else:
-                # the resident store enters row-sharded under Lattice
-                # (1/N rows per device), replicated otherwise — the
-                # ONLY in_sharding difference between the two modes
-                store = core.row_sharding if data_sharded else repl
-                self._train_step = core.jit(
-                    train_step, donate=(0, 1, 2, 3),
-                    in_shardings=(repl, repl, repl, repl, store, store,
-                                  batch, batch, repl, repl))
-                self._eval_step = core.jit(
-                    eval_step, donate=(1, 2),
-                    in_shardings=(repl, repl, repl, store, store,
-                                  batch, batch, repl))
-        elif streaming:
-            self._train_step = core.jit(train_step_stream,
-                                        donate=(0, 1, 2, 3))
-            self._eval_step = core.jit(eval_step_stream,
-                                       donate=(1, 2))
-        else:
-            self._train_step = core.jit(train_step,
-                                        donate=(0, 1, 2, 3))
-            self._eval_step = core.jit(eval_step, donate=(1, 2))
+            # the resident store enters row-sharded under Lattice (1/N
+            # rows per device), replicated otherwise — the ONLY
+            # in_sharding difference between the two modes
+            store = core.row_sharding if data_sharded else repl
+            feed = (batch,) * 3 if streaming \
+                else (store, store, batch, batch)
+            train_in = (repl,) * 4 + feed + (repl, repl)
+            eval_in = (repl,) * 3 + feed + (repl,)
+        self._train_step = core.jit(train_step, donate=(0, 1, 2, 3),
+                                    in_shardings=train_in)
+        self._eval_step = core.jit(eval_step, donate=(1, 2),
+                                   in_shardings=eval_in)
 
     def _device_bytes_limit(self) -> Optional[int]:
         """One device's memory as its allocator reports it (None where
@@ -520,10 +380,6 @@ class FusedStepRunner(AcceleratedUnit):
             self.mesh is not None and not self.streaming
             and getattr(self.loader, "shard_resident", False))
         if self.mesh is not None:
-            # sharded jit partitions poorly around custom-call kernels;
-            # units with hand kernels (LRN) must take their XLA form
-            for f in self.forwards:
-                f.force_xla = True
             # the STATIC minibatch shape is max_minibatch_size, which
             # clamps below minibatch_size when every class is smaller —
             # DataParallel.install() can only check minibatch_size
@@ -702,8 +558,8 @@ class FusedStepRunner(AcceleratedUnit):
             else self.device.jax_device
         # wire-byte accounting BEFORE the upload rebinds xb/tb: what
         # the codec actually ships per sample (uint8 ingest = 1
-        # byte/pixel; bf16 = 2; f32 = 4) — bench.py and the codec
-        # tests divide this by processed images
+        # byte/pixel; bf16 = 2; f32 = 4) — the codec tests divide
+        # this by processed images
         n_wire = int(xb.nbytes) + int(tb.nbytes)
         self._stream_bytes += n_wire
         telemetry.counter(
@@ -813,8 +669,8 @@ class FusedStepRunner(AcceleratedUnit):
         would raise a utilisation).  Wall includes host time between
         dispatches, so this is the run's DELIVERED rate (a lower bound
         on engine efficiency), the number an operator reads off
-        obs_report; bench.py's barriered windows remain the measured
-        engine rate."""
+        obs_report; the measured engine rate is the benchmark's
+        barriered window (benchmarks/run.py)."""
         if self._first_run_ts is None or not telemetry.enabled():
             return
         elapsed = time.monotonic() - self._first_run_ts
@@ -869,8 +725,9 @@ class FusedStepRunner(AcceleratedUnit):
         """Drop every device buffer this runner (and its forwards)
         holds — params, optimizer state, metric carries, the upload
         double-buffer, and the units' param/output device copies.  For
-        callers that build several workflows in one process (bench.py
-        measures resident then streaming): the unit graph is cyclic,
+        callers that build several workflows in one process (the GA
+        evaluator and the Hive across models, the benchmark before its
+        reference runs): the unit graph is cyclic,
         so dropping the workflow reference alone frees nothing until
         a gc cycle collection, and the chip OOMs first.  Kept HERE so
         new device-resident fields get added to the release next to
@@ -1706,143 +1563,43 @@ class PopulationTrainEngine:
                                               self.device)
 
     def _build(self) -> None:
-        import jax.numpy as jnp
-        from jax import lax
-
         from veles_tpu.engine import core as engine_core
 
-        evaluator = self.evaluator
         seed = prng.get(self.fused.rng_stream).seed
         cd = self._resolved_dtype()
         core = self._core
-        # the same shared Keel bodies FusedStepRunner composes —
-        # cohort members share the per-genome oracle's seed, so
-        # dropout masks match it (and each other) exactly; the
-        # backward walk takes the per-member decays row
+        # the same shared Keel bodies and the same scan FusedStepRunner
+        # jits, as ONE member's — cohort members share the per-genome
+        # oracle's seed, so dropout masks match it (and each other)
+        # exactly; the backward walk takes the member's decays row.
+        # No confusion matrix: the GA consumes n_err only
         ingest = engine_core.build_ingest(
             getattr(self.loader, "dequant", None))
         forward_pass = engine_core.build_forward(self.forwards, seed,
                                                 cd)
         backward_update = engine_core.build_backward(self.forwards,
                                                      self.gds, cd)
-
-        cast = batching.make_caster(cd)
-
-        def metrics_of(out, target, mask):
-            # no confusion matrix: the GA consumes n_err only
-            return evaluator.metrics_fn(out.astype(jnp.float32),
-                                        target, mask)
-
-        def train_iter(carry, x, target, msk, lrow, wd):
-            # one minibatch of one member's train scan — shared by the
-            # resident (gathered) and streaming (host-assembled) paths
-            params, opt, acc, rc = carry
-            x = ingest(x)
-            cparams = cast(params)
-            out, residuals = forward_pass(cparams, x, rc, True)
-            m = metrics_of(out, target, msk)
-            err = m.pop("err_output")
-            new_params, new_opt = backward_update(
-                cparams, params, opt, residuals, err, lrow, wd)
-            acc = acc + jnp.stack([m["n_err"], m["loss_sum"],
-                                   m["count"]])
-            return (new_params, new_opt, acc, rc + 1)
-
-        def member_train(params, opt, acc, lr, wd, dataset,
-                         target_store, indices, mask, rc0):
-            # ONE member's superstep scan — the same body shape the
-            # fused train_step scans, with per-member (lr, wd) closed
-            # in via vmapped arguments instead of unit attributes
-            def body(carry, xs):
-                idx, msk, lrow = xs
-                x = jnp.take(dataset, idx, axis=0)
-                target = jnp.take(target_store, idx, axis=0)
-                return train_iter(carry, x, target, msk, lrow, wd), \
-                    None
-
-            (params, opt, acc, _), _ = lax.scan(
-                body, (params, opt, acc, rc0), (indices, mask, lr))
-            return params, opt, acc
-
-        def member_train_stream(params, opt, acc, lr, wd, xb, tb,
-                                mask, rc0):
-            # the streaming-cohort scan: batch rows ride the scan
-            # directly (broadcast over the member axis by vmap — HBM
-            # holds ONE copy of each batch, params x P, zero dataset
-            # residency)
-            def body(carry, xs):
-                x, target, msk, lrow = xs
-                return train_iter(carry, x, target, msk, lrow, wd), \
-                    None
-
-            (params, opt, acc, _), _ = lax.scan(
-                body, (params, opt, acc, rc0), (xb, tb, mask, lr))
-            return params, opt, acc
-
-        def eval_iter(acc, cparams, x, target, msk, rc):
-            out, _ = forward_pass(cparams, ingest(x), rc, False)
-            m = metrics_of(out, target, msk)
-            m.pop("err_output")
-            return acc + jnp.stack([m["n_err"], m["loss_sum"],
-                                    m["count"]])
-
-        def member_eval(params, acc, dataset, target_store, indices,
-                        mask, rc0):
-            cparams = cast(params)
-
-            def body(carry, xs):
-                acc, rc = carry
-                idx, msk = xs
-                x = jnp.take(dataset, idx, axis=0)
-                target = jnp.take(target_store, idx, axis=0)
-                return (eval_iter(acc, cparams, x, target, msk, rc),
-                        rc + 1), None
-
-            (acc, _), _ = lax.scan(body, (acc, rc0), (indices, mask))
-            return acc
-
-        def member_eval_stream(params, acc, xb, tb, mask, rc0):
-            cparams = cast(params)
-
-            def body(carry, xs):
-                acc, rc = carry
-                x, target, msk = xs
-                return (eval_iter(acc, cparams, x, target, msk, rc),
-                        rc + 1), None
-
-            (acc, _), _ = lax.scan(body, (acc, rc0), (xb, tb, mask))
-            return acc
-
-        # member axis on params/opt/acc/lr/wd; dataset, targets,
-        # indices, mask, rng_counter broadcast — x stays UNBATCHED
-        # through gather+ingest (vmap only batches where member-axis
-        # arrays flow in, i.e. from the first matmul on), so the
-        # cohort's HBM cost is params x P, not data x P.  The
-        # streaming variants broadcast the host-assembled batch the
-        # same way: one batch copy serves every member.
-        if self.streaming:
-            self._train_step = core.jit(
-                core.vmap_members(
-                    member_train_stream,
-                    in_axes=(0, 0, 0, 0, 0, None, None, None, None)),
-                donate=(0, 1, 2))
-            self._eval_step = core.jit(
-                core.vmap_members(
-                    member_eval_stream,
-                    in_axes=(0, 0, None, None, None, None)),
-                donate=(1,))
-        else:
-            self._train_step = core.jit(
-                core.vmap_members(
-                    member_train,
-                    in_axes=(0, 0, 0, 0, 0, None, None, None, None,
-                             None)),
-                donate=(0, 1, 2))
-            self._eval_step = core.jit(
-                core.vmap_members(
-                    member_eval,
-                    in_axes=(0, 0, None, None, None, None, None)),
-                donate=(1,))
+        train_step, eval_step = engine_core.build_scan_steps(
+            ingest, forward_pass, backward_update, cd,
+            self.evaluator.metrics_fn,
+            gather=None if self.streaming else engine_core.take_rows,
+            members=True)
+        # member axis on params/opt/acc/lr/wd; the feed (dataset,
+        # targets, indices, mask — or the host-assembled batch) and
+        # the rng counter broadcast — x stays UNBATCHED through
+        # gather+ingest (vmap only batches where member-axis arrays
+        # flow in, i.e. from the first matmul on), so the cohort's HBM
+        # cost is params x P, not data x P: one copy of the data, or
+        # of each streamed batch, serves every member
+        feed = (None,) * (3 if self.streaming else 4)
+        self._train_step = core.jit(
+            core.vmap_members(train_step,
+                              in_axes=(0,) * 5 + feed + (None,)),
+            donate=(0, 1, 2))
+        self._eval_step = core.jit(
+            core.vmap_members(eval_step,
+                              in_axes=(0,) * 2 + feed + (None,)),
+            donate=(1,))
 
     # -- per-member learning-rate schedule ----------------------------
 

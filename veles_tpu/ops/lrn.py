@@ -21,18 +21,15 @@ when written naively — docs/perf.md):
   materialized passes over the largest activations in the net.  The
   numpy oracle keeps the explicit shifted-adds form — an independent
   implementation the tests compare against.
-- residual policy: by default the forward saves ``den`` so the
-  backward skips the windowed reduction; the opt-in variants (pallas
-  kernels via VELES_TPU_LRN_PALLAS, x-only residual via
-  VELES_TPU_LRN_RECOMPUTE) save just ``x`` and re-derive ``den`` in
-  the backward.  Measured on a v5e the two policies tie — fwd and bwd
-  share one scan body, so XLA schedules the residual freely either
-  way (docs/perf.md).
+- residual policy: the forward saves ``den`` so the backward skips
+  the windowed reduction.  Saving ``x`` alone and re-deriving ``den``
+  tied on a v5e, and hand Pallas kernels lost to this form (fwd and
+  bwd share one scan body, so XLA schedules the residual freely and
+  fuses into the neighbours — docs/perf.md); both are gone.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Any, Dict
 
 import numpy as np
@@ -54,8 +51,7 @@ def band_matrix(c: int, n: int, transpose: bool = False) -> np.ndarray:
     taps for both parities of n (a symmetric -half..+half band would
     sum n+1 taps for even n).  ``transpose=True`` gives the adjoint
     window (taps j in [-(n-1-half), half]) — equal to the forward
-    window only for ODD n; the backward pass needs the adjoint.
-    Single source of truth for lrn.py and lrn_pallas.py."""
+    window only for ODD n; the backward pass needs the adjoint."""
     half = n // 2
     band = np.zeros((c, c), np.float32)
     for off in range(half - n + 1, half + 1):
@@ -130,49 +126,10 @@ class LRNormalizer(ForwardUnit):
         d, _ = _neg_beta_pow(xp, self._den(xp, x), self.beta)
         return {"output": x * d}
 
-    def _use_pallas(self, x) -> bool:
-        """Whether the hand kernels (ops/lrn_pallas.py) take the hot
-        fused path.  OPT-IN via VELES_TPU_LRN_PALLAS=1: measured on a
-        v5e chip with a data-fetch barrier, XLA's banded-matmul form
-        BEATS the hand kernels at AlexNet's shapes (docs/perf.md
-        records the shootout), so the default stays XLA.  The kernels
-        remain for other shapes/platforms and as tuning
-        infrastructure.  Further requirements: a real TPU (not the
-        XLA:CPU test platform), no sharded mesh (XLA partitions
-        poorly around custom calls — ``force_xla`` is set by the
-        fused runner), beta=3/4, and a tileable shape."""
-        if not os.environ.get("VELES_TPU_LRN_PALLAS"):
-            return False
-        if getattr(self, "force_xla", False):
-            return False
-        dev = getattr(self, "device", None)
-        if dev is None or not getattr(dev, "is_jax", False) or \
-                getattr(dev, "platform", "cpu") == "cpu":
-            return False
-        if getattr(dev, "mesh", None) is not None:
-            # a MeshJaxDevice reaches here on the eager path too, where
-            # the fused runner's force_xla loop never runs
-            return False
-        from veles_tpu.ops import lrn_pallas
-        return lrn_pallas.usable(x.shape, self.n, self.beta)
-
     def apply_fwd(self, params, x, rng=None, train=True):
-        """Residual policy: pallas path and the recompute variant save
-        only ``x`` — the backward re-derives ``den`` (a cheap banded
-        MXU matmul) instead of storing/loading an f32 array the size
-        of the largest activations in the net.  Default XLA path
-        carries ``den``; VELES_TPU_LRN_RECOMPUTE=1 switches (both
-        measured in docs/perf.md — fwd+bwd live in ONE scan body, so
-        XLA schedules the residual freely either way)."""
         xp = _xp(x)
-        if xp is not np and self._use_pallas(x):
-            from veles_tpu.ops import lrn_pallas
-            return lrn_pallas.lrn_fwd(x, self.n, self.k,
-                                      self.alpha), (x, None)
         den = self._den(xp, x)
         d, _ = _neg_beta_pow(xp, den, self.beta)
-        if xp is not np and os.environ.get("VELES_TPU_LRN_RECOMPUTE"):
-            return x * d, (x, None)
         return x * d, (x, den)
 
 
@@ -181,12 +138,6 @@ class GDLRNormalizer(GradientUnit):
         f = self.forward
         x, den = saved
         xp = _xp(err_output)
-        if den is None:  # x-only residual: recompute den here
-            if f._use_pallas(x):
-                from veles_tpu.ops import lrn_pallas
-                return lrn_pallas.lrn_bwd(x, err_output, f.n, f.k,
-                                          f.alpha), {}
-            den = f._den(xp, x)
         d_nb, r = _neg_beta_pow(xp, den, f.beta)      # den^-beta
         if f.beta == 0.75 and r is not None:
             d_nb1 = d_nb * (r * r)                    # den^-(beta+1)

@@ -16,7 +16,7 @@ steady state runs with ZERO recompiles.
 - :mod:`veles_tpu.serve.hive` — the serving process
   (``python -m veles_tpu --serve-models NAME=PKG ...``);
 - :mod:`veles_tpu.serve.client` — the line-protocol client used by
-  tests, bench.py, and operators' smoke probes;
+  tests, the fleet router, and operators' smoke probes;
 - :mod:`veles_tpu.serve.fleet` — replica lifecycle (spawn / monitor /
   respawn) and the model placement policy;
 - :mod:`veles_tpu.serve.router` — Swarm, the SLO-aware fleet router

@@ -5,8 +5,8 @@ CONCURRENT callers over its stdin/stdout: every request draws a wire
 id under a lock, a single reader thread routes response lines back to
 per-id waiters, and heartbeats/garbage are tolerated as proof of life
 (the ChipEvaluatorPool discipline).  This is the surface the serving
-tests, bench.py's ``serve_*``/``fleet_*`` phases, the FleetRouter's
-replicas (veles_tpu/serve/fleet.py), and operator smoke probes share.
+tests, the FleetRouter's replicas (veles_tpu/serve/fleet.py), and
+operator smoke probes share.
 
 Death semantics: when the replica's stdout reaches EOF (process exit,
 SIGKILL, pipe loss), EVERY pending waiter fails immediately with

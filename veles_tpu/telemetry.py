@@ -4,7 +4,7 @@ tracing, and the run journal.
 Until this module existed, every subsystem grew its own ad-hoc
 telemetry attributes (``FusedStepRunner.stream_transfer_bytes``,
 ``ChipEvaluatorPool.hangs_detected``, ``GeneticOptimizer.eval_count``)
-and bench.py scraped them field by field — nothing could answer the
+and each experiment scraped them field by field — nothing could answer the
 questions the roadmap's serving/scaling items are graded on (p50/p99
 latency, sustained throughput) without bespoke instrumentation per
 experiment.  Sightline is the read-side twin of the Faultline
@@ -56,8 +56,7 @@ dir into the human-readable summary.
 Telemetry must never take down a run: file errors drop the sink and
 keep counting in memory; ``set_enabled(False)`` reduces every call to
 one module-attribute load + falsy check (a span keeps its two clock
-reads for ``seconds``; bench.py measures the on/off delta as
-``telemetry_overhead_pct``).
+reads for ``seconds``).
 """
 
 from __future__ import annotations
