@@ -576,7 +576,7 @@ def test_tiny_alexnet_step_is_the_parents_program(monkeypatch,
                 _parent_forward(f, seed, cd))
             monkeypatch.setattr(
                 engine_core, "build_backward",
-                lambda f, g, cd, seed=0:
+                lambda f, g, cd, seed=0, exchange=None:
                 _parent_backward(f, g, cd))
         prng.seed_all(4242)
         train, _, _ = synthetic_classification(

@@ -85,6 +85,69 @@ assert hlo.count("tpu_custom_call") >= 2, hlo[-2000:]
 print("COMPILED")
 """
 
+DP_STEP = PRELUDE + """
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from veles_tpu import events, prng, telemetry
+from veles_tpu.backends import make_device
+from veles_tpu.datasets import synthetic_classification
+from veles_tpu.engine import core as engine_core
+from veles_tpu.loader import ArrayLoader
+from veles_tpu.ops.standard_workflow import StandardWorkflow
+
+mb, hidden, k = (int(a) for a in sys.argv[1:4])
+prng.seed_all(4242)
+train, _, _ = synthetic_classification(
+    2 * mb, 0, (12, 12, 1), n_classes=10, seed=5)
+gd = {"learning_rate": 0.1, "gradient_moment": 0.9}
+w = StandardWorkflow(
+    loader_factory=lambda w: ArrayLoader(
+        w, train=train, minibatch_size=mb, name="loader"),
+    layers=[{"type": "all2all_tanh",
+             "->": {"output_sample_shape": hidden}, "<-": gd},
+            {"type": "dropout", "->": {"dropout_ratio": 0.5}, "<-": {}},
+            {"type": "softmax", "->": {"output_sample_shape": 10},
+             "<-": gd}],
+    decision_config={"max_epochs": 1}, superstep=k, name="wf")
+w.initialize(device=make_device("cpu"))
+# the runner's own mesh step, built for the four described chips
+fused = w.fused
+mesh = Mesh(np.array(topo.devices), ("data",))
+fused.mesh, fused.compute_dtype, fused._train_step = \
+    mesh, jnp.bfloat16, None
+fused._build_steps()
+ev = telemetry.recent_events(events.EV_DP_GRAD_EXCHANGE)[-1]
+assert [g["how"] for g in ev["groups"]] == ["reduced", "gathered"], ev
+assert ev["options"] == engine_core.TPU_GRAD_EXCHANGE_OPTIONS, ev
+repl, batch = NamedSharding(mesh, P()), NamedSharding(mesh, P(None, "data"))
+
+
+def shapes(tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=repl), tree)
+
+
+S = jax.ShapeDtypeStruct
+args = (shapes(fused._collect_params()), shapes(fused._collect_opt()),
+        S((3,), jnp.float32, sharding=repl),
+        S(fused._conf_shape(), jnp.int32, sharding=repl),
+        S((2 * mb, 12, 12, 1), jnp.bfloat16, sharding=repl),
+        S((2 * mb,), jnp.int32, sharding=repl),
+        S((k, mb), jnp.int32, sharding=batch),
+        S((k, mb), w.loader.minibatch_mask.mem.dtype, sharding=batch),
+        S((k, len(fused.gds), 2), jnp.float32, sharding=repl),
+        S((), jnp.int32, sharding=repl))
+# an option libtpu does not know would raise here
+hlo = fused._train_step.lower(*args).compile().as_text()
+wide = "[144,%d]" % hidden
+for line in hlo.splitlines():
+    if " all-reduce(" in line or " reduce-scatter(" in line:
+        assert wide not in line.split(" all-re")[0], line[:200]
+assert "all-gather" in hlo or "all_gather" in hlo
+assert "bf16[%d,144]" % mb in hlo or "bf16[%d,12,12,1]" % mb in hlo
+print("COMPILED")
+"""
+
 
 def _compile(src, *argv):
     res = subprocess.run(
@@ -121,3 +184,15 @@ def test_pallas_eva_attention_compiles_at_the_published_widths(
     cannot see an unaligned slice or a VMEM overrun; this can.
     tests_tpu/test_eva_kernel.py runs them on the chip."""
     _compile(PALLAS_EVA, t, 32, 128, window, 4 if window == 512 else 16)
+
+
+def test_data_parallel_step_compiles_with_its_exchange_options():
+    """The mesh train step as ``FusedStepRunner._build_steps`` jits it
+    (ISSUE 31) through the v5e's compiler for a 2x2 host: the three
+    options ``GradExchange`` hands over are names libtpu knows (an
+    unknown one fails the compile), the wide dense layer's gradient is
+    made from gathered activations — no all-reduce of its shape in the
+    compiled text, the global minibatch's rows gathered instead — and
+    the ``shard_map`` inside the scanned backward partitions.
+    tests_tpu/test_dp_exchange.py runs AlexNet's on four chips."""
+    _compile(DP_STEP, 64, 2048, 2)

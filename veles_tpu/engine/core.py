@@ -145,12 +145,16 @@ def put(array: Any, where: Any = None):
 
 def donating_jit(fn, donate: Tuple[int, ...] = (),
                  in_shardings: Any = None, out_shardings: Any = None,
-                 static_argnums: Any = None):
+                 static_argnums: Any = None,
+                 compiler_options: Optional[Dict[str, Any]] = None):
     """THE donation seam: ``jax.jit`` with ``donate_argnums`` spelled
     exactly once in the repo.  ``donate=()`` compiles without donation
     (the eval/predict dispatchers); sharding kwargs pass through only
     when given, so the non-mesh call is byte-identical to a bare
-    ``jax.jit(fn, donate_argnums=...)``."""
+    ``jax.jit(fn, donate_argnums=...)``.  ``compiler_options`` go with
+    ONE program (the data-parallel train step: :class:`GradExchange`);
+    an option the backend's compiler does not know raises at that
+    program's first compile."""
     import jax
 
     watch_compiles()
@@ -163,6 +167,8 @@ def donating_jit(fn, donate: Tuple[int, ...] = (),
         kw["out_shardings"] = out_shardings
     if static_argnums is not None:
         kw["static_argnums"] = static_argnums
+    if compiler_options:
+        kw["compiler_options"] = dict(compiler_options)
     return jax.jit(fn, **kw)
 
 
@@ -335,7 +341,159 @@ def _not_f32(compute_dtype) -> bool:
     return compute_dtype != jnp.float32
 
 
-def build_backward(forwards, gds, compute_dtype, seed: int = 0):
+#: what the TPU's compiler is told with a data-parallel train step
+#: (libtpu 0.0.34 knows all three; an unknown name fails the step's
+#: first compile).  Left alone it joins every all-reduce the
+#: partitioner emitted into ONE behind the last gradient; with these
+#: each gradient's all-reduce stays its own op (the combiner's count
+#: threshold), is asynchronous, and is woven into the compute fusion
+#: scheduled beside it ("async collective fusion").
+TPU_GRAD_EXCHANGE_OPTIONS = {
+    "xla_jf_crs_combiner_threshold_count": "1",
+    "xla_enable_async_all_reduce": "true",
+    "xla_tpu_enable_async_collective_fusion_fuse_all_reduce": "true",
+}
+
+
+class GradExchange:
+    """How a data-parallel train step makes every device hold the
+    GLOBAL minibatch's gradients — planned from shapes alone, for a
+    mesh whose rows ride the batch sharding; there is none without a
+    mesh.
+
+    Every device walks back its own rows.  A layer's weight gradient
+    is a sum over the rows of the minibatch, so the devices must
+    exchange something, and there are two things they can exchange:
+
+    ``reduced``
+        each device's partial gradient, summed across devices (the
+        partitioner's all-reduce, pinned to the layer that made it):
+        ``2 (n-1)/n`` x the gradient's bytes on the wire;
+    ``gathered``
+        the layer's saved activations and its error, gathered to every
+        device, which then makes the whole gradient itself
+        (:meth:`gathered_backward`): ``(n-1)/n`` x the activations'
+        bytes, no reduction at all, and the sum over the rows happens
+        in the matmul's f32 accumulator as on one device.
+
+    A layer is ``gathered`` when that moves fewer bytes than its
+    gradient has — a dense layer at a minibatch of a few hundred rows
+    (AlexNet's fc6: 17.8 MB of activations against a 75.5 MB
+    gradient); a convolution's activations dwarf its kernel, so it
+    stays ``reduced``.  The weight gradient of a gathered layer costs
+    ``n`` x the matmul work, on a matmul that waits for HBM at such a
+    minibatch.  Either way the step is synchronous SGD on the global
+    minibatch: every gradient is whole before its update, in the step
+    that made it, and every device computes the same update."""
+
+    def __init__(self, mesh, forwards, gds, dtype) -> None:
+        from veles_tpu.parallel import mesh as mesh_helpers
+
+        self.mesh = mesh
+        self.axis = mesh.axis_names[0]
+        self.devices = n = int(mesh.devices.size)
+        self.replicated = mesh_helpers.replicated_sharding(mesh)
+        self.dtype = np.dtype(dtype)
+        item = self.dtype.itemsize
+        #: one entry a layer with parameters, in the order the
+        #: backward walk makes its gradients
+        self.groups: list = []
+        self.leaves = 0
+        self._gathered = set()
+        for i in reversed(range(len(forwards))):
+            f = forwards[i]
+            vecs = [v for v in f.param_vectors().values() if v] \
+                if gds[i] is not None else []
+            if not vecs:
+                continue
+            self.leaves += len(vecs)
+            grad = sum(int(v.size) for v in vecs) * item
+            # what a gathered backward needs of every row: the
+            # layer's input, its output and the error at its output
+            acts = (int(np.prod(f.input.shape))
+                    + 2 * int(np.prod(f.output.shape))) * item
+            gathered = acts < grad and \
+                getattr(f, "residual_of", None) is None
+            if gathered:
+                self._gathered.add(i)
+            self.groups.append({
+                "layer": f.name, "bytes": grad,
+                "how": "gathered" if gathered else "reduced",
+                "wire_bytes": (acts if gathered else 2 * grad)
+                * (n - 1) // n})
+        self.bytes = sum(g["bytes"] for g in self.groups)
+        platform = mesh.devices.flat[0].platform
+        self.options: Dict[str, str] = dict(
+            TPU_GRAD_EXCHANGE_OPTIONS) if platform == "tpu" else {}
+
+    def describe(self) -> Dict[str, Any]:
+        """The ``dp.grad_exchange`` journal event's fields."""
+        return {"devices": self.devices, "leaves": self.leaves,
+                "bytes": self.bytes, "dtype": self.dtype.name,
+                "groups": self.groups, "options": self.options}
+
+    def gathers(self, i: int) -> bool:
+        return i in self._gathered
+
+    def reduced(self, grads):
+        """Pin a layer's gradients replicated where the walk made
+        them, so the partitioner's all-reduce sits at that layer."""
+        import jax
+        from jax import lax
+
+        return jax.tree_util.tree_map(
+            lambda g: lax.with_sharding_constraint(g, self.replicated),
+            grads)
+
+    def gathered_backward(self, gd, cparams, saved, err,
+                          need_err_input: bool):
+        """``gd.backward_from_saved`` with the parameter gradients made
+        from the rows of EVERY device: the saved leaves that lead with
+        the minibatch axis and the error are all-gathered, the error
+        at the layer's input stays this device's rows (its own call on
+        the local rows where the unit can skip it, a slice
+        otherwise)."""
+        import jax
+        from jax import lax, shard_map
+        from jax.sharding import PartitionSpec
+
+        axis, mb = self.axis, err.shape[0]
+        rows, whole = PartitionSpec(axis), PartitionSpec()
+        batched = jax.tree_util.tree_map(
+            lambda a: bool(getattr(a, "ndim", 0)) and a.shape[0] == mb,
+            saved)
+
+        def everyone(a):
+            return lax.all_gather(a, axis, axis=0, tiled=True)
+
+        def local(cp, saved, err):
+            all_saved = jax.tree_util.tree_map(
+                lambda a, b: everyone(a) if b else a, saved, batched)
+            if gd.can_skip_err_input:
+                _, grads = gd.backward_from_saved(
+                    cp, all_saved, everyone(err), need_err_input=False)
+                if not need_err_input:
+                    return None, grads
+                # this device's rows only; its gradients are dead code
+                return gd.backward_from_saved(cp, saved, err)[0], grads
+            err_in, grads = gd.backward_from_saved(
+                cp, all_saved, everyone(err))
+            return lax.dynamic_slice_in_dim(
+                err_in, lax.axis_index(axis) * err.shape[0],
+                err.shape[0]), grads
+
+        return shard_map(
+            local, mesh=self.mesh,
+            in_specs=(jax.tree_util.tree_map(lambda _: whole, cparams),
+                      jax.tree_util.tree_map(
+                          lambda b: rows if b else whole, batched),
+                      rows),
+            out_specs=(rows if need_err_input else None, whole),
+            check_vma=False)(cparams, saved, err)
+
+
+def build_backward(forwards, gds, compute_dtype, seed: int = 0,
+                   exchange: Optional[GradExchange] = None):
     """The backward + SGD chain: walk the gradient units in reverse,
     skip the chain-head err_input when nothing consumes it, and apply
     ``update_params`` with the per-call (lr, bias-lr) row — plus the
@@ -350,7 +508,10 @@ def build_backward(forwards, gds, compute_dtype, seed: int = 0):
     re-runs its layers' forward here, under ``bwd/<layer>/recompute``,
     behind a barrier that ties the re-run to the error's arrival (so
     the compiler can neither share it with the first forward nor run
-    it early); ``seed`` is the forward's, for the same rng keys."""
+    it early); ``seed`` is the forward's, for the same rng keys.
+
+    ``exchange`` (a mesh's :class:`GradExchange`, None without one)
+    says how each layer's gradients become the global minibatch's."""
     import jax
     from jax import lax
 
@@ -387,7 +548,14 @@ def build_backward(forwards, gds, compute_dtype, seed: int = 0):
                 if gd is None:
                     continue
                 with jax.named_scope("bwd/" + f.name):
-                    if i == first_gd and gd.can_skip_err_input:
+                    skip = i == first_gd and gd.can_skip_err_input
+                    gathered = exchange is not None \
+                        and exchange.gathers(i)
+                    if gathered:
+                        err_in, grads = exchange.gathered_backward(
+                            gd, cparams[f.name], residuals[i], err,
+                            not skip)
+                    elif skip:
                         # nothing consumes the chain-head err_input;
                         # for conv1 this skips the input-dilated
                         # transposed conv (the worst MXU op here)
@@ -398,6 +566,8 @@ def build_backward(forwards, gds, compute_dtype, seed: int = 0):
                     else:
                         err_in, grads = gd.backward_from_saved(
                             cparams[f.name], residuals[i], err)
+                    if grads and exchange is not None and not gathered:
+                        grads = exchange.reduced(grads)
                 if grads:
                     with jax.named_scope("update/" + f.name):
                         if wd is None:
@@ -908,12 +1078,14 @@ class ExecutionCore:
     # -- compile -------------------------------------------------------
 
     def jit(self, fn, donate: Tuple[int, ...] = (),
-            in_shardings: Any = None, out_shardings: Any = None):
+            in_shardings: Any = None, out_shardings: Any = None,
+            compiler_options: Optional[Dict[str, Any]] = None):
         """Compile through the donation seam; ``donate`` is dropped
         when the core was built with ``donate=False``."""
         return donating_jit(
             fn, donate=donate if self.donate else (),
-            in_shardings=in_shardings, out_shardings=out_shardings)
+            in_shardings=in_shardings, out_shardings=out_shardings,
+            compiler_options=compiler_options)
 
     @staticmethod
     def vmap_members(fn, in_axes):

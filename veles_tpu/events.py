@@ -78,6 +78,12 @@ EV_FUSED_RECOMPUTE = _ev("fused.recompute")
 #: ``window``), the kernels' ``tiles`` where it is ``fused``; once a
 #: unit at ``initialize``, again only where a later trace must differ
 EV_EVA_PATH = _ev("eva.path")
+#: how a data-parallel train step exchanges its gradients, once at
+#: ``FusedStepRunner._build_steps`` on a mesh and never without one
+#: (``engine/core.py`` ``GradExchange``): ``devices``, ``leaves``,
+#: ``bytes`` and ``dtype`` of the gradients, ``groups`` in the order
+#: the backward walk makes them, ``options`` handed to the compiler
+EV_DP_GRAD_EXCHANGE = _ev("dp.grad_exchange")
 
 #: one per backend compile OR persistent-cache load of a program
 #: (jax.monitoring reports both under one name): ``seconds``, the
@@ -274,6 +280,9 @@ CTR_SUPERVISOR_RESTARTS = _ctr("supervisor.restarts")
 
 GAUGE_FUSED_MFU = _gauge("fused.mfu")
 GAUGE_FUSED_KEPT_ACTIVATION_BYTES = _gauge("fused.kept_activation_bytes")
+#: layers whose gradients the traced data-parallel step exchanges
+#: (never set without a mesh)
+GAUGE_DP_GRAD_EXCHANGE_GROUPS = _gauge("dp.grad_exchange_groups")
 GAUGE_EVA_WINDOW = _gauge("eva.window")
 GAUGE_EVA_CHUNK = _gauge("eva.chunk")
 GAUGE_EVA_SUMMARIES_PER_ROW = _gauge("eva.summaries_per_row")

@@ -233,8 +233,19 @@ class FusedStepRunner(AcceleratedUnit):
         recompute = self._decide_recompute(cd)
         forward_pass = engine_core.build_forward(
             self.forwards, seed, cd, recompute)
+        # on a mesh: how each layer's gradients become the global
+        # minibatch's (gathered activations or an all-reduce, from
+        # shapes), and what the chip's compiler is told with the step
+        exchange = None
+        if core.on_mesh:
+            exchange = engine_core.GradExchange(
+                core.mesh, self.forwards, self.gds, cd)
+            telemetry.event(events.EV_DP_GRAD_EXCHANGE,
+                            **exchange.describe())
+            telemetry.gauge(events.GAUGE_DP_GRAD_EXCHANGE_GROUPS).set(
+                len(exchange.groups))
         backward_update = engine_core.build_backward(
-            self.forwards, self.gds, cd, seed)
+            self.forwards, self.gds, cd, seed, exchange)
 
         data_sharded = self.data_sharded and self.mesh is not None
         gather = None if streaming else engine_core.take_rows
@@ -263,7 +274,9 @@ class FusedStepRunner(AcceleratedUnit):
             # data axis, params replicated.  mask.sum() and the
             # per-param batch reductions cross the sharded axis, so the
             # partitioner emits the gradient allreduce (ICI psum) —
-            # this IS the master-slave aggregation, in-compiler.
+            # this IS the master-slave aggregation, in-compiler —
+            # except where ``exchange`` gathers a layer's activations
+            # instead (engine/core.py GradExchange).
             repl = core.replicated
             # every scanned array rides the batch sharding — each
             # device receives only its slice of every minibatch
@@ -276,8 +289,9 @@ class FusedStepRunner(AcceleratedUnit):
                 else (store, store, batch, batch)
             train_in = (repl,) * 4 + feed + (repl, repl)
             eval_in = (repl,) * 3 + feed + (repl,)
-        self._train_step = core.jit(train_step, donate=(0, 1, 2, 3),
-                                    in_shardings=train_in)
+        self._train_step = core.jit(
+            train_step, donate=(0, 1, 2, 3), in_shardings=train_in,
+            compiler_options=exchange.options if exchange else None)
         self._eval_step = core.jit(eval_step, donate=(1, 2),
                                    in_shardings=eval_in)
 

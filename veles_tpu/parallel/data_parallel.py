@@ -5,7 +5,9 @@ Replaces the reference's master--slave gradient aggregation
 canonical weights) with a single-controller sharded jit: the fused
 step's minibatch ``indices``/``mask`` are sharded over the mesh's
 ``data`` axis, parameters stay replicated, and XLA inserts the gradient
-allreduce over ICI.  Semantics are synchronous SGD on the GLOBAL
+allreduce over ICI (a layer whose activations are fewer bytes than its
+gradient gathers them instead: engine/core.py ``GradExchange``).
+Semantics are synchronous SGD on the GLOBAL
 minibatch — numerically the same training trajectory as the
 single-device fused step (the tests assert this on a virtual CPU mesh).
 """
