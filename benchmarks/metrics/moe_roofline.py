@@ -1,0 +1,22 @@
+"""The mixture of experts' share of its roofline (router, dispatch, the
+held experts' grouped products, the shared expert): the analytic floor
+of the mechanism in one train step (``flops_lm.moe_floor_seconds``:
+forward + backward, per layer the larger of FLOPs / peak and minimum
+bytes / peak: MXU-bound, 6.1 ms a layer) x steps of the traced window,
+over the device time under the ``moe/`` scopes there.  Recomputed
+forwards are in the time and not in the floor."""
+
+from benchmarks.lib import flops_lm
+
+
+def read(ctx):
+    tr, sc = ctx["traced"], ctx.get("scopes") or {}
+    if not tr.get("images") or not sc.get("moe_s"):
+        return None
+    mix, pk = ctx["mix"], ctx["peaks"]
+    rows = int(mix["minibatch"]) // ctx["chips"]
+    floor = flops_lm.moe_floor_seconds(
+        ctx["cfg"]["layers"], ctx["seq_len"], rows,
+        pk["flops_bf16"], pk["hbm_bytes_per_s"])
+    steps = tr["images"] / float(mix["minibatch"])
+    return 100.0 * floor * steps / sc["moe_s"]

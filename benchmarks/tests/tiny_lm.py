@@ -1,0 +1,36 @@
+"""A tiny language-model configuration and mix for rehearsals on the
+CPU: Qwen3-Next's layer types at toy widths (the model file's
+``TINY``).  Numbers from such a run are counts and correctness checks,
+never speeds."""
+
+import json
+
+from veles_tpu.models.qwen3next import TINY, qwen3next_layers
+
+CFG = {
+    "name": "tiny_lm", "input_shape": [TINY["seq_len"]],
+    "loss": "next_byte", "init_std": TINY["initializer_range"],
+    "dataset": {"kind": "packed_token_documents",
+                "->": {"n_values": TINY["vocab_held"] - 1,
+                       "separator": TINY["vocab_held"] - 1,
+                       "median_len": 48, "sigma": 1.2}},
+    "layers": json.loads(json.dumps(qwen3next_layers(**TINY))),
+}
+
+MIX = {
+    "name": "tiny_lm.train_packed", "config": "tiny_lm",
+    "traffic": "train_resident_lm", "chips": 1,
+    "seq_len": TINY["seq_len"], "minibatch": 1, "superstep": 2,
+    "n_train": 4, "trace_seconds": 0.2, "trace_firings": 2,
+    "reference_seq_block": 32,
+    "end_to_end": ["setup_s", "train_images_per_s"],
+    "per_layer": ["loader.run_ms", "fused.dispatch_ms",
+                  "fused.compiles_in_window", "decision.epoch_end_ms",
+                  "device.idle_pct", "fused.recomputed_pct",
+                  "lm.step_mfu_pct", "gdn_roofline", "gdn.busy_pct",
+                  "full_attention_roofline", "full_attention.busy_pct",
+                  "moe_roofline", "moe.busy_pct"],
+    # f32 program against the f32 reference on XLA:CPU
+    "limits": {"loss_gap": 1e-4, "momentum_gap": 2e-3,
+               "update_gap": 2e-3},
+}

@@ -572,7 +572,7 @@ def test_tiny_alexnet_step_is_the_parents_program(monkeypatch,
         if parent:
             monkeypatch.setattr(
                 engine_core, "build_forward",
-                lambda f, seed, cd, recompute=False:
+                lambda f, seed, cd, recompute=False, head_apart=False:
                 _parent_forward(f, seed, cd))
             monkeypatch.setattr(
                 engine_core, "build_backward",

@@ -148,6 +148,40 @@ assert "bf16[%d,144]" % mb in hlo or "bf16[%d,12,12,1]" % mb in hlo
 print("COMPILED")
 """
 
+HYBRID_UNITS = PRELUDE + """
+from veles_tpu.models.qwen3next import qwen3next_layers
+from veles_tpu.ops import deltanet
+from veles_tpu.ops.registry import forward_registry
+from veles_tpu.ops.sequence import SequenceUnit
+
+kind, t = sys.argv[1], int(sys.argv[2])
+SequenceUnit.platform = lambda self: "tpu"      # no chip to observe
+flat = [c for e in qwen3next_layers() for c in e.get("layers", [e])]
+fw = dict(next(c for c in flat if c["type"] == kind)["->"])
+fw.pop("weights_stddev")
+unit = forward_registry[kind][0](None, name="u", **fw)
+x = spec((1, t, 2048), jnp.bfloat16)
+params = {n: spec(s, jnp.bfloat16)
+          for n, s in unit.param_shapes(x.shape).items()}
+
+
+def both(params, x, err):
+    out, back = jax.vjp(unit.forward, params, x)
+    return (out,) + back(err)
+
+
+err = spec(unit.output_shape_for(x.shape), jnp.bfloat16)
+hlo = jax.jit(both).lower(params, x, err).compile().as_text()
+want = {"gated_attention": ("splash", 3), "moe": ("gmm", 9),
+        "gated_delta_net": ("chunked", 0)}[kind]
+form = {"gated_attention": lambda: unit.path, "moe": lambda: unit.share,
+        "gated_delta_net": lambda: deltanet.rule_path(t, unit.chunk_size)
+        }[kind]()
+assert form["form"] == want[0], form
+assert hlo.count("tpu_custom_call") >= want[1], hlo.count("tpu_custom_call")
+print("COMPILED")
+"""
+
 
 def _compile(src, *argv):
     res = subprocess.run(
@@ -196,3 +230,20 @@ def test_data_parallel_step_compiles_with_its_exchange_options():
     the ``shard_map`` inside the scanned backward partitions.
     tests_tpu/test_dp_exchange.py runs AlexNet's on four chips."""
     _compile(DP_STEP, 64, 2048, 2)
+
+
+@pytest.mark.parametrize("kind,t", [("gated_delta_net", 4096),
+                                    ("gated_attention", 4096),
+                                    ("moe", 4096)])
+def test_hybrid_layer_types_compile_at_the_published_widths(kind, t):
+    """The three layer types of ISSUE 32, forward and backward, through
+    the v5e's compiler in bf16 at Qwen3-Next's published widths on a
+    row of 4096: the chunked delta rule with its scan over chunks, the
+    causal attention core on the flash kernel that ships with jax (16
+    query heads x 256 over 2 key heads: its multi-query kernel mapped
+    over the key heads, forward + dq + dkv), and the expert share's
+    three grouped products on the shipped grouped-matmul kernel
+    (forward + two backward each) with buffers sized for the worst
+    routing.  tests_tpu/test_hybrid_layers.py runs them on the chip."""
+    _compile(HYBRID_UNITS, kind, t)
+

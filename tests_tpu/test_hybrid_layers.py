@@ -1,0 +1,153 @@
+"""The three layer types of ISSUE 32 on the chip, at Qwen3-Next's
+published widths in bf16: each TPU form (the chunked delta rule; the
+shipped flash kernel under ``gated_attention``; the shipped grouped
+matmul under ``moe``) against the plain form every other platform runs,
+each within the gap that plain form itself keeps from an f32 run of the
+same mathematics (the bf16 witness); and the cell's own sizes through
+``StandardWorkflow``: the journaled paths, the blocked loss, and one
+firing whose ``moe.load`` reads ``dropped`` 0.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from veles_tpu import events, prng, telemetry
+from veles_tpu.models import qwen3next
+from veles_tpu.models.qwen3next import CUT, PUBLISHED, qwen3next_layers
+from veles_tpu.ops import attention, deltanet, moe
+from veles_tpu.ops.registry import forward_registry
+
+T = 4096
+
+
+def _gap(got, want):
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def _unit(kind):
+    flat = [c for e in qwen3next_layers() for c in e.get("layers", [e])]
+    fw = dict(next(c for c in flat if c["type"] == kind)["->"])
+    fw.pop("weights_stddev")
+    return forward_registry[kind][0](None, name="u_" + kind, **fw)
+
+
+def test_splash_core_matches_the_xla_core(tpu_device):
+    keys = jax.random.split(jax.random.key(3232), 4)
+    q = jax.random.normal(keys[0], (1, T, 2, 8, 256), jnp.float32) / 16.0
+    k, v = (jax.random.normal(keys[i], (1, T, 2, 256), jnp.float32)
+            for i in (1, 2))
+    err = jax.random.normal(keys[3], q.shape, jnp.float32)
+
+    def run(fn, dtype):
+        @jax.jit
+        def both(err, *args):
+            out, back = jax.vjp(fn, *(a.astype(dtype) for a in args))
+            return (out,) + back(err.astype(dtype))
+        return both(err, q, k, v)
+
+    with jax.default_matmul_precision("highest"):
+        exact = run(attention.core_xla, jnp.float32)
+    xla = run(attention.core_xla, jnp.bfloat16)
+    splash = run(lambda *a: attention.core_splash(*a, 512), jnp.bfloat16)
+    for name, e, x, s in zip(("o", "dq", "dk", "dv"), exact, xla, splash):
+        assert s.shape == x.shape and s.dtype == x.dtype, name
+        assert np.isfinite(np.asarray(s, np.float32)).all(), name
+        witness, ours = _gap(x, e), _gap(s, e)
+        assert ours <= 1.5 * witness + 2e-3, (name, ours, witness)
+
+
+def test_gmm_experts_match_ragged_dot(tpu_device):
+    unit = _unit("moe")
+    unit.device = tpu_device
+    x = jax.random.normal(jax.random.key(1), (1, T, 2048), jnp.bfloat16)
+    params = {n: (0.02 * jax.random.normal(
+        jax.random.fold_in(jax.random.key(2), i), s)).astype(jnp.bfloat16)
+        for i, (n, s) in enumerate(unit.param_shapes(x.shape).items())}
+    err = jax.random.normal(jax.random.key(3), x.shape, jnp.bfloat16)
+
+    def both(params, x, err):
+        out, back = jax.vjp(unit.forward, params, x)
+        return (out,) + back(err)
+
+    fast = jax.jit(both)(params, x, err)
+    assert unit.share["form"] == "gmm" and unit.share["rows"] == T * 10
+    plain = moe.grouped_path
+    try:
+        moe.grouped_path = lambda *a, **k: {"form": "ragged_dot",
+                                            "reason": "test"}
+        slow = jax.jit(lambda *a: both(*a))(params, x, err)
+    finally:
+        moe.grouped_path = plain
+    assert unit.share["form"] == "ragged_dot"
+    for a, b in zip(jax.tree.leaves(fast), jax.tree.leaves(slow)):
+        assert np.isfinite(np.asarray(a, np.float32)).all()
+        assert _gap(a, b) <= 2e-2, _gap(a, b)
+    got = jax.device_get(jax.jit(unit.probe)(params, x))
+    assert int(got["dropped"]) == 0
+    # about 4096 x 10 x 32 / 512 = 2560 pairs land on this share
+    assert 1500 < int(got["expert_rows"].sum()) < 4000
+
+
+def test_chunked_rule_matches_the_recurrence(tpu_device):
+    ks = jax.random.split(jax.random.key(7), 5)
+    unit = lambda a: a / jnp.linalg.norm(  # noqa: E731
+        a, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (1, T, 16, 128))) / 128 ** 0.5
+    k = unit(jax.random.normal(ks[1], (1, T, 16, 128)))
+    v = jax.random.normal(ks[2], (1, T, 32, 128))
+    g = -jax.nn.softplus(jax.random.normal(ks[3], (1, T, 32))) * 0.2
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, T, 32)))
+    with jax.default_matmul_precision("highest"):
+        exact = jax.jit(deltanet.rule_recurrent)(q, k, v, g, beta)
+    got = jax.jit(lambda *a: deltanet.rule_chunked(
+        *a, 64, jnp.bfloat16))(q, k, v, g, beta)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    assert _gap(got, exact) <= 3e-2, _gap(got, exact)
+
+
+def test_the_cells_sizes_take_the_chip_forms_and_drop_nothing(
+        tpu_device):
+    """``veles_tpu/models/qwen3next.py`` as the cell runs it: 4 layers,
+    32 of 512 experts, 18 992 ids, a row of 32 768; one firing."""
+    telemetry.reset()
+    prng.seed_all(32)
+
+    class Launcher:
+        workflow = None
+
+    w = qwen3next.create_workflow(
+        Launcher(), loader=dict(qwen3next.DEFAULTS["loader"], n_train=2),
+        superstep=1, decision={"max_epochs": 1})
+    w.initialize(device=tpu_device)
+    assert [e["form"] for e in telemetry.recent_events(
+        events.EV_GDN_PATH)] == ["chunked"] * 3
+    seen = telemetry.recent_events(events.EV_ATTN_PATH)
+    assert [(e["form"], e["tiles"]["block_q"]) for e in seen] == [
+        ("splash", 512)]
+    shares = telemetry.recent_events(events.EV_MOE_SHARE)
+    assert len(shares) == 4 and all(
+        (e["form"], e["experts_total"], e["experts_held"], e["top_k"],
+         e["rows"], e["blocks"]) == ("gmm", 512, 32, 10, 40960, 8)
+        for e in shares)
+    blocked = telemetry.recent_events(events.EV_LOSS_BLOCKED)[-1]
+    assert (blocked["blocks"], blocked["reason"]) == (
+        16, "whole_exceeds_free")
+    assert telemetry.recent_events(events.EV_FUSED_RECOMPUTE)[-1][
+        "policy"] == "recompute"
+    w.loader.run()
+    w.fused.run()
+    jax.block_until_ready(w.fused._params)
+    loads = telemetry.recent_events(events.EV_MOE_LOAD)
+    assert len(loads) == 4
+    for e in loads:
+        assert e["dropped"] == 0
+        # 32 768 x 10 / 16 = 20 480 expected; never all, never none
+        assert 10_000 < e["local_assignments"] < 40_000, e
+    assert telemetry.gauge(events.GAUGE_MOE_DROPPED_ROWS).value == 0
+    _, loss_sum, count, _ = w.fused.take_class_metrics()
+    assert count == CUT["seq_len"] - 1
+    assert abs(loss_sum / count - np.log(CUT["vocab_held"])) < 0.5
+    assert PUBLISHED["num_experts"] == 512
+    w.stop()

@@ -621,34 +621,44 @@ def synthetic_classification_device(n: int, shape: Tuple[int, ...],
     return data, labels
 
 
-def synthetic_packed_bytes(n_rows: int, seq_len: int, seed: int,
-                           median_len: int = 2048, sigma: float = 1.2,
-                           separator: int = 256) -> np.ndarray:
-    """Rows of packed byte documents, int32 ``[n_rows, seq_len]``: a
-    stream of documents with log-normal lengths (median ``median_len``
-    bytes; at sigma 1.2 one in a hundred is longer than 32 kB), bytes
-    from a seeded order-1 Markov chain over 256 values, one
-    ``separator`` id (>= 256: outside the byte range, inside a byte
-    model's vocabulary) after each document, cut into rows with no
-    regard to document boundaries — how a byte-level LM's pre-training
-    data is packed."""
+def synthetic_packed_tokens(n_rows: int, seq_len: int, seed: int,
+                            n_values: int, separator: int,
+                            median_len: int = 2048, sigma: float = 1.2
+                            ) -> np.ndarray:
+    """Rows of packed documents of token ids, int32 ``[n_rows,
+    seq_len]``: a stream of documents with log-normal lengths (median
+    ``median_len`` tokens), ids from a seeded order-1 Markov chain over
+    ``n_values`` values, one ``separator`` id after each document, cut
+    into rows with no regard to document boundaries — how a language
+    model's pre-training data is packed."""
     rng = np.random.default_rng(seed)
     total = n_rows * seq_len
-    # x[t+1] = (perm[x[t]] + e[t]) mod 256, e geometric: every byte has
-    # a few likely successors — structure for a next-byte loss to learn
-    perm = rng.permutation(256)
-    steps = np.minimum(rng.geometric(0.35, total) - 1, 255)
+    # x[t+1] = (perm[x[t]] + e[t]) mod n_values, e geometric: every id
+    # has a few likely successors — structure for a next-token loss
+    perm = rng.permutation(n_values)
+    steps = np.minimum(rng.geometric(0.35, total) - 1, n_values - 1)
     out = np.empty(total, np.int32)
-    x = int(rng.integers(256))
+    x = int(rng.integers(n_values))
     for t in range(total):
         out[t] = x
-        x = (perm[x] + steps[t]) & 255
+        x = (perm[x] + steps[t]) % n_values
     n_docs = max(8, 4 * total // median_len)
     lengths = np.maximum(1, np.rint(rng.lognormal(
         np.log(median_len), sigma, n_docs))).astype(np.int64)
     ends = np.cumsum(lengths + 1) - 1      # one separator a document
     out[ends[ends < total]] = separator
     return out.reshape(n_rows, seq_len)
+
+
+def synthetic_packed_bytes(n_rows: int, seq_len: int, seed: int,
+                           median_len: int = 2048, sigma: float = 1.2,
+                           separator: int = 256) -> np.ndarray:
+    """:func:`synthetic_packed_tokens` over the 256 byte values (at
+    sigma 1.2 one document in a hundred is longer than 32 kB), the
+    ``separator`` id >= 256: outside the byte range, inside a byte
+    model's vocabulary."""
+    return synthetic_packed_tokens(n_rows, seq_len, seed, 256, separator,
+                                   median_len, sigma)
 
 
 def _main(argv=None) -> int:

@@ -44,7 +44,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -199,7 +199,7 @@ def build_ingest(dequant: Any):
 
 
 def build_forward(forwards, seed: int, compute_dtype,
-                  recompute: bool = False):
+                  recompute: bool = False, head_apart: bool = False):
     """One model's forward chain WITH residuals — the train-mode body
     every loop traces.  The rng key chain (``fold_in(fold_in(key(seed),
     rc), i)`` per stochastic layer) is the repo-wide dropout contract:
@@ -213,11 +213,17 @@ def build_forward(forwards, seed: int, compute_dtype,
     entry keeps only its INPUT (and the rng counter) as the residual
     of its first layer: :func:`build_backward` re-runs the entry's
     forward inside its backward instead of keeping every layer's
-    residuals for the whole chain."""
+    residuals for the whole chain.  With ``head_apart`` the walk ends
+    BEFORE the last layer and returns that layer's input: the head and
+    the loss are made together, a block of positions at a time
+    (:func:`build_blocked_head`)."""
     import jax
 
     mixed = _not_f32(compute_dtype)
     chain = chain_of(forwards)
+    if head_apart:
+        assert chain[-1] == len(forwards) - 1, chain[-1]
+        chain = chain[:-1]
 
     def forward_pass(params, x, rng_counter, train: bool):
         residuals = [None] * len(forwards)
@@ -551,7 +557,11 @@ def build_backward(forwards, gds, compute_dtype, seed: int = 0,
                     skip = i == first_gd and gd.can_skip_err_input
                     gathered = exchange is not None \
                         and exchange.gathers(i)
-                    if gathered:
+                    if isinstance(residuals[i], HeadDone):
+                        # the blocked head walked itself back: ``err``
+                        # already is the error at its input
+                        err_in, grads = err, residuals[i].grads
+                    elif gathered:
                         err_in, grads = exchange.gathered_backward(
                             gd, cparams[f.name], residuals[i], err,
                             not skip)
@@ -592,6 +602,116 @@ def build_backward(forwards, gds, compute_dtype, seed: int = 0,
     return backward_update
 
 
+class HeadDone(NamedTuple):
+    """What stands in the residuals for a head that
+    :func:`build_blocked_head` has already walked back: its gradients
+    (f32, summed over the blocks)."""
+    grads: Any
+
+
+class BlockedHead(NamedTuple):
+    """:func:`build_blocked_head`'s result: the head's ``name`` (the
+    key of its parameters), ``train`` and ``evaluate``."""
+    name: str
+    train: Any
+    evaluate: Any
+
+
+def build_blocked_head(head, evaluator, blocks: int):
+    """The last layer and the loss together, a block of positions at a
+    time (a :class:`BlockedHead`) — for a head whose whole logits, and
+    the loss's arrays at their shape, would not fit beside the state.
+
+    ``head`` acts on each position alone (``[rows, T, width]`` ->
+    ``[rows, T, ...]``); ``evaluator.block_metrics(output, target,
+    mask, start, n)`` is the loss of the positions ``start ..`` of a
+    row under the mean over ``n = evaluator.valid_count(...)``.
+    ``train(head_params, x, target, mask)`` returns ``(metrics, the
+    head's gradients in f32, the error at x)``; ``evaluate`` the
+    metrics alone.  One ``lax.scan`` over the blocks: a block's logits
+    are made, scored, walked back and dropped before the next."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def walk(step, carry, x, mask):
+        """Scan ``step(carry, block of x, its first position, n) ->
+        (carry, the block's metrics, y)`` over the blocks."""
+        rows, t = x.shape[:2]
+        tb = t // blocks
+        n = evaluator.valid_count(head.output_shape_for(x.shape), mask)
+
+        def body(c, xs):
+            carry, n_err, loss = c
+            xb, i = xs
+            with jax.named_scope(events.SCOPE_LOSS_BLOCK):
+                carry, m, y = step(carry, xb, i * tb, n)
+            return (carry, n_err + m["n_err"], loss + m["loss_sum"]), y
+
+        (carry, n_err, loss), ys = lax.scan(
+            body, (carry, jnp.float32(0.0), jnp.float32(0.0)),
+            (jnp.moveaxis(x.reshape((rows, blocks, tb) + x.shape[2:]),
+                          1, 0), jnp.arange(blocks)))
+        return carry, ys, {"n_err": n_err, "loss_sum": loss, "count": n}
+
+    def train(params, x, target, mask):
+        def fn(grads, xb, start, n):
+            logits, back = jax.vjp(head.forward, params, xb)
+            m = evaluator.block_metrics(logits, target, mask, start, n)
+            g, dxb = back(m.pop("err_output"))
+            grads = jax.tree_util.tree_map(
+                lambda a, b: a + b.astype(jnp.float32), grads, g)
+            return grads, m, dxb
+
+        zeros = jax.tree_util.tree_map(
+            lambda a: jnp.zeros(a.shape, jnp.float32), params)
+        grads, dx, m = walk(fn, zeros, x, mask)
+        return m, grads, jnp.moveaxis(dx, 0, 1).reshape(x.shape)
+
+    def evaluate(params, x, target, mask):
+        def fn(carry, xb, start, n):
+            m = evaluator.block_metrics(head.forward(params, xb), target,
+                                        mask, start, n)
+            m.pop("err_output")
+            return carry, m, None
+
+        return walk(fn, None, x, mask)[2]
+
+    return BlockedHead(head.name, train, evaluate)
+
+
+def build_probe(forwards, compute_dtype):
+    """A forward-only walk of the chain that asks every unit with a
+    ``probe(params, x)`` what its input puts on it, and ends at the
+    last of them: ``probe(params, x) -> {unit name: its answer}``; None
+    where no unit has one.  Never part of a step: set-up's own program
+    (``FusedStepRunner.probe_units``)."""
+    from veles_tpu.ops import batching
+
+    probed = [i for i, f in enumerate(forwards) if hasattr(f, "probe")]
+    if not probed:
+        return None
+    cast = batching.make_caster(compute_dtype)
+    chain = chain_of(forwards)
+
+    def probe(params, x):
+        cparams, out = cast(params), {}
+        for entry in chain:
+            skip = x
+            for i in _layers_of(entry):
+                f = forwards[i]
+                if i in probed:
+                    out[f.name] = f.probe(cparams[f.name], x)
+                if i == probed[-1]:
+                    return out
+                x, _ = f.apply_fwd(cparams[f.name], x, train=False)
+            if not isinstance(entry, int):
+                x = _skip_add(skip, x)
+        return out
+
+    return probe
+
+
 def take_rows(dataset, target_store, indices):
     """The plain gather of a resident feed: one minibatch's rows of
     the data store and of the target store."""
@@ -604,7 +724,7 @@ def take_rows(dataset, target_store, indices):
 def build_scan_steps(ingest, forward_pass, backward_update,
                      compute_dtype, metrics_fn, gather=None,
                      n_classes=None, out_shape=None,
-                     members: bool = False):
+                     members: bool = False, blocked_head=None):
     """THE superstep: ``(train_step, eval_step)``, each one
     ``lax.scan`` over the minibatches of a firing, composed from the
     three shared bodies.  Every engine that trains jits these two (the
@@ -634,12 +754,18 @@ def build_scan_steps(ingest, forward_pass, backward_update,
     through untouched otherwise (a member has none); the eval carry
     keeps the last minibatch's f32 output when ``out_shape`` is given;
     ``lr`` is ``(k, n_gd, 2)`` absolute rates, one row a minibatch;
-    ``wd`` reaches ``backward_update`` only for a member."""
+    ``wd`` reaches ``backward_update`` only for a member.
+
+    ``blocked_head`` (:func:`build_blocked_head`'s, with a
+    ``forward_pass`` built ``head_apart``): the last layer and the loss
+    are made together, a block of positions at a time, and the eval
+    carry keeps no last output."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
     from veles_tpu.ops import batching
+
 
     cast = batching.make_caster(compute_dtype)
     # ``with`` blocks in place, not wrappers (see Workflow.initialize)
@@ -680,8 +806,13 @@ def build_scan_steps(ingest, forward_pass, backward_update,
             with scope(events.SCOPE_CAST_PARAMS):
                 cparams = cast(params)
             out, residuals = forward_pass(cparams, x, rc, True)
-            m = metrics_of(out, target, mask)
-            err = m.pop("err_output")
+            if blocked_head is None:
+                m = metrics_of(out, target, mask)
+                err = m.pop("err_output")
+            else:
+                m, grads, err = blocked_head.train(
+                    cparams[blocked_head.name], out, target, mask)
+                residuals = residuals[:-1] + [HeadDone(grads)]
             new_params, new_opt = backward_update(
                 cparams, params, opt, residuals, err, lr, wd)
             acc, conf = accumulate(acc, conf, m)
@@ -709,8 +840,12 @@ def build_scan_steps(ingest, forward_pass, backward_update,
                     xs = gather(*store, xs[0]) + xs[1:]
             x, target, mask = xs
             out, _ = forward_pass(cparams, ingest(x), rc, False)
-            m = metrics_of(out, target, mask)
-            m.pop("err_output")
+            if blocked_head is None:
+                m = metrics_of(out, target, mask)
+                m.pop("err_output")
+            else:
+                m = blocked_head.evaluate(
+                    cparams[blocked_head.name], out, target, mask)
             acc, conf = accumulate(acc, conf, m)
             last = None if out_shape is None \
                 else out.astype(jnp.float32)

@@ -78,6 +78,39 @@ EV_FUSED_RECOMPUTE = _ev("fused.recompute")
 #: ``window``), the kernels' ``tiles`` where it is ``fused``; once a
 #: unit at ``initialize``, again only where a later trace must differ
 EV_EVA_PATH = _ev("eva.path")
+#: which form of the gated delta rule a unit runs (``ops/deltanet.py``
+#: ``rule_path``): ``unit``, ``form`` (``chunked`` / ``recurrent``),
+#: ``reason`` where it is ``recurrent`` (``ragged``: the row is not
+#: whole chunks), ``chunk``; once a unit at ``initialize``
+EV_GDN_PATH = _ev("gdn.path")
+#: which form of the causal attention core a unit runs
+#: (``ops/attention.py`` ``attention_path``): ``unit``, ``form``
+#: (``splash``: the Pallas kernel that ships with jax / ``xla``: a block
+#: of queries at a time), ``reason`` where it is ``xla`` (``platform`` /
+#: ``batched`` / ``head_size`` / ``row``), ``tiles``; once a unit at
+#: ``initialize``, again only where a later trace must differ
+EV_ATTN_PATH = _ev("attn.path")
+#: the share of a mixture of experts a unit holds, once at
+#: ``initialize`` (``ops/moe.py``): ``unit``, ``experts_total``,
+#: ``experts_held``, ``first_held``, ``top_k``, ``rows`` the dispatch
+#: buffers of one block of tokens are sized for (every token of the
+#: block may choose ``top_k`` held experts: nothing is ever dropped),
+#: ``blocks`` of tokens a row is cut into, ``form`` of the grouped
+#: products (``gmm``: the Pallas grouped matmul that ships with jax /
+#: ``ragged_dot``) and its ``reason`` / ``tiles``
+EV_MOE_SHARE = _ev("moe.share")
+#: what the routing of the FIRST firing's first minibatch put on the
+#: held experts, a layer — read by a forward-only probe at set-up,
+#: never inside a timed step (``FusedStepRunner._report_loads``):
+#: ``unit``, ``local_assignments``, ``max_expert_rows``,
+#: ``min_expert_rows``, ``dropped`` (must read 0)
+EV_MOE_LOAD = _ev("moe.load")
+#: whether the head's product, the loss and the head's error are made
+#: a block of positions at a time (``FusedStepRunner._decide_loss_
+#: blocks``, from shapes against free memory): ``blocks`` (0: whole),
+#: ``bytes_whole``, ``bytes_block``, ``reason`` (``no_limit`` / ``fits``
+#: / ``whole_exceeds_free`` / ``not_blockable``)
+EV_LOSS_BLOCKED = _ev("loss.blocked")
 #: how a data-parallel train step exchanges its gradients, once at
 #: ``FusedStepRunner._build_steps`` on a mesh and never without one
 #: (``engine/core.py`` ``GradExchange``): ``devices``, ``leaves``,
@@ -288,6 +321,9 @@ GAUGE_EVA_CHUNK = _gauge("eva.chunk")
 GAUGE_EVA_SUMMARIES_PER_ROW = _gauge("eva.summaries_per_row")
 #: ``eva_attention`` units of the workflow on the fused kernels
 GAUGE_EVA_FUSED_LAYERS = _gauge("eva.fused_layers")
+#: rows the held experts could not take in the probed minibatch, summed
+#: over the layers (static buffers are sized for the worst routing: 0)
+GAUGE_MOE_DROPPED_ROWS = _gauge("moe.dropped_rows")
 GAUGE_FUSED_TRAIN_GFLOPS_PER_IMAGE = _gauge(
     "fused.train_gflops_per_image")
 GAUGE_FUSED_TRAIN_IMAGES_PER_SEC_WALL = _gauge(
@@ -421,6 +457,23 @@ SCOPE_RECOMPUTE = _scope("recompute")
 SCOPE_EVA_SUMMARIES = _scope("eva/summaries")
 SCOPE_EVA_LOCAL = _scope("eva/local")
 SCOPE_EVA_REMOTE = _scope("eva/remote")
+#: Gated DeltaNet: the causal depthwise convolution; the delta rule
+#: (normalisation of q and k, gates, chunk products, the state's
+#: scan); the gated norm on the way out
+SCOPE_GDN_CONV = _scope("gdn/conv")
+SCOPE_GDN_RULE = _scope("gdn/rule")
+SCOPE_GDN_GATE_NORM = _scope("gdn/gate_norm")
+#: causal attention: scores, softmax, weighted sums (no projection)
+SCOPE_ATTN_CORE = _scope("attn/core")
+#: mixture of experts: router (product, softmax, top-k); dispatch
+#: (sort, gather, combine); the grouped products over the held
+#: experts; the shared expert
+SCOPE_MOE_ROUTER = _scope("moe/router")
+SCOPE_MOE_DISPATCH = _scope("moe/dispatch")
+SCOPE_MOE_EXPERTS = _scope("moe/experts")
+SCOPE_MOE_SHARED = _scope("moe/shared")
+#: the head's product, loss and error of ONE block of positions
+SCOPE_LOSS_BLOCK = _scope("loss/block")
 
 
 DYNAMIC_FAMILIES = (

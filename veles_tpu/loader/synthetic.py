@@ -183,14 +183,35 @@ class PackedBytesLoader(FullBatchLoader):
                              seq_len=seq_len, median_len=median_len,
                              seed=seed)
 
+    def make_rows(self, n_rows: int) -> np.ndarray:
+        a = self.gen_args
+        return datasets.synthetic_packed_bytes(
+            n_rows, a["seq_len"], a["seed"], median_len=a["median_len"])
+
     def load_data(self) -> None:
         a = self.gen_args
         self.class_lengths[TEST] = 0
         self.class_lengths[VALID] = a["n_valid"]
         self.class_lengths[TRAIN] = a["n_train"]
-        self.original_data.mem = datasets.synthetic_packed_bytes(
-            a["n_valid"] + a["n_train"], a["seq_len"], a["seed"],
-            median_len=a["median_len"])
+        self.original_data.mem = self.make_rows(
+            a["n_valid"] + a["n_train"])
 
     def __getstate__(self) -> dict:
         return self.getstate_dropping("original_data")
+
+
+class PackedTokensLoader(PackedBytesLoader):
+    """The same store over a vocabulary that is a parameter: ids
+    ``0 .. vocab_size - 2`` from the chain, ``vocab_size - 1`` the
+    separator (``datasets.synthetic_packed_tokens``)."""
+
+    def __init__(self, workflow=None, vocab_size: int = 512,
+                 **kwargs: Any) -> None:
+        super().__init__(workflow, **kwargs)
+        self.vocab_size = vocab_size
+
+    def make_rows(self, n_rows: int) -> np.ndarray:
+        a = self.gen_args
+        return datasets.synthetic_packed_tokens(
+            n_rows, a["seq_len"], a["seed"], self.vocab_size - 1,
+            self.vocab_size - 1, median_len=a["median_len"])

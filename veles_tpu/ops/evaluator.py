@@ -145,8 +145,9 @@ class EvaluatorSoftmax(EvaluatorBase):
 
 
 class EvaluatorNextByte(EvaluatorBase):
-    """Next-byte cross-entropy at every position for every prediction
-    head (``loss_function="next_byte"``).
+    """Next-token cross-entropy at every position for every prediction
+    head (``loss_function="next_byte"``: ids of bytes, or of any
+    vocabulary the head spans).
 
     ``input`` holds the head's f32 logits ``[rows, T, heads, vocab]``;
     the targets are the row of ids itself (``targets_from_data``: the
@@ -156,7 +157,11 @@ class EvaluatorNextByte(EvaluatorBase):
     the valid (row, n, j), ``count`` their number — so Decision's loss
     is the mean per prediction — ``n_err`` the wrong arg-max ones.
     err_output = (softmax - onehot) * valid / count: d(mean CE)/d
-    logits."""
+    logits.
+
+    ``block_metrics`` is the same loss of a block of positions of the
+    row, for a head too wide for its whole logits to exist
+    (``engine/core.py`` ``build_blocked_head``)."""
 
     targets_from_data = True
 
@@ -165,14 +170,32 @@ class EvaluatorNextByte(EvaluatorBase):
         self.target = Vector(name=f"{self.name}.target")
         self.mask = Vector(name=f"{self.name}.mask")
 
-    def metrics_fn(self, output, target, mask):
+    def valid_count(self, output_shape, mask):
+        """How many (row, position, head) of logits of
+        ``output_shape`` have a target: the ``count`` of the whole."""
+        import jax.numpy as jnp
+        t, heads = int(output_shape[1]), int(output_shape[2])
+        per_row = sum(max(t - 1 - j, 0) for j in range(heads))
+        return jnp.sum(jnp.asarray(mask) > 0).astype(jnp.float32) \
+            * per_row
+
+    def block_metrics(self, output, target, mask, start=None, n=None):
+        """The metrics of logits ``output`` for positions ``start ..
+        start + output.shape[1]`` of the rows ``target`` (None: the
+        whole row); the error under the mean over ``n`` valid
+        predictions (None: this block's own)."""
         import jax
         import jax.numpy as jnp
-        t, heads = output.shape[1], output.shape[2]
-        pos = jnp.arange(t)[:, None] + 1 + jnp.arange(heads)[None, :]
-        tgt = jnp.asarray(target)[:, jnp.minimum(pos, t - 1)]
+        target = jnp.asarray(target)
+        t, heads = target.shape[1], output.shape[2]
+        pos = jnp.arange(output.shape[1])[:, None] + 1 \
+            + jnp.arange(heads)[None, :]
+        if start is not None:
+            pos = pos + start
+        tgt = target[:, jnp.minimum(pos, t - 1)]
         valid = (pos < t)[None] & (jnp.asarray(mask) > 0)[:, None, None]
-        n = valid.sum().astype(jnp.float32)
+        if n is None:
+            n = valid.sum().astype(jnp.float32)
         logp = jax.nn.log_softmax(jnp.asarray(output), axis=-1)
         picked = jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
         loss_sum = -jnp.sum(jnp.where(valid, picked, 0.0))
@@ -184,6 +207,9 @@ class EvaluatorNextByte(EvaluatorBase):
                 "n_err": n_err.astype(jnp.float32),
                 "loss_sum": loss_sum.astype(jnp.float32),
                 "count": n}
+
+    def metrics_fn(self, output, target, mask):
+        return self.block_metrics(output, target, mask)
 
     def run(self) -> None:
         if self._compiled is None:

@@ -75,6 +75,9 @@ class FusedStepRunner(AcceleratedUnit):
         self.data_sharded = False
         self._train_step = None
         self._eval_step = None
+        #: the forward-only program of ``probe_units`` (None: not built
+        #: yet; False: no unit of the chain has a probe)
+        self._probe = None
         #: the Keel ExecutionCore (engine/core.py): every placement /
         #: donation / compile decision this runner makes goes through
         #: it, and it charges the params+opt footprint to the process
@@ -142,7 +145,7 @@ class FusedStepRunner(AcceleratedUnit):
         self._first_run_ts = None
 
     _unpicklable = AcceleratedUnit._unpicklable + (
-        "_train_step", "_eval_step", "_params", "_opt", "mesh",
+        "_train_step", "_eval_step", "_probe", "_params", "_opt", "mesh",
         "_batch_sharding", "_acc", "_conf", "_inflight", "_core")
 
     @property
@@ -231,8 +234,15 @@ class FusedStepRunner(AcceleratedUnit):
         ingest = engine_core.build_ingest(
             getattr(self.loader, "dequant", None))
         recompute = self._decide_recompute(cd)
+        loss_blocks = self._decide_loss_blocks(cd)
+        blocked_head = None
+        if loss_blocks:
+            blocked_head = engine_core.build_blocked_head(
+                self.forwards[-1], evaluator, loss_blocks)
+            out_shape = None      # no whole output exists to keep
         forward_pass = engine_core.build_forward(
-            self.forwards, seed, cd, recompute)
+            self.forwards, seed, cd, recompute,
+            head_apart=bool(loss_blocks))
         # on a mesh: how each layer's gradients become the global
         # minibatch's (gathered activations or an all-reduce, from
         # shapes), and what the chip's compiler is told with the step
@@ -267,7 +277,8 @@ class FusedStepRunner(AcceleratedUnit):
             ingest, forward_pass, backward_update, cd,
             evaluator.metrics_fn, gather=gather,
             n_classes=evaluator.n_classes if self._want_confusion()
-            else None, out_shape=out_shape)
+            else None, out_shape=out_shape, blocked_head=blocked_head)
+        self._probe = None
         train_in = eval_in = None
         if self.mesh is not None:
             # SPMD data parallelism: minibatch rows sharded over the
@@ -303,6 +314,63 @@ class FusedStepRunner(AcceleratedUnit):
         return int(stats["bytes_limit"]) \
             if stats and stats.get("bytes_limit") else None
 
+    def _state_bytes(self, cd) -> int:
+        """What the device holds beside a step's activations: the
+        units' parameters and optimiser state as they hold them, the
+        parameters' copy in the compute dtype, a resident store."""
+        state = sum(v.nbytes + v.size * np.dtype(cd).itemsize
+                    for f in self.forwards
+                    for v in f.param_vectors().values() if v)
+        state += sum(gd.opt_nbytes() for gd in self.gds
+                     if gd is not None)
+        ld = self.loader
+        if not self.streaming and ld.original_data:
+            state += ld.original_data.nbytes
+        return state
+
+    def _decide_loss_blocks(self, cd) -> int:
+        """Into how many blocks of positions the head's product, the
+        loss and the head's backward are cut (0: the whole, as ever).
+        Decided from what the program can observe, no knob: the loss
+        holds about four arrays shaped like the logits (logits,
+        log-probabilities, one-hot targets, error) in f32; whole they
+        may take a quarter of what the device has left beside the state
+        (half of it is the kept residuals', :meth:`_decide_recompute`;
+        this is half of the backward's working half), else a block may
+        take a sixteenth.  Only a head that acts on each position
+        alone, under an evaluator that scores a block, can be cut.
+        Journaled either way where the loss could be (``loss.blocked``)."""
+        head, ev = self.forwards[-1], self.evaluator
+        if not getattr(head, "per_position", False) \
+                or not hasattr(ev, "block_metrics") \
+                or getattr(head, "residual_of", None) is not None \
+                or self.gds[-1] is None:
+            return 0
+        shape = tuple(int(d) for d in head.output.shape)
+        n_dev = int(self.mesh.devices.size) if self.mesh is not None \
+            else 1
+        whole = 4 * 4 * int(np.prod(shape)) // n_dev
+        limit, t = self._device_bytes_limit(), shape[1]
+        free = None if limit is None else limit - self._state_bytes(cd)
+        blocks = 0
+        if limit is None:
+            reason = "no_limit"
+        elif self.mesh is not None:
+            reason = "not_blockable"
+        elif 4 * whole <= free:
+            reason = "fits"
+        else:
+            reason, blocks = "whole_exceeds_free", 2
+            while 16 * whole > blocks * free and t % (2 * blocks) == 0:
+                blocks *= 2
+            if t % blocks:
+                reason, blocks = "not_blockable", 0
+        telemetry.event(
+            events.EV_LOSS_BLOCKED, blocks=blocks, bytes_whole=whole,
+            bytes_block=whole // blocks if blocks else whole,
+            limit_bytes=limit, reason=reason)
+        return blocks
+
     def _decide_recompute(self, cd) -> bool:
         """Whether the chain's residual entries keep only their inputs
         and re-run their forward inside the backward walk.  Decided
@@ -325,20 +393,13 @@ class FusedStepRunner(AcceleratedUnit):
             (max(1, ld.max_minibatch_size // n_dev),)
             + tuple(ld.minibatch_data.shape[1:]),
             ld.minibatch_data.dtype)
-        pvecs = {f.name: {k: v for k, v in f.param_vectors().items()
-                          if v} for f in self.forwards}
         cparams = {
-            name: {k: jax.ShapeDtypeStruct(tuple(v.shape), cd)
-                   for k, v in vecs.items()}
-            for name, vecs in pvecs.items()}
+            f.name: {k: jax.ShapeDtypeStruct(tuple(v.shape), cd)
+                     for k, v in f.param_vectors().items() if v}
+            for f in self.forwards}
         kept, kept_recomputing = engine_core.kept_activation_bytes(
             self.forwards, cd, cparams, x)
-        state = sum(v.nbytes + v.size * np.dtype(cd).itemsize
-                    for vecs in pvecs.values() for v in vecs.values())
-        state += sum(gd.opt_nbytes() for gd in self.gds
-                     if gd is not None)
-        if not self.streaming and ld.original_data:
-            state += ld.original_data.nbytes
+        state = self._state_bytes(cd)
         limit = self._device_bytes_limit()
         if limit is None:
             recompute, reason = False, "no_limit"
@@ -467,10 +528,13 @@ class FusedStepRunner(AcceleratedUnit):
             self.processed_eval_images += images
         if self._first_run_ts is None:
             self._first_run_ts = time.monotonic()
+        first_train = train and "train" not in self._dispatch_seen
         if self.streaming:
             self._run_streaming(ld, k, mask, train)
         else:
             self._run_resident(ld, k, indices, mask, train)
+            if first_train and self.mesh is None:
+                self._report_probes(indices[0])
         self._rng_counter += k
         telemetry.counter(events.CTR_FUSED_DISPATCHES).inc()
         telemetry.counter(events.CTR_FUSED_MINIBATCHES).inc(k)
@@ -481,6 +545,39 @@ class FusedStepRunner(AcceleratedUnit):
             # sequence of this many tokens
             telemetry.counter(events.CTR_FUSED_TRAIN_TOKENS).inc(
                 images * int(np.prod(ld.minibatch_data.shape[1:])))
+
+    def probe_units(self, rows) -> Dict[str, Any]:
+        """What the units that have a ``probe`` say the minibatch
+        ``rows`` (as the step ingests them) puts on them, under the
+        parameters as they stand: ``{unit name: its answer}`` on the
+        host, ``{}`` where no unit has one.  A forward-only program of
+        its own (``engine/core.py`` ``build_probe``), built on first
+        use; it waits for the device, so it belongs to set-up."""
+        import jax
+
+        from veles_tpu.engine import core as engine_core
+        if self._probe is None:
+            fn = engine_core.build_probe(self.forwards,
+                                         self._resolved_dtype())
+            self._probe = self._core.jit(fn) if fn else False
+        if not self._probe:
+            return {}
+        self._ensure_params()
+        return jax.device_get(self._probe(self._params, rows))
+
+    def _report_probes(self, indices) -> None:
+        """Once, right after the first train firing: the probed units
+        report what its first minibatch put on them (a mixture of
+        experts: ``moe.load``)."""
+        import jax.numpy as jnp
+        if not any(hasattr(f, "probe") for f in self.forwards):
+            return
+        got = self.probe_units(jnp.take(
+            self.loader.original_data.unmap(), jnp.asarray(indices),
+            axis=0))
+        for f in self.forwards:
+            if f.name in got:
+                f.report_probe(got[f.name])
 
     @contextlib.contextmanager
     def _submit(self, kind: str, k: int):
@@ -544,7 +641,8 @@ class FusedStepRunner(AcceleratedUnit):
                 self._acc, self._conf, out = self._eval_step(
                     self._params, self._acc, self._conf, dataset,
                     targets, indices, mask, self._rng_counter)
-            self.forwards[-1].output.devmem = out
+            if out is not None:       # a blocked head keeps no output
+                self.forwards[-1].output.devmem = out
 
     def _run_streaming(self, ld, k, mask, train: bool) -> None:
         """Dispatch over the loader's host-assembled superstep batch.
@@ -633,7 +731,8 @@ class FusedStepRunner(AcceleratedUnit):
                 self._acc, self._conf, out = self._eval_step(
                     self._params, self._acc, self._conf, xb, tb, mask,
                     self._rng_counter)
-            self.forwards[-1].output.devmem = out
+            if out is not None:
+                self.forwards[-1].output.devmem = out
 
     def _lr_rates_array(self, k: int) -> np.ndarray:
         """``lr_rates`` as the (k, n_gd, 2) scanned input.  With no

@@ -111,6 +111,12 @@ def _populate() -> None:
         register("swiglu", seq.SwiGLU, seq.GDSequence)
         register("eva_attention", seq.EvaAttention, seq.GDSequence)
         register("lm_head", seq.LMHead, seq.GDSequence)
+        from veles_tpu.ops import attention, deltanet, moe
+        register("gated_delta_net", deltanet.GatedDeltaNet,
+                 seq.GDSequence)
+        register("gated_attention", attention.GatedAttention,
+                 seq.GDSequence)
+        register("moe", moe.MoE, seq.GDSequence)
 
     for name, fn in families:
         try:

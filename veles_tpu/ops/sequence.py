@@ -1,7 +1,9 @@
 """The sequence op family: the layer types a decoder over rows of
 token ids is made of — ``embedding``, ``rmsnorm``, ``dense`` (per
 position, no flattening of the sample, no bias), ``swiglu``,
-``eva_attention`` and ``lm_head``.
+``eva_attention`` and ``lm_head`` here; ``gated_delta_net``
+(``ops/deltanet.py``), ``gated_attention`` (``ops/attention.py``) and
+``moe`` (``ops/moe.py``) in modules of their own on this one's base.
 
 Every unit here is ONE pure ``forward(params, x)`` over ``[rows, T,
 width]`` activations (the embedding: ``[rows, T]`` integer ids); its
@@ -46,7 +48,7 @@ once at ``initialize`` (``eva.path``; gauge ``eva.fused_layers``).
 
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
@@ -83,14 +85,30 @@ class SequenceUnit(ForwardUnit):
         gen = prng.get("weights").numpy
         std = self.weights_stddev
         for name, shape in self.param_shapes(input_shape).items():
-            if name == "gain":        # the norm multiplies by 1 + gain
-                arr = np.zeros(shape, np.float32)
-            else:
+            arr = self.fill_special(name, shape, gen)
+            if arr is None:
                 s = std if std is not None else \
                     1.0 / np.sqrt(shape[0] or 1)
                 arr = gen.standard_normal(shape, dtype=np.float32)
                 arr *= np.float32(s)
             getattr(self, name).mem = arr
+
+    def fill_special(self, name: str, shape, gen) -> Optional[np.ndarray]:
+        """The fill of a parameter that is no gaussian matrix (None:
+        it is one); ``gen`` is the weights stream's numpy generator."""
+        if name == "gain":            # the norm multiplies by 1 + gain
+            return np.zeros(shape, np.float32)
+        return None
+
+    def platform(self) -> str:
+        """The platform this unit's ops will run on, as far as it can
+        observe: its device's, or JAX's default when it is walked
+        without ``initialize``."""
+        if self.device is None:
+            import jax
+            return jax.default_backend()
+        return getattr(self.device, "platform", None) \
+            or self.device.backend_name
 
     # -- pure compute --------------------------------------------------
 
@@ -164,6 +182,16 @@ class Embedding(SequenceUnit):
                                          dtype=w.dtype), w)
 
 
+def rms_norm(x, gain, eps: float):
+    """``x / sqrt(mean(x^2) + eps) * (1 + gain)`` over the last axis,
+    in f32 (returned in f32)."""
+    import jax.numpy as jnp
+    from jax import lax
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return xf * lax.rsqrt(ms + eps) * (1.0 + gain.astype(jnp.float32))
+
+
 class RMSNorm(SequenceUnit):
     """``x / sqrt(mean(x^2) + eps) * (1 + gain)`` over the last axis,
     in f32 (the published ``norm_add_unit_offset``)."""
@@ -182,13 +210,7 @@ class RMSNorm(SequenceUnit):
         return {"gain": (int(input_shape[-1]),)}
 
     def forward(self, params, x):
-        import jax.numpy as jnp
-        from jax import lax
-        xf = x.astype(jnp.float32)
-        ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-        y = xf * lax.rsqrt(ms + self.eps) \
-            * (1.0 + params["gain"].astype(jnp.float32))
-        return y.astype(x.dtype)
+        return rms_norm(x, params["gain"], self.eps).astype(x.dtype)
 
 
 class Dense(SequenceUnit):
@@ -249,12 +271,18 @@ class SwiGLU(SequenceUnit):
 
 
 class LMHead(SequenceUnit):
-    """Multi-byte prediction heads: one ``hidden -> n_pred_heads *
-    vocab`` product, logits ``[rows, T, n_pred_heads, vocab]`` in f32
-    (the published ``fp32_logits``)."""
+    """Prediction heads over a vocabulary of token ids (bytes, or any
+    other): one ``hidden -> n_pred_heads * vocab`` product, logits
+    ``[rows, T, n_pred_heads, vocab]`` in f32 (head j at position n
+    predicts id n + 1 + j).  It acts on each position alone, so where
+    the whole logits would not fit beside the state the fused step
+    makes them, the loss and the head's backward a block of positions
+    at a time (``engine/core.py`` ``build_blocked_head``)."""
 
     matrix_names = param_names = ("weights",)
     f32_output = True
+    #: ``forward`` of a block of positions is that block of ``forward``
+    per_position = True
 
     def __init__(self, workflow=None, vocab_size: int = 320,
                  n_pred_heads: int = 8, **kwargs: Any) -> None:
@@ -281,10 +309,15 @@ class LMHead(SequenceUnit):
         return 2.0 * t * n_in * self.n_pred_heads * self.vocab_size
 
 
-def rope(x, theta: float):
-    """Rotate-half RoPE over the whole head, in f32; x ``[rows, T,
-    heads, d]``, angle ``n * theta^(-2i/d)``."""
+def rope(x, theta: float, rotary: Optional[int] = None):
+    """Rotate-half RoPE in f32; x ``[rows, T, heads, d]``.  Over the
+    whole head (``rotary`` None), angle ``n * theta^(-2i/d)``, or over
+    the first ``rotary`` elements of each head alone (a partial rotary
+    factor: angle ``n * theta^(-2i/rotary)``), the rest untouched."""
     import jax.numpy as jnp
+    if rotary is not None and rotary < x.shape[-1]:
+        return jnp.concatenate(
+            [rope(x[..., :rotary], theta), x[..., rotary:]], -1)
     t, d = x.shape[1], x.shape[-1]
     inv = jnp.float32(theta) ** (
         -jnp.arange(0, d, 2, dtype=jnp.float32) / d)
@@ -445,13 +478,7 @@ class EvaAttention(SequenceUnit):
         journaled (``eva.path``) whenever it differs from the last
         one journaled: once at ``initialize``, and again only where a
         later trace must leave it (a ``vmap``)."""
-        if self.device is None:         # walked without ``initialize``
-            import jax
-            platform = jax.default_backend()
-        else:
-            platform = getattr(self.device, "platform", None) \
-                or self.device.backend_name
-        path = eva_path(platform, self.head_size, self._window(t),
+        path = eva_path(self.platform(), self.head_size, self._window(t),
                         self.chunk_size, t, batched)
         if path != self.path:
             self.path = path
