@@ -8,6 +8,7 @@ a masked loop over the experts)."""
 
 import os
 import sys
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +28,7 @@ from veles_tpu.models import evabyte  # noqa: E402
 from veles_tpu.models.qwen3next import (  # noqa: E402
     CUT, PUBLISHED, TINY, qwen3next_layers)
 from veles_tpu.ops import attention, deltanet, moe  # noqa: E402
+from veles_tpu.ops import deltanet_pallas  # noqa: E402
 from veles_tpu.ops import sequence as seq  # noqa: E402
 from veles_tpu.ops.fused import FusedStepRunner  # noqa: E402
 from veles_tpu.ops.registry import forward_registry  # noqa: E402
@@ -182,6 +184,160 @@ def test_chunked_rule_survives_the_published_decay():
     _close(got, want)
     assert all(bool(jnp.all(jnp.isfinite(a)))
                for a in back(jnp.ones_like(got)))
+
+
+# -- the fused chunk products (ISSUE 33), in Pallas interpret mode ----------
+
+def _gap(got, want):
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    return float(np.abs(got - want).max()
+                 / max(float(np.abs(want).max()), 1e-30))
+
+
+def _chunk_inputs(chunk, r, cd, n=2, hk=2, d=128, seed=5, g_scale=1.0):
+    """(the five arrays of ``_chunk_products``, their tiles): ``n``
+    chunks of one row, ``hk`` key heads each serving ``r`` value heads
+    of ``d``, at the sizes the compiled kernels tile."""
+    q, k, v, g, beta = _rule_inputs(n * chunk, hk, hk * r, d, d, seed)
+    parts = deltanet.chunk_parts(q[:1], k[:1], v[:1], g[:1] * g_scale,
+                                 beta[:1], chunk, cd)
+    tiles = deltanet_pallas.tiles_for(chunk, d, d, r)
+    assert tiles == (deltanet_pallas.PAIRS, 2 if (chunk, r) == (64, 2)
+                     else 1)
+    return parts, tiles
+
+
+@pytest.mark.parametrize("cd,tol", [(jnp.float32, 5e-5),
+                                    (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("r", [1, 2])
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_fused_chunk_products_equal_the_xla_form(chunk, r, cd, tol):
+    """The two kernels against ``_chunk_products`` and ``jax.vjp`` of
+    it: u, w, the decayed q k^T, log Gamma, and the cotangents to q,
+    k, v, g, beta — two value heads of a chunk of 64 side by side in
+    one 128-row system, else a system a value head; every f32 product
+    in three bf16 passes (``Precision.HIGH``), so f32 agrees to 5e-5
+    and bf16 to its own rounding."""
+    parts, tiles = _chunk_inputs(chunk, r, cd)
+    want, back = jax.vjp(partial(deltanet._chunk_products, cd), *parts)
+    got, mine = jax.vjp(
+        lambda *a: deltanet_pallas.chunk_products(*a, tiles, True),
+        *parts)
+    for name, a, b in zip(("u", "w", "a_qk", "gsum"), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert _gap(a, b) <= tol, (name, _gap(a, b))
+    cots = tuple(
+        jax.random.normal(jax.random.key(40 + i), w.shape).astype(w.dtype)
+        for i, w in enumerate(want))
+    for name, a, b in zip(("q", "k", "v", "g", "beta"), mine(cots),
+                          back(cots)):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert _gap(a, b) <= tol, (name, _gap(a, b))
+
+
+def test_fused_chunk_products_survive_the_published_decay():
+    """``g`` x 60, as ``A_log`` = log 16 makes it: the decay is masked
+    before the exp in the kernels too — finite, and the XLA form's."""
+    parts, tiles = _chunk_inputs(64, 2, jnp.float32, g_scale=60.0)
+    want, back = jax.vjp(partial(deltanet._chunk_products, jnp.float32),
+                         *parts)
+    got, mine = jax.vjp(
+        lambda *a: deltanet_pallas.chunk_products(*a, tiles, True),
+        *parts)
+    ones = tuple(jnp.ones_like(w) for w in want)
+    for a, b in zip(got + mine(ones), want + back(ones)):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        assert _gap(a, b) <= 5e-5, _gap(a, b)
+
+
+@pytest.mark.parametrize("chunk,hv", [(64, 2), (64, 1), (128, 2)])
+def test_chunked_rule_over_the_fused_products_is_the_recurrence(chunk, hv):
+    """``rule_chunked`` with the kernels' products equals the token
+    recurrence of the reference, and its gradient the recurrence's:
+    the scan reads the kernels' four arrays as it reads the XLA
+    form's."""
+    q, k, v, g, beta = (a[:1] for a in _rule_inputs(256, 1, hv, 128, 128))
+    args = (q, k, v, g, beta)
+    tiles = deltanet_pallas.tiles_for(chunk, 128, 128, hv)
+    run = lambda *a: deltanet.rule_chunked(  # noqa: E731
+        *a, chunk, jnp.float32, tiles, True)
+    want = ref.delta_rule(*args)
+    _close(run(*args), want, tol=5e-5)
+    err = jax.random.normal(jax.random.key(8), want.shape)
+    g_want = jax.grad(lambda *a: jnp.sum(ref.delta_rule(*a) * err),
+                      argnums=range(5))(*args)
+    g_got = jax.grad(lambda *a: jnp.sum(run(*a) * err),
+                     argnums=range(5))(*args)
+    for a, b in zip(g_got, g_want):
+        _close(a, b, tol=1e-4)
+
+
+def test_fused_chunk_products_pad_a_ragged_count_of_pairs():
+    """Three chunks of one key head: three pairs in a grid step of
+    eight, the rest padding that reaches no result."""
+    parts, tiles = _chunk_inputs(64, 2, jnp.float32, n=3, hk=1)
+    want = deltanet._chunk_products(jnp.float32, *parts)
+    got = deltanet_pallas.chunk_products(*parts, tiles, True)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and _gap(a, b) <= 5e-5
+    with pytest.raises(ValueError, match="do not tile"):
+        deltanet_pallas.chunk_products(
+            *_chunk_inputs(64, 1, jnp.float32)[0],
+            deltanet_pallas.Tiles(8, 2), True)
+
+
+@pytest.mark.parametrize(
+    "platform,chunk,dk,dv,r,batched,products,reason", [
+        ("cpu", 64, 128, 128, 2, False, "xla", "platform"),
+        ("tpu", 64, 128, 128, 2, False, "fused", None),
+        ("tpu", 64, 128, 128, 2, True, "xla", "batched"),
+        ("tpu", 64, 128, 128, 1, False, "fused", None),
+        ("tpu", 128, 128, 256, 2, False, "fused", None),
+        ("tpu", 64, 16, 16, 2, False, "xla", "head_size"),
+        ("tpu", 64, 128, 64, 2, False, "xla", "head_size"),
+        ("tpu", 16, 128, 128, 2, False, "xla", "chunk"),
+        ("tpu", 256, 128, 128, 2, False, "xla", "chunk"),
+        ("tpu", 64, 128, 128, 32, False, "xla", "chunk")])
+def test_products_path_is_chosen_from_platform_and_shapes(
+        platform, chunk, dk, dv, r, batched, products, reason):
+    path = deltanet.products_path(platform, chunk, dk, dv, r, batched)
+    assert (path["products"], path.get("reason")) == (products, reason)
+    if products == "fused":
+        assert path["tiles"] == deltanet_pallas.tiles_for(chunk, dk, dv, r)
+        assert path["tiles"].pack * chunk in (64, 128)
+        assert r % path["tiles"].pack == 0
+
+
+def test_gdn_path_is_journaled_and_a_vmap_leaves_the_kernels():
+    """On the CPU a unit journals ``chunked`` with ``products`` ``xla``
+    / ``platform``; a unit that believes it is on a TPU at the
+    published head size takes the kernels, and under ``vmap`` (a
+    cohort, an ensemble) falls back to the XLA form and says why; a
+    ragged row has no products to choose."""
+    from types import SimpleNamespace
+    telemetry.reset()
+    unit, _, _, _ = _unit("gated_delta_net", key_head_size=128,
+                          value_head_size=128, chunk_size=64)
+    shapes = unit.param_shapes((1, T, HIDDEN))
+    params = {n: 0.2 * jax.random.normal(jax.random.key(j), (2,) + sh)
+              for j, (n, sh) in enumerate(sorted(shapes.items()))}
+    x = jax.random.normal(jax.random.key(9), (1, T, HIDDEN), jnp.float32)
+    one = lambda i: {n: p[i] for n, p in params.items()}  # noqa: E731
+    want = jnp.stack([unit.forward(one(i), x) for i in range(2)])
+    assert unit.path == {"form": "chunked", "chunk": 64,
+                         "products": "xla", "reason": "platform"}
+    unit.device = SimpleNamespace(platform="tpu")
+    assert unit._path(T)["products"] == "fused"
+    got = jax.vmap(unit.forward, in_axes=(0, None))(params, x)
+    _close(got, want)
+    assert unit._path(100) == {"form": "recurrent", "reason": "ragged",
+                               "chunk": 1}
+    seen = telemetry.recent_events(events.EV_GDN_PATH)[-4:]
+    assert [(e["form"], e["products"], e["reason"]) for e in seen] == [
+        ("chunked", "xla", "platform"), ("chunked", "fused", None),
+        ("chunked", "xla", "batched"), ("recurrent", None, "ragged")]
+    assert seen[1]["tiles"] == {"pairs": 8, "pack": 2}
+    assert all(e["unit"] == unit.name for e in seen)
 
 
 @pytest.mark.parametrize("kind", ["gated_delta_net", "gated_attention"])

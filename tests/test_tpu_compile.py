@@ -173,12 +173,18 @@ def both(params, x, err):
 err = spec(unit.output_shape_for(x.shape), jnp.bfloat16)
 hlo = jax.jit(both).lower(params, x, err).compile().as_text()
 want = {"gated_attention": ("splash", 3), "moe": ("gmm", 9),
-        "gated_delta_net": ("chunked", 0)}[kind]
+        "gated_delta_net": ("chunked", 2)}[kind]
 form = {"gated_attention": lambda: unit.path, "moe": lambda: unit.share,
-        "gated_delta_net": lambda: deltanet.rule_path(t, unit.chunk_size)
-        }[kind]()
+        "gated_delta_net": lambda: unit.path}[kind]()
 assert form["form"] == want[0], form
 assert hlo.count("tpu_custom_call") >= want[1], hlo.count("tpu_custom_call")
+if kind == "gated_delta_net":
+    assert form == dict(deltanet.rule_path(t, unit.chunk_size),
+                        products="fused", tiles=(8, 2)), form
+    for kernel in ("gdn_products_fwd", "gdn_products_bwd"):
+        assert kernel in hlo, kernel
+    # every [C, C] matrix lives in the kernels: none is an HLO value
+    assert "f32[%d,1,16,2,64,64]" % (t // 64) not in hlo
 print("COMPILED")
 """
 
@@ -233,15 +239,21 @@ def test_data_parallel_step_compiles_with_its_exchange_options():
 
 
 @pytest.mark.parametrize("kind,t", [("gated_delta_net", 4096),
+                                    ("gated_delta_net", 32768),
                                     ("gated_attention", 4096),
                                     ("moe", 4096)])
 def test_hybrid_layer_types_compile_at_the_published_widths(kind, t):
     """The three layer types of ISSUE 32, forward and backward, through
     the v5e's compiler in bf16 at Qwen3-Next's published widths on a
-    row of 4096: the chunked delta rule with its scan over chunks, the
-    causal attention core on the flash kernel that ships with jax (16
-    query heads x 256 over 2 key heads: its multi-query kernel mapped
-    over the key heads, forward + dq + dkv), and the expert share's
+    row of 4096: the chunked delta rule with its scan over chunks and
+    its chunk products in the two fused kernels of ISSUE 33 (also on
+    the cell's row of 32 768: Mosaic through the v5e's compiler sees
+    the unaligned slice or VMEM overrun that interpret mode cannot;
+    the compiled text names both kernels and holds no ``[C, C]`` f32
+    array of the chunks), the causal attention core on the flash kernel
+    that ships with jax (16 query heads x 256 over 2 key heads: its
+    multi-query kernel mapped over the key heads, forward + dq + dkv),
+    and the expert share's
     three grouped products on the shipped grouped-matmul kernel
     (forward + two backward each) with buffers sized for the worst
     routing.  tests_tpu/test_hybrid_layers.py runs them on the chip."""

@@ -1,6 +1,7 @@
 """The three layer types of ISSUE 32 on the chip, at Qwen3-Next's
-published widths in bf16: each TPU form (the chunked delta rule; the
-shipped flash kernel under ``gated_attention``; the shipped grouped
+published widths in bf16: each TPU form (the chunked delta rule, its
+chunk products by the XLA form and by the fused kernels of ISSUE 33;
+the shipped flash kernel under ``gated_attention``; the shipped grouped
 matmul under ``moe``) against the plain form every other platform runs,
 each within the gap that plain form itself keeps from an f32 run of the
 same mathematics (the bf16 witness); and the cell's own sizes through
@@ -90,8 +91,10 @@ def test_gmm_experts_match_ragged_dot(tpu_device):
     assert 1500 < int(got["expert_rows"].sum()) < 4000
 
 
-def test_chunked_rule_matches_the_recurrence(tpu_device):
-    ks = jax.random.split(jax.random.key(7), 5)
+def _rule_inputs(seed):
+    """q, k (unit, q scaled), v, g, beta of one row at the published
+    widths, f32."""
+    ks = jax.random.split(jax.random.key(seed), 5)
     unit = lambda a: a / jnp.linalg.norm(  # noqa: E731
         a, axis=-1, keepdims=True)
     q = unit(jax.random.normal(ks[0], (1, T, 16, 128))) / 128 ** 0.5
@@ -99,12 +102,54 @@ def test_chunked_rule_matches_the_recurrence(tpu_device):
     v = jax.random.normal(ks[2], (1, T, 32, 128))
     g = -jax.nn.softplus(jax.random.normal(ks[3], (1, T, 32))) * 0.2
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, T, 32)))
+    return q, k, v, g, beta
+
+
+def test_chunked_rule_matches_the_recurrence(tpu_device):
+    q, k, v, g, beta = _rule_inputs(7)
     with jax.default_matmul_precision("highest"):
         exact = jax.jit(deltanet.rule_recurrent)(q, k, v, g, beta)
-    got = jax.jit(lambda *a: deltanet.rule_chunked(
-        *a, 64, jnp.bfloat16))(q, k, v, g, beta)
-    assert np.isfinite(np.asarray(got, np.float32)).all()
-    assert _gap(got, exact) <= 3e-2, _gap(got, exact)
+    path = deltanet.products_path("tpu", 64, 128, 128, 2)
+    assert path["products"] == "fused", path
+    gaps = {}
+    for name, tiles in (("xla", None), ("fused", path["tiles"])):
+        got = jax.jit(lambda *a, tiles=tiles: deltanet.rule_chunked(
+            *a, 64, jnp.bfloat16, tiles))(q, k, v, g, beta)
+        assert np.isfinite(np.asarray(got, np.float32)).all(), name
+        gaps[name] = _gap(got, exact)
+    print("chunked rule against the recurrence:", gaps)
+    assert gaps["xla"] <= 3e-2 and gaps["fused"] <= 3e-2, gaps
+    # the kernels make the same three-pass products: no further off
+    assert gaps["fused"] <= 1.25 * gaps["xla"] + 1e-3, gaps
+
+
+def test_fused_chunk_products_match_the_xla_form(tpu_device):
+    """The two kernels of ``ops/deltanet_pallas.py`` at the published
+    widths against ``_chunk_products`` and ``jax.vjp`` of it, both in
+    bf16 on the chip: the four results and the five cotangents within
+    the rounding of their dtype."""
+    parts = deltanet.chunk_parts(*_rule_inputs(33), 64, jnp.bfloat16)
+    tiles = deltanet.products_path("tpu", 64, 128, 128, 2)["tiles"]
+
+    def both(tiles):
+        @jax.jit
+        def run(parts, cots):
+            out, back = jax.vjp(
+                lambda *a: deltanet.products_of(a, tiles), *parts)
+            return out, back(cots)
+        return run
+
+    shapes = jax.eval_shape(lambda *a: deltanet.products_of(a), *parts)
+    cots = tuple(jax.random.normal(jax.random.key(40 + i), s.shape, s.dtype)
+                 for i, s in enumerate(shapes))
+    want, mine = both(None)(parts, cots), both(tiles)(parts, cots)
+    names = ("u", "w", "a_qk", "gsum", "dq", "dk", "dv", "dg", "dbeta")
+    for name, a, b in zip(names, jax.tree.leaves(mine),
+                          jax.tree.leaves(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert np.isfinite(np.asarray(a, np.float32)).all(), name
+        limit = 1e-4 if a.dtype == jnp.float32 else 2e-2
+        assert _gap(a, b) <= limit, (name, _gap(a, b))
 
 
 def test_the_cells_sizes_take_the_chip_forms_and_drop_nothing(
@@ -121,8 +166,9 @@ def test_the_cells_sizes_take_the_chip_forms_and_drop_nothing(
         Launcher(), loader=dict(qwen3next.DEFAULTS["loader"], n_train=2),
         superstep=1, decision={"max_epochs": 1})
     w.initialize(device=tpu_device)
-    assert [e["form"] for e in telemetry.recent_events(
-        events.EV_GDN_PATH)] == ["chunked"] * 3
+    assert [(e["form"], e["products"]) for e in telemetry.recent_events(
+        events.EV_GDN_PATH)] == [("chunked", "fused")] * 3
+    assert telemetry.gauge(events.GAUGE_GDN_FUSED_LAYERS).value == 3
     seen = telemetry.recent_events(events.EV_ATTN_PATH)
     assert [(e["form"], e["tiles"]["block_q"]) for e in seen] == [
         ("splash", 512)]

@@ -81,7 +81,13 @@ EV_EVA_PATH = _ev("eva.path")
 #: which form of the gated delta rule a unit runs (``ops/deltanet.py``
 #: ``rule_path``): ``unit``, ``form`` (``chunked`` / ``recurrent``),
 #: ``reason`` where it is ``recurrent`` (``ragged``: the row is not
-#: whole chunks), ``chunk``; once a unit at ``initialize``
+#: whole chunks), ``chunk``; and, where it is ``chunked``, what makes
+#: the chunks' ``[C, C]`` products (``products_path``): ``products``
+#: (``fused``: the Pallas kernels of ``ops/deltanet_pallas.py`` /
+#: ``xla``), ``reason`` where they are ``xla`` (``platform`` /
+#: ``batched`` / ``head_size`` / ``chunk``), the kernels' ``tiles``
+#: where they are ``fused``; once a unit at ``initialize``, again only
+#: where a later trace must differ
 EV_GDN_PATH = _ev("gdn.path")
 #: which form of the causal attention core a unit runs
 #: (``ops/attention.py`` ``attention_path``): ``unit``, ``form``
@@ -321,6 +327,9 @@ GAUGE_EVA_CHUNK = _gauge("eva.chunk")
 GAUGE_EVA_SUMMARIES_PER_ROW = _gauge("eva.summaries_per_row")
 #: ``eva_attention`` units of the workflow on the fused kernels
 GAUGE_EVA_FUSED_LAYERS = _gauge("eva.fused_layers")
+#: ``gated_delta_net`` units of the workflow whose chunk products the
+#: fused kernels make
+GAUGE_GDN_FUSED_LAYERS = _gauge("gdn.fused_layers")
 #: rows the held experts could not take in the probed minibatch, summed
 #: over the layers (static buffers are sized for the worst routing: 0)
 GAUGE_MOE_DROPPED_ROWS = _gauge("moe.dropped_rows")

@@ -51,7 +51,10 @@ k_t)``; ``S <- S + k_t delta_t^T``; ``o_t = S^T q_t``.  Out: ``y_t = w
 2048 out-projection (the entry's ``dense``).  The program runs the
 rule in chunks of 64 (the triangular system inside a chunk solved once
 for all chunks, the state carried chunk to chunk) where the row is
-whole chunks, the recurrence itself otherwise (``gdn.path``).
+whole chunks, the recurrence itself otherwise; on a TPU a chunk's
+``[64, 64]`` matrices are made, inverted and used inside the fused
+kernels of ``ops/deltanet_pallas.py``, forward and backward, elsewhere
+as XLA ops (``gdn.path``: ``form``, ``products``).
 
 Gated attention: ``W_q u [T, 16, 512]`` splits a head into query (256)
 and gate (256); ``k, v [T, 2, 256]``; ``q <- N(q)``, ``k <- N(k)`` a

@@ -26,12 +26,39 @@ is made once for all chunks as the product ``(I + P)(I + P^2)(I +
 P^4)...``, ``P = -M`` (M is nilpotent), all large matmuls; only the
 ``[dk, dv]`` state is carried chunk to chunk by a ``lax.scan`` — and
 ``recurrent``, the recurrence above token by token: the oracle of the
-chunked form, and what a ragged row runs.  The backward of both is
-``jax.vjp`` of the scan: that of a carried state.  State, decays and
+chunked form, and what a ragged row runs.  The backward of both scans
+is ``jax.vjp`` of the scan: that of a carried state.  State, decays and
 the triangular inverse in f32; the chunk products take operands in the
 compute dtype and accumulate in f32.
 
-Device ops carry ``gdn/conv``, ``gdn/rule``, ``gdn/gate_norm``.
+What the scan over chunks reads — ``u = T (beta V)``, ``w = T (beta
+Gamma K)``, the decayed ``q k^T`` and ``log Gamma``, ``T = (I + M)^-1``
+— is made in one of two ways, chosen by :func:`products_path` from
+what the unit observes (platform, ``vmap``, chunk and head sizes; no
+knob) and journaled with the form (``gdn.path``: ``products``,
+``reason`` / ``tiles``; gauge ``gdn.fused_layers``): ``fused`` on a TPU
+where the shapes tile — the two Pallas kernels of
+``ops/deltanet_pallas.py``, a chunk's ``[C, C]`` matrices in VMEM from
+birth to last use, forward and backward — else ``xla``,
+:func:`_chunk_products`: ``CHUNKS_AT_ONCE`` chunks at a time under
+``jax.checkpoint``, its backward ``jax.vjp`` of the ten-product chain;
+what every other platform, a cohort under ``vmap`` and a shape that
+does not tile run, and the oracle of the kernels.  The fused backward
+is no transpose of that chain: with ``A = I + M``, ``T = A^-1``, ``u =
+T b_v``, ``w = T b_k`` (``b_v = beta V``, ``b_k = beta Gamma K``),
+
+    d b_v = T^T du,   d b_k = T^T dw,
+    dA = -(d b_v) u^T - (d b_k) w^T   (strictly lower; the rest masked)
+
+so it needs ``T`` once — re-made in VMEM exactly as the forward makes
+it, from the kernel's inputs, its only residuals — two ``T^T [C, dk +
+dv]`` products and two ``[C, d] [d, C]`` products a value head, then
+the elementwise chain back to ``k``, ``beta``, ``g`` (``dM_ij`` moves
+``beta_i``, ``k_i . k_j`` and ``log Gamma_i - log Gamma_j``) and the
+``q k^T`` branch of the decayed scores.
+
+Device ops carry ``gdn/conv``, ``gdn/rule``, ``gdn/gate_norm``; both
+kernels run under ``gdn/rule``.
 """
 
 from __future__ import annotations
@@ -42,7 +69,8 @@ from typing import Any, Dict
 import numpy as np
 
 from veles_tpu import events, telemetry
-from veles_tpu.ops.sequence import SequenceUnit
+from veles_tpu.ops import deltanet_pallas
+from veles_tpu.ops.sequence import SequenceUnit, under_vmap
 
 
 #: chunks whose ``[C, C]`` products are made (and kept) at one time
@@ -54,6 +82,30 @@ def rule_path(t: int, chunk: int) -> Dict[str, Any]:
     if chunk > 1 and t % chunk == 0:
         return {"form": "chunked", "chunk": chunk}
     return {"form": "recurrent", "reason": "ragged", "chunk": 1}
+
+
+def products_path(platform: str, chunk: int, key_head_size: int,
+                  value_head_size: int, heads_a_key: int,
+                  batched: bool = False) -> Dict[str, Any]:
+    """Which form makes the chunks' products, from what the code
+    observes: ``{"products": "fused", "tiles": Tiles}`` on a TPU where
+    the shapes tile, else ``{"products": "xla", "reason": ...}`` —
+    ``platform`` (not a TPU), ``batched`` (under ``vmap``: the kernels'
+    blocks have no member axis), ``head_size`` (a head is not whole
+    128-lane columns), ``chunk`` (the chunk's systems do not fill
+    whole tiles)."""
+    if platform != "tpu":
+        return {"products": "xla", "reason": "platform"}
+    if batched:
+        return {"products": "xla", "reason": "batched"}
+    tiles = deltanet_pallas.tiles_for(chunk, key_head_size,
+                                      value_head_size, heads_a_key)
+    if tiles is None:
+        lanes = deltanet_pallas.LANES
+        return {"products": "xla", "reason": "head_size"
+                if key_head_size % lanes or value_head_size % lanes
+                else "chunk"}
+    return {"products": "fused", "tiles": tiles}
 
 
 def causal_conv(x, kernel):
@@ -134,41 +186,63 @@ def _chunk_products(cd, qc, kc, vc, gc, bc):
     return u, w, a_qk, gsum
 
 
-def rule_chunked(q, k, v, g, beta, chunk: int, compute_dtype):
-    """The same rule in chunks of ``chunk`` positions (the module's
-    docstring has the algebra); arguments and result as
-    :func:`rule_recurrent`, q, k, v taken in ``compute_dtype``.  The
-    chunks' own products — the ``[C, C]`` matrices and the triangular
-    inverse — are made ``CHUNKS_AT_ONCE`` chunks at a time and made
-    again for the backward (``jax.checkpoint``): whole, a 32 k row's
-    are gigabytes."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
+def chunk_parts(q, k, v, g, beta, chunk: int, cd):
+    """The rule's arguments as the chunk products and the scan read
+    them — chunk first (the scan's axis), heads before positions, so
+    that every product is a batched matmul over (chunk, row, head): q,
+    k ``[n, rows, Hk, C, dk]``, v ``[n, rows, Hk, r, C, dv]`` in
+    ``cd``, g, beta ``[n, rows, Hk, r, C]``."""
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
-    r, n, c, cd = hv // hk, t // chunk, chunk, compute_dtype
-
-    # chunk first (the scan's axis), heads before positions: every
-    # product is a batched matmul over (chunk, row, head)
-    parts = (
+    r, n, c = hv // hk, t // chunk, chunk
+    return (
         q.astype(cd).reshape(b, n, c, hk, dk).transpose(1, 0, 3, 2, 4),
         k.astype(cd).reshape(b, n, c, hk, dk).transpose(1, 0, 3, 2, 4),
         v.astype(cd).reshape(b, n, c, hk, r, dv).transpose(
             1, 0, 3, 4, 2, 5),
         g.reshape(b, n, c, hk, r).transpose(1, 0, 3, 4, 2),
         beta.reshape(b, n, c, hk, r).transpose(1, 0, 3, 4, 2))
+
+
+def products_of(parts, tiles=None, interpret: bool = False):
+    """:func:`_chunk_products` of all ``n`` chunks (``parts``: its five
+    arrays, q in the compute dtype): by the kernels of
+    ``ops/deltanet_pallas.py`` where :func:`products_path` gave their
+    ``tiles`` (``interpret``: the tests', off the chip) — nothing to block or checkpoint there: no
+    ``[n, ..., C, C]`` f32 array exists in HBM and the backward kernel
+    re-makes ``T`` itself — else the XLA form, ``CHUNKS_AT_ONCE``
+    chunks at a time and made again for the backward
+    (``jax.checkpoint``): whole, a 32 k row's are gigabytes."""
+    import jax
+    from jax import lax
+    if tiles is not None:
+        return deltanet_pallas.chunk_products(*parts, tiles, interpret)
+    n = parts[0].shape[0]
     at_once = max(d for d in range(1, min(n, CHUNKS_AT_ONCE) + 1)
                   if n % d == 0)
-    products = jax.checkpoint(partial(_chunk_products, cd))
+    products = jax.checkpoint(partial(_chunk_products, parts[0].dtype))
     if at_once == n:
-        made = products(*parts)
-    else:
-        made = lax.map(lambda args: products(*args), jax.tree.map(
-            lambda a: a.reshape((n // at_once, at_once) + a.shape[1:]),
-            parts))
-        made = jax.tree.map(lambda a: a.reshape((n,) + a.shape[2:]),
-                            made)
+        return products(*parts)
+    made = lax.map(lambda args: products(*args), jax.tree.map(
+        lambda a: a.reshape((n // at_once, at_once) + a.shape[1:]),
+        parts))
+    return jax.tree.map(lambda a: a.reshape((n,) + a.shape[2:]), made)
+
+
+def rule_chunked(q, k, v, g, beta, chunk: int, compute_dtype,
+                 tiles=None, interpret: bool = False):
+    """The same rule in chunks of ``chunk`` positions (the module's
+    docstring has the algebra); arguments and result as
+    :func:`rule_recurrent`, q, k, v taken in ``compute_dtype``; the
+    chunks' own products by :func:`products_of`."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+    r, cd = hv // hk, compute_dtype
+    parts = chunk_parts(q, k, v, g, beta, chunk, cd)
+    made = products_of(parts, tiles, interpret)
 
     @jax.checkpoint       # the scan keeps a chunk's incoming state alone
     def step(s, xs):
@@ -220,7 +294,8 @@ class GatedDeltaNet(SequenceUnit):
         self.value_head_size = value_head_size
         self.conv_kernel, self.chunk_size = conv_kernel, chunk_size
         self.eps = eps
-        #: the :func:`rule_path` journaled at ``initialize``
+        #: the last path journaled: :func:`rule_path` and, where it
+        #: is ``chunked``, :func:`products_path` ({} before the first)
         self.path: Dict[str, Any] = {}
 
     def output_shape_for(self, input_shape):
@@ -247,10 +322,35 @@ class GatedDeltaNet(SequenceUnit):
             return gen.uniform(-bound, bound, shape).astype(np.float32)
         return super().fill_special(name, shape, gen)
 
+    def _path(self, t: int, batched: bool = False) -> Dict[str, Any]:
+        """The form of the rule and of its chunk products for rows of
+        ``t`` positions, journaled (``gdn.path``) whenever it differs
+        from the last one journaled: once at ``initialize``, and again
+        only where a later trace must leave it (a ``vmap``)."""
+        path = rule_path(t, self.chunk_size)
+        if path["form"] == "chunked":
+            path.update(products_path(
+                self.platform(), path["chunk"], self.key_head_size,
+                self.value_head_size,
+                self.n_value_heads // self.n_key_heads, batched))
+        if path != self.path:
+            self.path = path
+            tiles = path.get("tiles")
+            telemetry.event(
+                events.EV_GDN_PATH, unit=self.name, form=path["form"],
+                chunk=path["chunk"], products=path.get("products"),
+                reason=path.get("reason"),
+                tiles=tiles and dict(tiles._asdict()))
+        return path
+
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device=device, **kwargs)
-        self.path = rule_path(int(self.input.shape[1]), self.chunk_size)
-        telemetry.event(events.EV_GDN_PATH, unit=self.name, **self.path)
+        self._path(int(self.input.shape[1]))
+        # units initialize in order: the last one sets the whole count
+        peers = getattr(self.workflow, "forwards", None) or [self]
+        telemetry.gauge(events.GAUGE_GDN_FUSED_LAYERS).set(sum(
+            isinstance(f, GatedDeltaNet)
+            and f.path.get("products") == "fused" for f in peers))
 
     def forward(self, params, x):
         import jax
@@ -286,9 +386,10 @@ class GatedDeltaNet(SequenceUnit):
                     + params["dt_bias"].astype(jnp.float32))
             q, k = unit(q) * dk ** -0.5, unit(k)
             v = v.reshape(b, t, hv, dv)
-            path = rule_path(t, self.chunk_size)
+            path = self._path(t, under_vmap(q, k, v))
             if path["form"] == "chunked":
-                o = rule_chunked(q, k, v, g, beta, path["chunk"], x.dtype)
+                o = rule_chunked(q, k, v, g, beta, path["chunk"], x.dtype,
+                                 path.get("tiles"))
             else:
                 o = rule_recurrent(q, k, v.astype(jnp.float32), g, beta)
         with jax.named_scope(events.SCOPE_GDN_GATE_NORM):
