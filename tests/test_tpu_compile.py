@@ -188,6 +188,43 @@ if kind == "gated_delta_net":
 print("COMPILED")
 """
 
+MELLUM2_UNITS = PRELUDE + """
+from veles_tpu.models.mellum2 import CUT, mellum2_layers
+from veles_tpu.ops.registry import forward_registry
+from veles_tpu.ops.sequence import SequenceUnit
+
+which = sys.argv[1]
+SequenceUnit.platform = lambda self: "tpu"      # no chip to observe
+flat = [c for e in mellum2_layers() for c in e.get("layers", [e])]
+fw = dict(flat[{"window": 2, "full": 17, "moe": 5}[which]])
+unit = forward_registry[fw["type"]][0](None, name="u", **{
+    k: v for k, v in fw["->"].items() if k != "weights_stddev"})
+x = spec((CUT["minibatch"], CUT["seq_len"], 2304), jnp.bfloat16)
+params = {n: spec(s, jnp.bfloat16)
+          for n, s in unit.param_shapes(x.shape).items()}
+
+
+def both(params, x, err):
+    out, back = jax.vjp(unit.forward, params, x)
+    return (out,) + back(err)
+
+
+err = spec(unit.output_shape_for(x.shape), jnp.bfloat16)
+hlo = jax.jit(both).lower(params, x, err).compile().as_text()
+if which == "moe":
+    assert (unit.share["form"], unit.share["rows"], unit.share["blocks"],
+            unit.share["shared"]) == ("gmm", 32768, 8, False), unit.share
+    assert unit.share["tiles"]["in"] == (512, 1024, 512), unit.share
+    assert hlo.count("tpu_custom_call") >= 9
+else:
+    assert (unit.path["form"], unit.path["window"],
+            unit.path["kv_blocks"]) == (
+        ("splash", 1024, 3) if which == "window"
+        else ("splash", None, 16)), unit.path
+    assert hlo.count("tpu_custom_call") >= 3
+print("COMPILED")
+"""
+
 
 def _compile(src, *argv):
     res = subprocess.run(
@@ -259,3 +296,16 @@ def test_hybrid_layer_types_compile_at_the_published_widths(kind, t):
     routing.  tests_tpu/test_hybrid_layers.py runs them on the chip."""
     _compile(HYBRID_UNITS, kind, t)
 
+
+
+@pytest.mark.parametrize("which", ["window", "full", "moe"])
+def test_mellum2_layer_types_compile_at_the_cells_sizes(which):
+    """ISSUE 34's layer types, forward and backward, through the v5e's
+    compiler in bf16 at Mellum2's published widths on the cell's four
+    rows of 8 192: the attention core on the shipped flash kernel under
+    a window of 1 024 keys (its mask tables leave a query block 3 key
+    blocks of 512) and without one (16), 32 query heads x 128 over 4
+    key heads; the share of 16 of 64 experts of width 896 on the
+    shipped grouped matmul at 32 768-row buffers, 8 blocks, no shared
+    expert.  tests_tpu/test_mellum2_layers.py runs them on the chip."""
+    _compile(MELLUM2_UNITS, which)

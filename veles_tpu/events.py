@@ -93,8 +93,11 @@ EV_GDN_PATH = _ev("gdn.path")
 #: (``ops/attention.py`` ``attention_path``): ``unit``, ``form``
 #: (``splash``: the Pallas kernel that ships with jax / ``xla``: a block
 #: of queries at a time), ``reason`` where it is ``xla`` (``platform`` /
-#: ``batched`` / ``head_size`` / ``row``), ``tiles``; once a unit at
-#: ``initialize``, again only where a later trace must differ
+#: ``batched`` / ``head_size`` / ``row``), ``tiles``, ``window`` (keys
+#: a query reads; None: every key up to itself), ``rope`` (``default``
+#: / ``yarn``) and, under the kernel form, ``kv_blocks``: the most key
+#: blocks a query block visits; once a unit at ``initialize``, again
+#: only where a later trace must differ
 EV_ATTN_PATH = _ev("attn.path")
 #: the share of a mixture of experts a unit holds, once at
 #: ``initialize`` (``ops/moe.py``): ``unit``, ``experts_total``,
@@ -103,7 +106,8 @@ EV_ATTN_PATH = _ev("attn.path")
 #: block may choose ``top_k`` held experts: nothing is ever dropped),
 #: ``blocks`` of tokens a row is cut into, ``form`` of the grouped
 #: products (``gmm``: the Pallas grouped matmul that ships with jax /
-#: ``ragged_dot``) and its ``reason`` / ``tiles``
+#: ``ragged_dot``) and its ``reason`` / ``tiles``, ``shared`` (whether
+#: the layer has a shared expert)
 EV_MOE_SHARE = _ev("moe.share")
 #: what the routing of the FIRST firing's first minibatch put on the
 #: held experts, a layer — read by a forward-only probe at set-up,
@@ -330,6 +334,8 @@ GAUGE_EVA_FUSED_LAYERS = _gauge("eva.fused_layers")
 #: ``gated_delta_net`` units of the workflow whose chunk products the
 #: fused kernels make
 GAUGE_GDN_FUSED_LAYERS = _gauge("gdn.fused_layers")
+#: attention units of the workflow whose core reads a window of keys
+GAUGE_ATTN_WINDOW_LAYERS = _gauge("attn.window_layers")
 #: rows the held experts could not take in the probed minibatch, summed
 #: over the layers (static buffers are sized for the worst routing: 0)
 GAUGE_MOE_DROPPED_ROWS = _gauge("moe.dropped_rows")
@@ -474,6 +480,8 @@ SCOPE_GDN_RULE = _scope("gdn/rule")
 SCOPE_GDN_GATE_NORM = _scope("gdn/gate_norm")
 #: causal attention: scores, softmax, weighted sums (no projection)
 SCOPE_ATTN_CORE = _scope("attn/core")
+#: the same of a layer that has a window of keys
+SCOPE_ATTN_WINDOW = _scope("attn/window")
 #: mixture of experts: router (product, softmax, top-k); dispatch
 #: (sort, gather, combine); the grouped products over the held
 #: experts; the shared expert

@@ -1,6 +1,8 @@
 """``moe``: a sparse mixture-of-experts MLP of which this device holds
-a share, beside one shared expert — a layer type of the sequence op
-family (``ops/sequence.py``).
+a share, beside one shared expert where the model has one
+(``shared_size`` 0: none — no ``s_*`` parameters, no op under
+``moe/shared``) — a layer type of the sequence op family
+(``ops/sequence.py``).
 
 ``p = softmax(u W_r)`` over ALL ``experts_total`` experts in f32; the
 ``top_k`` largest, ``w_e = p_e / sum_topk p``; ``y = sum over e in topk
@@ -171,14 +173,16 @@ class MoE(SequenceUnit):
     """Router + the held experts' grouped SwiGLUs + the shared expert
     over ``[rows, T, hidden]`` -> ``[rows, T, hidden]``."""
 
-    matrix_names = param_names = (
-        "router", "w_gate", "w_up", "w_down", "s_gate", "s_up",
-        "s_down", "s_mix")
+    ROUTED = ("router", "w_gate", "w_up", "w_down")
+    SHARED = ("s_gate", "s_up", "s_down", "s_mix")
 
     def __init__(self, workflow=None, experts_total: int = 8,
                  experts_held: int = 4, first_held: int = 0,
                  top_k: int = 2, expert_size: int = 32,
                  shared_size: int = 32, **kwargs: Any) -> None:
+        # (before the base makes a Vector a name)
+        self.matrix_names = self.param_names = self.ROUTED + (
+            self.SHARED if shared_size else ())
         super().__init__(workflow, **kwargs)
         if not 0 <= first_held <= experts_total - experts_held \
                 or not 0 < top_k <= experts_total:
@@ -199,11 +203,13 @@ class MoE(SequenceUnit):
     def param_shapes(self, input_shape):
         h, e = int(input_shape[-1]), self.experts_held
         n, s = self.expert_size, self.shared_size
-        return {"router": (h, self.experts_total),
-                "w_gate": (e, h, n), "w_up": (e, h, n),
-                "w_down": (e, n, h),
-                "s_gate": (h, s), "s_up": (h, s), "s_down": (s, h),
-                "s_mix": (h, 1)}
+        shapes = {"router": (h, self.experts_total),
+                  "w_gate": (e, h, n), "w_up": (e, h, n),
+                  "w_down": (e, n, h)}
+        if s:
+            shapes.update({"s_gate": (h, s), "s_up": (h, s),
+                           "s_down": (s, h), "s_mix": (h, 1)})
+        return shapes
 
     # -- the share, and how it is laid out --------------------------------
 
@@ -217,6 +223,7 @@ class MoE(SequenceUnit):
                  "experts_held": self.experts_held,
                  "first_held": self.first_held, "top_k": self.top_k,
                  "rows": rows, "blocks": tokens // block,
+                 "shared": bool(self.shared_size),
                  **grouped_path(self.platform(), rows, width,
                                 self.expert_size, batched)}
         if share != self.share:
@@ -302,6 +309,8 @@ class MoE(SequenceUnit):
                 (tokens.reshape(n, -1, h), top_i.reshape(n, -1, k),
                  top_w.reshape(n, -1, k)))
             routed = routed.reshape(b * t, h)
+        if not self.shared_size:
+            return routed.astype(x.dtype).reshape(b, t, h)
         with jax.named_scope(events.SCOPE_MOE_SHARED):
             hid = jax.nn.silu(jnp.einsum(
                 "th,hk->tk", tokens, params["s_gate"]).astype(

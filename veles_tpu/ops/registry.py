@@ -116,6 +116,7 @@ def _populate() -> None:
                  seq.GDSequence)
         register("gated_attention", attention.GatedAttention,
                  seq.GDSequence)
+        register("attention", attention.Attention, seq.GDSequence)
         register("moe", moe.MoE, seq.GDSequence)
 
     for name, fn in families:

@@ -309,21 +309,68 @@ class LMHead(SequenceUnit):
         return 2.0 * t * n_in * self.n_pred_heads * self.vocab_size
 
 
-def rope(x, theta: float, rotary: Optional[int] = None):
+def rope_frequencies(spec: Dict[str, Any], head_size: int
+                     ) -> Tuple[np.ndarray, float]:
+    """(inverse frequencies ``[head_size / 2]`` f32, the scale on cos
+    and sin) of a layer's ``rope`` specification, made once on the
+    host.  ``rope_type`` ``default``: ``theta^(-2j/d)``, scale 1.
+    ``yarn`` (as ``transformers`` computes it): ``e_j = theta^(-2j/d)``,
+    ``p_j = e_j / factor``; ``dim(r) = d ln(original / (2 pi r)) / (2 ln
+    theta)``, ``low = max(floor(dim(beta_fast)), 0)``, ``high =
+    min(ceil(dim(beta_slow)), d - 1)``; ``ramp_j = clip((j - low) /
+    (high - low), 0, 1)``; ``f_j = p_j ramp_j + e_j (1 - ramp_j)``; the
+    scale is ``attention_factor`` (``0.1 ln(factor) + 1`` where the
+    specification gives none)."""
+    kind = spec.get("rope_type", "default")
+    theta, d = float(spec["rope_theta"]), int(head_size)
+    e = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    if kind == "default":
+        return e.astype(np.float32), 1.0
+    if kind != "yarn":
+        raise ValueError(f"rope: unknown rope_type {kind!r}")
+    factor = float(spec["factor"])
+    original = float(spec["original_max_position_embeddings"])
+
+    def dim(rotations: float) -> float:
+        return d * np.log(original / (rotations * 2.0 * np.pi)) \
+            / (2.0 * np.log(theta))
+
+    low = max(np.floor(dim(float(spec.get("beta_fast", 32.0)))), 0.0)
+    high = min(np.ceil(dim(float(spec.get("beta_slow", 1.0)))), d - 1.0)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(d // 2) - low) / (high - low), 0.0, 1.0)
+    scale = spec.get("attention_factor")
+    if scale is None:
+        scale = 0.1 * np.log(factor) + 1.0 if factor > 1.0 else 1.0
+    return (e / factor * ramp + e * (1.0 - ramp)).astype(np.float32), \
+        float(scale)
+
+
+def rope(x, theta: Optional[float] = None, rotary: Optional[int] = None,
+         inv_freq=None, scale: float = 1.0):
     """Rotate-half RoPE in f32; x ``[rows, T, heads, d]``.  Over the
     whole head (``rotary`` None), angle ``n * theta^(-2i/d)``, or over
     the first ``rotary`` elements of each head alone (a partial rotary
-    factor: angle ``n * theta^(-2i/rotary)``), the rest untouched."""
+    factor: angle ``n * theta^(-2i/rotary)``), the rest untouched.
+    ``inv_freq`` ``[d / 2]`` given (:func:`rope_frequencies`): angle
+    ``n * inv_freq_i`` in ``theta``'s place, cos and sin times
+    ``scale``."""
     import jax.numpy as jnp
     if rotary is not None and rotary < x.shape[-1]:
         return jnp.concatenate(
             [rope(x[..., :rotary], theta), x[..., rotary:]], -1)
     t, d = x.shape[1], x.shape[-1]
-    inv = jnp.float32(theta) ** (
-        -jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if inv_freq is None:
+        inv = jnp.float32(theta) ** (
+            -jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    else:
+        inv = jnp.asarray(inv_freq, jnp.float32)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv
     cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[None, :, None, :]
     sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[None, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * jnp.float32(scale), sin * jnp.float32(scale)
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., :d // 2], xf[..., d // 2:]
     y = xf * cos + jnp.concatenate([-x2, x1], -1) * sin
