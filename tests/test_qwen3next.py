@@ -476,6 +476,108 @@ def test_moe_in_blocks_of_tokens_equals_one_block(monkeypatch):
         _close(a, b)
 
 
+# -- the compact dispatch buffers ---------------------------------------------
+
+COMPACT = dict(experts_total=16, experts_held=2, top_k=4)
+
+
+def _steered(params, x, pairs_held):
+    """Router and input under which the first ``pairs_held // 2`` tokens
+    of the ``ROWS * T`` choose experts 0-3 (two held pairs each) and the
+    others experts 2-5 (none): the routing is read off the first 16
+    input dimensions."""
+    router = jnp.zeros_like(params["router"]).at[:16].set(
+        10.0 * jnp.eye(16))
+    here = jnp.zeros(16).at[:4].set(jnp.array([4.0, 3.0, 2.0, 1.0]))
+    there = jnp.roll(here, 2)
+    first = (jnp.arange(ROWS * T) < pairs_held // 2)[:, None]
+    scores = jnp.where(first, here, there).reshape(ROWS, T, 16)
+    return dict(params, router=router), x.at[..., :16].set(scores)
+
+
+@pytest.mark.parametrize("case", ["fits", "every_block_overflows",
+                                  "exactly_at_capacity",
+                                  "one_pair_over_capacity"])
+def test_a_layer_with_compact_buffers_equals_the_reference(
+        case, monkeypatch):
+    """2 of 16 experts held, top 4: a block's buffers hold 1.5 x the
+    pairs it expects, not every pair.  A block whose held pairs fit —
+    up to exactly ``capacity`` of them — goes through the buffers once;
+    one that overflows walks its sorted pairs a bufferful at a time,
+    and nothing is dropped: the layer equals the reference, forward and
+    backward, whatever the routing."""
+    unit, cfg, params, x = _unit("moe", **COMPACT)
+    blocks, pieces = 1, 1
+    if case == "every_block_overflows":
+        monkeypatch.setattr(moe, "DISPATCH_BUFFER_BYTES",
+                            64 * 4 * HIDDEN * 2)
+        blocks, pieces = ROWS * T // 64, 3      # 128 pairs in 48 rows
+        x = jnp.abs(x)
+        params = dict(params, router=params["router"].at[:, :2].add(3.0))
+    elif case != "fits":
+        pieces = 1 + (case == "one_pair_over_capacity")
+        params, x = _steered(params, x, 192 + 2 * (pieces - 1))
+    load = unit.report_probe(jax.device_get(unit.probe(params, x)))
+    capacity = unit.share["capacity"]
+    assert (unit.share["rows"], unit.share["blocks"], capacity) == (
+        ROWS * T * 4 // blocks, blocks, 192 // blocks)
+    assert load["dropped"] == 0 and load["blocks"] == blocks
+    assert load["over_capacity_blocks"] == (blocks if pieces > 1 else 0)
+    assert telemetry.gauge(
+        events.GAUGE_MOE_OVER_CAPACITY_BLOCKS).value \
+        == load["over_capacity_blocks"]
+    if case == "every_block_overflows":
+        assert load["local_assignments"] == ROWS * T * 2
+    elif case != "fits":
+        assert load["local_assignments"] == capacity + 2 * (pieces - 1)
+        # the pieces one at a time: what lies beyond the held pairs
+        # adds nothing, and a piece is needed only past ``capacity``
+        tokens = x.reshape(ROWS * T, HIDDEN)
+        top_i, top_w = unit.route(params, tokens)
+        whole = unit._routed_block(params, None, tokens, top_i, top_w)
+        parts = [unit._compact_block(params, None, capacity,
+                                     jnp.int32(piece), tokens, top_i,
+                                     top_w) for piece in range(3)]
+        _close(sum(parts[:pieces]), whole)
+        assert float(jnp.abs(parts[pieces - 1]).max()) > 0.0
+        assert float(jnp.abs(parts[pieces]).max()) == 0.0
+    want, back = jax.vjp(lambda p, xx: ref.layer_forward(cfg, p, xx),
+                         params, x)
+    y, mine = jax.vjp(unit.forward, params, x)
+    _close(y, want)
+    err = jax.random.normal(jax.random.key(4), want.shape)
+    for a, b in zip(jax.tree.leaves(mine(err)),
+                    jax.tree.leaves(back(err))):
+        _close(a, b)
+
+
+@pytest.mark.parametrize("block,top_k,held,total,unit,batched,want", [
+    (4096, 10, 32, 512, moe.GMM_ROWS, False, 4096),     # qwen3next's cell
+    (4096, 8, 16, 64, moe.GMM_ROWS, False, 12288),      # mellum2's cell
+    (4096, 8, 16, 64, moe.GMM_ROWS, True, None),        # under vmap
+    (4096, 8, 64, 64, moe.GMM_ROWS, False, None),       # every expert held
+    (256, 2, 4, 8, moe.SUBLANES, False, None),          # the tiny preset
+    (256, 2, 2, 8, moe.SUBLANES, False, 192),           # mellum2's tiny one
+    (4096, 8, 21, 64, moe.GMM_ROWS, False, 16384),      # half: still compact
+    (4096, 8, 22, 64, moe.GMM_ROWS, False, None)])      # over half: whole
+def test_capacity_is_read_from_shapes(block, top_k, held, total, unit,
+                                      batched, want):
+    assert moe.dispatch_capacity(block * top_k, held, total, unit,
+                                 batched) == want
+    if want is not None:
+        assert want % unit == 0 and 2 * want <= block * top_k
+
+
+def test_the_tiny_preset_keeps_the_whole_buffers():
+    unit, _, _, x = _unit("moe")
+    assert unit._share(ROWS * T, HIDDEN)["capacity"] is None
+    assert unit._share(ROWS * T, HIDDEN, batched=True)["capacity"] is None
+    compact, _, _, _ = _unit("moe", **COMPACT)
+    assert compact._share(ROWS * T, HIDDEN)["capacity"] == 192
+    assert compact._share(ROWS * T, HIDDEN, batched=True)[
+        "capacity"] is None
+
+
 @pytest.mark.parametrize("platform,rows,width,inner,batched,form", [
     ("cpu", 40960, 2048, 512, False, "ragged_dot"),
     ("tpu", 40960, 2048, 512, False, "gmm"),

@@ -178,6 +178,12 @@ form = {"gated_attention": lambda: unit.path, "moe": lambda: unit.share,
         "gated_delta_net": lambda: unit.path}[kind]()
 assert form["form"] == want[0], form
 assert hlo.count("tpu_custom_call") >= want[1], hlo.count("tpu_custom_call")
+if kind == "moe":
+    # the compact buffers alone (nine grouped products + the combine's
+    # two): no array of the whole buffers' 40 960 rows is left
+    assert (form["rows"], form["capacity"]) == (10 * t, t), form
+    assert hlo.count("tpu_custom_call") >= 11
+    assert "bf16[%d,2048]" % (10 * t) not in hlo
 if kind == "gated_delta_net":
     assert form == dict(deltanet.rule_path(t, unit.chunk_size),
                         products="fused", tiles=(8, 2)), form
@@ -213,9 +219,11 @@ err = spec(unit.output_shape_for(x.shape), jnp.bfloat16)
 hlo = jax.jit(both).lower(params, x, err).compile().as_text()
 if which == "moe":
     assert (unit.share["form"], unit.share["rows"], unit.share["blocks"],
-            unit.share["shared"]) == ("gmm", 32768, 8, False), unit.share
+            unit.share["capacity"], unit.share["shared"]) == (
+        "gmm", 32768, 8, 12288, False), unit.share
     assert unit.share["tiles"]["in"] == (512, 1024, 512), unit.share
-    assert hlo.count("tpu_custom_call") >= 9
+    assert hlo.count("tpu_custom_call") >= 11
+    assert "bf16[32768,896]" not in hlo     # no whole-buffer product
 else:
     assert (unit.path["form"], unit.path["window"],
             unit.path["kv_blocks"]) == (
@@ -292,8 +300,10 @@ def test_hybrid_layer_types_compile_at_the_published_widths(kind, t):
     multi-query kernel mapped over the key heads, forward + dq + dkv),
     and the expert share's
     three grouped products on the shipped grouped-matmul kernel
-    (forward + two backward each) with buffers sized for the worst
-    routing.  tests_tpu/test_hybrid_layers.py runs them on the chip."""
+    (forward + two backward each) through the compact buffers of
+    ISSUE 35 (4 096 rows of the worst routing's 40 960, the combine a
+    grouped product too, a loop over a block's pieces round them).
+    tests_tpu/test_hybrid_layers.py runs them on the chip."""
     _compile(HYBRID_UNITS, kind, t)
 
 
@@ -306,6 +316,7 @@ def test_mellum2_layer_types_compile_at_the_cells_sizes(which):
     a window of 1 024 keys (its mask tables leave a query block 3 key
     blocks of 512) and without one (16), 32 query heads x 128 over 4
     key heads; the share of 16 of 64 experts of width 896 on the
-    shipped grouped matmul at 32 768-row buffers, 8 blocks, no shared
-    expert.  tests_tpu/test_mellum2_layers.py runs them on the chip."""
+    shipped grouped matmul at the compact 12 288-row buffers (ISSUE 35;
+    32 768 rows would take the worst routing), 8 blocks, no shared expert.
+    tests_tpu/test_mellum2_layers.py runs them on the chip."""
     _compile(MELLUM2_UNITS, which)

@@ -91,6 +91,65 @@ def test_gmm_experts_match_ragged_dot(tpu_device):
     assert 1500 < int(got["expert_rows"].sum()) < 4000
 
 
+def test_compact_buffers_match_the_whole_buffers(tpu_device):
+    """ISSUE 35: one dispatch block of 4 096 tokens at the cell's
+    shapes through the compact buffers (4096 rows) against the
+    whole ones (40960), forward + backward, equal to rounding (the
+    operands are the same bf16 values, a token's slots are summed in
+    f32 in another order), the two times printed; under a router that
+    sends every token here the block overflows and walks its pairs a
+    bufferful at a time: the layer without compaction's numbers."""
+    import time
+    unit = _unit("moe")
+    unit.device = tpu_device
+    x = jax.random.normal(jax.random.key(1), (1, 4096, 2048),
+                          jnp.bfloat16)
+    params = {n: (0.02 * jax.random.normal(
+        jax.random.fold_in(jax.random.key(2), i), s)).astype(jnp.bfloat16)
+        for i, (n, s) in enumerate(unit.param_shapes(x.shape).items())}
+    err = jax.random.normal(jax.random.key(3), x.shape, jnp.bfloat16)
+    here = dict(params, router=params["router"].at[:, :32].add(1.0))
+
+    def both(params, x, err):
+        out, back = jax.vjp(unit.forward, params, x)
+        return (out,) + back(err)
+
+    def timed(f, *a):
+        jax.block_until_ready(f(*a))
+        t0 = time.perf_counter()
+        for _ in range(10):
+            out = f(*a)
+        jax.block_until_ready(out)
+        return out, (time.perf_counter() - t0) / 10
+
+    compact, t_compact = timed(jax.jit(both), params, x, err)
+    assert (unit.share["rows"], unit.share["blocks"],
+            unit.share["capacity"]) == (40960, 1, 4096)
+    over, t_over = timed(jax.jit(both), here, jnp.abs(x), err)
+    load = unit.report_probe(jax.device_get(
+        jax.jit(unit.probe)(here, jnp.abs(x))))
+    assert (load["over_capacity_blocks"], load["blocks"],
+            load["dropped"]) == (1, 1, 0)
+    plain = moe.dispatch_capacity
+    try:
+        moe.dispatch_capacity = lambda *a, **k: None
+        whole, t_whole = timed(jax.jit(lambda *a: both(*a)),
+                               params, x, err)
+        assert unit.share["capacity"] is None
+        over_whole = jax.jit(lambda *a: both(*a))(here, jnp.abs(x), err)
+    finally:
+        moe.dispatch_capacity = plain
+    print(f"one block forward + backward: compact {t_compact:.5f} s, "
+          f"whole {t_whole:.5f} s, overflowing {t_over:.5f} s")
+    for a, b in zip(jax.tree.leaves(compact), jax.tree.leaves(whole)):
+        assert np.isfinite(np.asarray(a, np.float32)).all()
+        assert _gap(a, b) <= 1e-2, _gap(a, b)
+    # (pieces' parts are added in f32, a gradient's in bf16)
+    for a, b in zip(jax.tree.leaves(over), jax.tree.leaves(over_whole)):
+        assert _gap(a, b) <= 2e-2, _gap(a, b)
+    assert t_compact < t_whole, (t_compact, t_whole)
+
+
 def _rule_inputs(seed):
     """q, k (unit, q scaled), v, g, beta of one row at the published
     widths, f32."""
@@ -175,8 +234,8 @@ def test_the_cells_sizes_take_the_chip_forms_and_drop_nothing(
     shares = telemetry.recent_events(events.EV_MOE_SHARE)
     assert len(shares) == 4 and all(
         (e["form"], e["experts_total"], e["experts_held"], e["top_k"],
-         e["rows"], e["blocks"]) == ("gmm", 512, 32, 10, 40960, 8)
-        for e in shares)
+         e["rows"], e["blocks"], e["capacity"]) == (
+            "gmm", 512, 32, 10, 40960, 8, 4096) for e in shares)
     blocked = telemetry.recent_events(events.EV_LOSS_BLOCKED)[-1]
     assert (blocked["blocks"], blocked["reason"]) == (
         16, "whole_exceeds_free")
@@ -191,7 +250,11 @@ def test_the_cells_sizes_take_the_chip_forms_and_drop_nothing(
         assert e["dropped"] == 0
         # 32 768 x 10 / 16 = 20 480 expected; never all, never none
         assert 10_000 < e["local_assignments"] < 40_000, e
+        # 2 560 pairs a block expected in buffers of 4 096
+        assert (e["over_capacity_blocks"], e["blocks"]) == (0, 8), e
     assert telemetry.gauge(events.GAUGE_MOE_DROPPED_ROWS).value == 0
+    assert telemetry.gauge(
+        events.GAUGE_MOE_OVER_CAPACITY_BLOCKS).value == 0
     _, loss_sum, count, _ = w.fused.take_class_metrics()
     assert count == CUT["seq_len"] - 1
     assert abs(loss_sum / count - np.log(CUT["vocab_held"])) < 0.5

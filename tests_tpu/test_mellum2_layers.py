@@ -114,6 +114,68 @@ def test_gmm_experts_at_the_cells_buffers_match_ragged_dot(tpu_device):
         and int(got["expert_rows"].max()) < 8_000
 
 
+def test_compact_buffers_match_the_whole_buffers(tpu_device):
+    """ISSUE 35: one dispatch block of 4 096 tokens at the cell's
+    shapes through the compact buffers (12288 rows) against the
+    whole ones (32768), forward + backward, equal to rounding (the
+    operands are the same bf16 values, a token's slots are summed in
+    f32 in another order), the two times printed; under a router that
+    sends every token here the block overflows and walks its pairs a
+    bufferful at a time: the layer without compaction's numbers."""
+    import time
+    flat = [c for e in mellum2_layers() for c in e.get("layers", [e])]
+    fw = dict(next(c for c in flat if c["type"] == "moe")["->"])
+    fw.pop("weights_stddev")
+    unit = forward_registry["moe"][0](None, name="u_moe", **fw)
+    unit.device = tpu_device
+    x = jax.random.normal(jax.random.key(1), (1, 4096, 2304),
+                          jnp.bfloat16)
+    params = {n: (0.02 * jax.random.normal(
+        jax.random.fold_in(jax.random.key(2), i), s)).astype(jnp.bfloat16)
+        for i, (n, s) in enumerate(unit.param_shapes(x.shape).items())}
+    err = jax.random.normal(jax.random.key(3), x.shape, jnp.bfloat16)
+    here = dict(params, router=params["router"].at[:, :16].add(1.0))
+
+    def both(params, x, err):
+        out, back = jax.vjp(unit.forward, params, x)
+        return (out,) + back(err)
+
+    def timed(f, *a):
+        jax.block_until_ready(f(*a))
+        t0 = time.perf_counter()
+        for _ in range(10):
+            out = f(*a)
+        jax.block_until_ready(out)
+        return out, (time.perf_counter() - t0) / 10
+
+    compact, t_compact = timed(jax.jit(both), params, x, err)
+    assert (unit.share["rows"], unit.share["blocks"],
+            unit.share["capacity"]) == (32768, 1, 12288)
+    over, t_over = timed(jax.jit(both), here, jnp.abs(x), err)
+    load = unit.report_probe(jax.device_get(
+        jax.jit(unit.probe)(here, jnp.abs(x))))
+    assert (load["over_capacity_blocks"], load["blocks"],
+            load["dropped"]) == (1, 1, 0)
+    plain = moe.dispatch_capacity
+    try:
+        moe.dispatch_capacity = lambda *a, **k: None
+        whole, t_whole = timed(jax.jit(lambda *a: both(*a)),
+                               params, x, err)
+        assert unit.share["capacity"] is None
+        over_whole = jax.jit(lambda *a: both(*a))(here, jnp.abs(x), err)
+    finally:
+        moe.dispatch_capacity = plain
+    print(f"one block forward + backward: compact {t_compact:.5f} s, "
+          f"whole {t_whole:.5f} s, overflowing {t_over:.5f} s")
+    for a, b in zip(jax.tree.leaves(compact), jax.tree.leaves(whole)):
+        assert np.isfinite(np.asarray(a, np.float32)).all()
+        assert _gap(a, b) <= 1e-2, _gap(a, b)
+    # (pieces' parts are added in f32, a gradient's in bf16)
+    for a, b in zip(jax.tree.leaves(over), jax.tree.leaves(over_whole)):
+        assert _gap(a, b) <= 2e-2, _gap(a, b)
+    assert t_compact < t_whole, (t_compact, t_whole)
+
+
 def test_the_cells_sizes_take_the_chip_forms_and_drop_nothing(
         tpu_device):
     """``veles_tpu/models/mellum2.py`` as the cell runs it: 4 layers,
@@ -136,8 +198,8 @@ def test_the_cells_sizes_take_the_chip_forms_and_drop_nothing(
     shares = telemetry.recent_events(events.EV_MOE_SHARE)
     assert len(shares) == 4 and all(
         (e["form"], e["experts_total"], e["experts_held"], e["top_k"],
-         e["rows"], e["blocks"], e["shared"]) == (
-            "gmm", 64, 16, 8, 32768, 8, False) for e in shares)
+         e["rows"], e["blocks"], e["capacity"], e["shared"]) == (
+            "gmm", 64, 16, 8, 32768, 8, 12288, False) for e in shares)
     blocked = telemetry.recent_events(events.EV_LOSS_BLOCKED)[-1]
     assert (blocked["blocks"], blocked["reason"]) == (
         32, "whole_exceeds_free")
@@ -151,6 +213,10 @@ def test_the_cells_sizes_take_the_chip_forms_and_drop_nothing(
         assert e["dropped"] == 0
         # 32 768 x 8 / 4 = 65 536 expected; never all, never none
         assert 40_000 < e["local_assignments"] < 100_000, e
+        # 8 192 pairs a block expected in buffers of 12 288: a seed's
+        # router may send a block over (it then takes the whole ones)
+        assert e["blocks"] == 8 and 0 <= e["over_capacity_blocks"] <= 8
+        print(e)
     assert telemetry.gauge(events.GAUGE_MOE_DROPPED_ROWS).value == 0
     _, loss_sum, count, _ = w.fused.take_class_metrics()
     assert count == CUT["minibatch"] * (CUT["seq_len"] - 1)

@@ -104,7 +104,10 @@ EV_ATTN_PATH = _ev("attn.path")
 #: ``experts_held``, ``first_held``, ``top_k``, ``rows`` the dispatch
 #: buffers of one block of tokens are sized for (every token of the
 #: block may choose ``top_k`` held experts: nothing is ever dropped),
-#: ``blocks`` of tokens a row is cut into, ``form`` of the grouped
+#: ``blocks`` of tokens a row is cut into, ``capacity`` (rows of the
+#: compact buffers a block goes through, once where its held pairs
+#: fit them: the pairs it expects x ``DISPATCH_HEADROOM``, from shapes
+#: alone; None: the ``rows``-row buffers), ``form`` of the grouped
 #: products (``gmm``: the Pallas grouped matmul that ships with jax /
 #: ``ragged_dot``) and its ``reason`` / ``tiles``, ``shared`` (whether
 #: the layer has a shared expert)
@@ -113,7 +116,9 @@ EV_MOE_SHARE = _ev("moe.share")
 #: held experts, a layer — read by a forward-only probe at set-up,
 #: never inside a timed step (``FusedStepRunner._report_loads``):
 #: ``unit``, ``local_assignments``, ``max_expert_rows``,
-#: ``min_expert_rows``, ``dropped`` (must read 0)
+#: ``min_expert_rows``, ``dropped`` (must read 0),
+#: ``over_capacity_blocks`` of the minibatch's ``blocks`` (they go
+#: through the compact buffers more than once)
 EV_MOE_LOAD = _ev("moe.load")
 #: whether the head's product, the loss and the head's error are made
 #: a block of positions at a time (``FusedStepRunner._decide_loss_
@@ -339,6 +344,10 @@ GAUGE_ATTN_WINDOW_LAYERS = _gauge("attn.window_layers")
 #: rows the held experts could not take in the probed minibatch, summed
 #: over the layers (static buffers are sized for the worst routing: 0)
 GAUGE_MOE_DROPPED_ROWS = _gauge("moe.dropped_rows")
+#: dispatch blocks of the probed minibatch whose held pairs passed the
+#: compact buffers' ``capacity`` and went through them a piece at a
+#: time, summed over the layers (of ``moe.load``'s ``blocks`` a layer)
+GAUGE_MOE_OVER_CAPACITY_BLOCKS = _gauge("moe.over_capacity_blocks")
 GAUGE_FUSED_TRAIN_GFLOPS_PER_IMAGE = _gauge(
     "fused.train_gflops_per_image")
 GAUGE_FUSED_TRAIN_IMAGES_PER_SEC_WALL = _gauge(
