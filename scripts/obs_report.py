@@ -10,8 +10,13 @@ The loading/merging/rendering internals live in ``veles_tpu/obs.py``
 script is the CLI: counter/gauge tables, a quantile table per
 histogram (count, mean, p50, p90, p99, max) — the per-dispatch /
 per-genome / per-request latency distributions the serving and
-multi-chip SLOs hang on — derived per-engine throughput, and the
-interleaved multi-process event timeline.  ``--json`` emits the merged
+multi-chip SLOs hang on — derived per-engine throughput, the
+interleaved multi-process event timeline, and a "set-up" section a
+training process: the self time of every span between its first
+span's start and the end of its first train epoch, with the first
+call of each step kind split into trace / lowering / compile or cache
+load / rest — the arithmetic the benchmark's ``setup.unspanned_ms``
+reader uses (``benchmarks/lib/timeline.py``).  ``--json`` emits the merged
 snapshot (plus the event count) as one JSON object for machines.
 """
 
@@ -26,11 +31,58 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
+from benchmarks.lib import timeline  # noqa: E402
 from veles_tpu.obs import (assemble_traces,  # noqa: E402
                            fleet_model_rows, fleet_rows, learner_rows,
                            load_dir, load_tree, render, render_fleet,
                            render_trace)
 from veles_tpu.telemetry import Histogram  # noqa: E402
+
+
+def render_setup(snaps, events) -> str:
+    """The "set-up" section: one block a process whose flushed
+    snapshot holds a set-up timeline (``telemetry.setup_timeline``) —
+    self time by span, in order of first start (outermost first), then
+    the journal's ``fused.first_dispatch`` events of that process, each
+    first call's seconds split by what jax reported inside it."""
+    out = []
+    for path in snaps:
+        with open(path) as f:
+            snap = json.load(f)
+        tl = snap.get("setup_timeline")
+        if not tl or not tl["records"]:
+            continue
+        pid = str(snap.get("pid"))
+        b = timeline.breakdown(tl)
+        state = "sealed at the first train class end" \
+            if tl["sealed_at"] is not None else "still open"
+        out.append(
+            f"-- set-up [{pid}]: {b['interval_s']:.3f} s from the "
+            f"first span's start, {state}; "
+            f"{1e3 * b['unspanned_s']:.1f} ms under no span or under "
+            f"{' / '.join(timeline.UNNAMED)} alone; dropped "
+            f"{b['dropped']}, on other threads {b['others']} --")
+        w = max(len(n) for n in b["self_s"])
+        out.append(f"  {'span':<{w}}  {'calls':>6} {'self ms':>11}")
+        for name, calls, self_s, _at in b["rows"]:
+            out.append(f"  {name or '(no span)':<{w}}  {calls:>6} "
+                       f"{1e3 * self_s:>11.2f}")
+        for ev in events:
+            if ev.get("event") != "fused.first_dispatch" \
+                    or ev.get("_pid") != pid \
+                    or "trace_seconds" not in ev:
+                continue
+            parts = [ev["trace_seconds"], ev["lower_seconds"],
+                     ev["compile_seconds"]]
+            out.append(
+                f"  first {ev.get('kind')} submit "
+                f"{1e3 * ev['seconds']:.1f} ms = trace "
+                f"{1e3 * parts[0]:.1f} + lowering {1e3 * parts[1]:.1f}"
+                f" + {'compile' if ev.get('cold') else 'cache load'} "
+                f"{1e3 * parts[2]:.1f} + rest "
+                f"{1e3 * (ev['seconds'] - sum(parts)):.1f}")
+        out.append("")
+    return "\n".join(out)
 
 
 def main(argv=None) -> int:
@@ -116,6 +168,9 @@ def main(argv=None) -> int:
         print()
     print(render(args.metrics_dir, reg, snaps, journals, events,
                  max_events=args.events))
+    setup = render_setup(snaps, events)
+    if setup:
+        print("\n" + setup.rstrip())
     return 0
 
 
@@ -124,4 +179,4 @@ if __name__ == "__main__":
 
 
 # re-exported for tests (quantile sanity against a raw histogram)
-__all__ = ["load_dir", "render", "main", "Histogram"]
+__all__ = ["load_dir", "render", "render_setup", "main", "Histogram"]

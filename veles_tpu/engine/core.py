@@ -44,49 +44,112 @@ from __future__ import annotations
 
 import contextlib
 import threading
+import time
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
 
 from veles_tpu import events, telemetry
 
-# -- compiles, seen from inside the program ----------------------------
+# -- tracing, lowering and compiles, seen from inside the program -------
 
 #: jax.monitoring's names: the duration of one ``compile_or_get_cached``
-#: (a backend compile OR a persistent-cache load), and the retrieval
-#: time jax reports just before it when the cache held the program
+#: (a backend compile OR a persistent-cache load), the retrieval time
+#: jax reports just before it when the cache held the program, and what
+#: the compile cache does NOT save: tracing a jitted function to a jaxpr
+#: and lowering the jaxpr to its module (Pallas kernels among it)
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+
+#: jax's event -> (journal event, counter of all its seconds, counter
+#: of the part inside an open ``fused.*`` span)
+_DURATIONS = {
+    _TRACE_EVENT: (events.EV_XLA_TRACE, events.CTR_XLA_TRACE_SECONDS,
+                   events.CTR_FUSED_TRACE_SECONDS),
+    _LOWER_EVENT: (events.EV_XLA_LOWER, events.CTR_XLA_LOWER_SECONDS,
+                   events.CTR_FUSED_LOWER_SECONDS),
+    _COMPILE_EVENT: (events.EV_XLA_COMPILE,
+                     events.CTR_XLA_COMPILE_SECONDS,
+                     events.CTR_FUSED_COMPILE_SECONDS),
+}
+#: a trace or a lowering shorter than this is counted and not journaled
+#: (every ``jnp`` function a trace calls reports a trace of its own)
+_JOURNAL_FROM_SECONDS = 0.01
+#: events a thread remembers (below); inner ones go when their outer
+#: one comes, so only an unbroken run of this many side by side
+#: inside one trace would lose its oldest
+_KEPT_EVENTS = 32768
+#: an earlier event that ended less than this after an event's start
+#: ended before it (two clock reads lie between an end and its report)
+_CLOCK_SLACK = 1e-4
 
 _watching_compiles = False
-_compiling = threading.local()     # .hit: the cache held the program
+#: per thread: ``.hit`` — the cache held the program now compiling;
+#: ``.counted`` — ``(end, seconds counted up to and with this event)``
+#: of its events in order of arrival, which is the order of their ends;
+#: ``.floor`` — the seconds counted before the oldest one kept
+_compiling = threading.local()
+
+
+def _own_seconds(duration: float) -> float:
+    """The part of ``[now - duration, now]`` that no earlier event of
+    this thread covered.  jax reports an event at its END, so a jitted
+    function called inside a trace (or an eager op compiled under
+    ``ensure_compile_time_eval``) reports before the trace it ran in:
+    the outer event then counts only what its inner ones left — every
+    event that ended after it started lies inside it — and the seconds
+    of all kinds add up to the union of their intervals, never to more
+    than the wall time they took."""
+    now = time.perf_counter()
+    state = _compiling.__dict__
+    counted = state.setdefault("counted", [])
+    total = counted[-1][1] if counted else state.get("floor", 0.0)
+    while counted and counted[-1][0] > now - duration + _CLOCK_SLACK:
+        counted.pop()
+    before = counted[-1][1] if counted else state.get("floor", 0.0)
+    own = max(duration - (total - before), 0.0)
+    counted.append((now, total + own))
+    if len(counted) > _KEPT_EVENTS:
+        state["floor"] = counted[_KEPT_EVENTS // 2 - 1][1]
+        del counted[:_KEPT_EVENTS // 2]
+    return own
 
 
 def _on_jax_duration(event: str, duration: float, **kw: Any) -> None:
     if event == _CACHE_HIT_EVENT:
         _compiling.hit = True
         return
-    if event != _COMPILE_EVENT:
+    names = _DURATIONS.get(event)
+    if names is None:
         return
-    cached = getattr(_compiling, "hit", False)
-    _compiling.hit = False
-    # the listener runs on the compiling thread, so its open spans say
-    # which step compiled and inside what
+    journal, all_seconds, fused_seconds = names
+    own = _own_seconds(duration)
+    # the listener runs on the tracing / compiling thread, so its open
+    # spans say which step it was and inside what
     during = telemetry.span_stack()
-    telemetry.counter(events.CTR_XLA_COMPILES).inc()
-    telemetry.counter(events.CTR_XLA_COMPILE_SECONDS).inc(duration)
-    if any(name.startswith("fused.") for name in during):
-        telemetry.counter(events.CTR_FUSED_COMPILE_SECONDS).inc(
-            duration)
-    telemetry.event(events.EV_XLA_COMPILE,
-                    seconds=round(duration, 6),
-                    fun=kw.get("fun_name"), cached=cached,
-                    during=during)
+    in_fused = any(name.startswith("fused.") for name in during)
+    telemetry.counter(all_seconds).inc(own)
+    if in_fused:
+        telemetry.counter(fused_seconds).inc(own)
+    fields = {}
+    if event == _COMPILE_EVENT:
+        cached = fields["cached"] = getattr(_compiling, "hit", False)
+        _compiling.hit = False
+        telemetry.counter(events.CTR_XLA_COMPILES).inc()
+        if in_fused and not cached:
+            telemetry.counter(events.CTR_FUSED_COLD_COMPILES).inc()
+    elif duration < _JOURNAL_FROM_SECONDS:
+        return
+    telemetry.event(journal, seconds=round(duration, 6),
+                    fun=kw.get("fun_name"), during=during, **fields)
 
 
 def watch_compiles() -> None:
-    """Register the process's ONE jax.monitoring listener (jax keeps a
-    listener for the life of the process, so: once).  Called where
+    """Register the process's ONE jax.monitoring listener — tracing,
+    lowering, compiles and cache loads all come through it (jax keeps
+    a listener for the life of the process, so: once).  Called where
     this module first touches jax."""
     global _watching_compiles
     if _watching_compiles:

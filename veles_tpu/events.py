@@ -69,6 +69,10 @@ def _timed(name: str) -> str:
 
 # -- journal events ----------------------------------------------------
 
+#: the first call of a step kind: where and what (device, static
+#: shapes), its ``seconds`` and of them ``trace_seconds``,
+#: ``lower_seconds``, ``compile_seconds`` (compile or cache load) and
+#: ``cold`` — compiles the persistent cache did not serve
 EV_FUSED_FIRST_DISPATCH = _ev("fused.first_dispatch")
 EV_FUSED_SUMMARY = _ev("fused.summary")
 EV_FUSED_RECOMPUTE = _ev("fused.recompute")
@@ -138,6 +142,14 @@ EV_DP_GRAD_EXCHANGE = _ev("dp.grad_exchange")
 #: jitted function's name, ``cached``, and ``during`` = the spans open
 #: on the compiling thread — which step compiled, and inside what
 EV_XLA_COMPILE = _ev("xla.compile")
+#: the same shape (``seconds``, ``fun``, ``during``; no ``cached``) for
+#: what the compile cache does not save, from 10 ms up: a jitted
+#: function traced to a jaxpr, a jaxpr lowered to its module (a Pallas
+#: kernel's Mosaic lowering is in the second).  A function traced
+#: inside a trace reports before the outer one, whose ``seconds`` hold
+#: it; the counters below count every interval once
+EV_XLA_TRACE = _ev("xla.trace")
+EV_XLA_LOWER = _ev("xla.lower")
 
 EV_DEVICE_OOM_RETRY = _ev("device.oom_retry")
 EV_DEVICE_OOM_DEGRADED = _ev("device.oom_degraded")
@@ -225,7 +237,6 @@ EV_SUPERVISOR_GIVEUP = _ev("supervisor.giveup")
 # -- counters ----------------------------------------------------------
 
 CTR_FUSED_DISPATCHES = _ctr("fused.dispatches")
-CTR_FUSED_MINIBATCHES = _ctr("fused.minibatches")
 CTR_FUSED_TRAIN_TOKENS = _ctr("fused.train_tokens")
 CTR_FUSED_STREAM_TRANSFER_BYTES = _ctr("fused.stream_transfer_bytes")
 CTR_FUSED_STREAM_TRANSFER_SECONDS = _ctr(
@@ -237,6 +248,18 @@ CTR_FUSED_STREAM_OOM_RETRIES = _ctr("fused.stream_oom_retries")
 CTR_XLA_COMPILES = _ctr("xla.compiles")
 CTR_XLA_COMPILE_SECONDS = _ctr("xla.compile_seconds")
 CTR_FUSED_COMPILE_SECONDS = _ctr("fused.compile_seconds")
+#: tracing and lowering the same way: all of it, and the part inside a
+#: ``fused.*`` span.  Seconds are the UNION of the reported intervals a
+#: thread (an inner trace, or an eager op compiled under a trace, is
+#: taken off the event it ran in), so trace + lower + compile never
+#: exceed the wall time of the span they fell in
+CTR_XLA_TRACE_SECONDS = _ctr("xla.trace_seconds")
+CTR_XLA_LOWER_SECONDS = _ctr("xla.lower_seconds")
+CTR_FUSED_TRACE_SECONDS = _ctr("fused.trace_seconds")
+CTR_FUSED_LOWER_SECONDS = _ctr("fused.lower_seconds")
+#: backend compiles inside a ``fused.*`` span that the persistent cache
+#: did NOT serve: 0 in a warm run
+CTR_FUSED_COLD_COMPILES = _ctr("fused.cold_compiles")
 
 CTR_ENSEMBLE_CHUNKS = _ctr("ensemble.chunks")
 CTR_ENSEMBLE_SECONDS = _ctr("ensemble.seconds")
@@ -348,10 +371,6 @@ GAUGE_MOE_DROPPED_ROWS = _gauge("moe.dropped_rows")
 #: compact buffers' ``capacity`` and went through them a piece at a
 #: time, summed over the layers (of ``moe.load``'s ``blocks`` a layer)
 GAUGE_MOE_OVER_CAPACITY_BLOCKS = _gauge("moe.over_capacity_blocks")
-GAUGE_FUSED_TRAIN_GFLOPS_PER_IMAGE = _gauge(
-    "fused.train_gflops_per_image")
-GAUGE_FUSED_TRAIN_IMAGES_PER_SEC_WALL = _gauge(
-    "fused.train_images_per_sec_wall")
 GAUGE_SERVE_QUEUE_DEPTH = _gauge("serve.queue_depth")
 GAUGE_SERVE_MODELS_RESIDENT = _gauge("serve.models_resident")
 GAUGE_SERVE_RESIDENT_BYTES = _gauge("serve.resident_bytes")
@@ -431,6 +450,18 @@ SPAN_LOADER_RESIDENT_DTYPE = _span("loader.resident_dtype")
 SPAN_WORKFLOW_INITIALIZE = _timed("workflow.initialize")
 SPAN_WORKFLOW_RUN = _timed("workflow.run")
 SPAN_FUSED_BUILD_STEPS = _timed("fused.build_steps")
+#: inside it, the decisions made from shapes alone before anything is
+#: jitted: what the chain keeps or re-runs (two ``eval_shape`` walks of
+#: every layer: all but milliseconds of it, on the chip), the loss's
+#: blocks, on a mesh the gradient exchange
+SPAN_FUSED_PLAN = _timed("fused.plan")
+#: ``probe_units``: the forward-only program built, compiled or loaded,
+#: run on one minibatch and fetched (set-up, once after the first
+#: train firing where a unit has a ``probe``)
+SPAN_FUSED_PROBE = _timed("fused.probe")
+#: the splash kernel's mask tables, made on the host once a shape by
+#: whatever trace asks first (``ops/attention.py`` ``_splash_kernel``)
+SPAN_ATTN_MASK_TABLES = _timed("attn.mask_tables")
 SPAN_FUSED_ENSURE_PARAMS = _timed("fused.ensure_params")
 SPAN_FUSED_PUT_CARRY = _timed("fused.put_carry")
 #: the host's wait for every queued superstep of the class: the

@@ -97,7 +97,8 @@ def _splash_kernel(t: int, group: int, block: int,
         block_kv_dkv_compute=block, block_q_dq=block, block_kv_dq=block)
     one = mask.CausalMask((t, t)) if window is None \
         else mask.LocalMask((t, t), (window - 1, 0), 0)
-    with jax.ensure_compile_time_eval():
+    with telemetry.span(events.SPAN_ATTN_MASK_TABLES), \
+            jax.ensure_compile_time_eval():
         return kernel.make_splash_mqa_single_device(
             mask.MultiHeadMask([one] * group), block_sizes=sizes)
 

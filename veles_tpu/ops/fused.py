@@ -46,6 +46,15 @@ from veles_tpu.loader.base import TRAIN
 from veles_tpu import events, prng, telemetry
 from veles_tpu.ops import batching
 
+#: ``fused.first_dispatch``'s split of the first call: field -> the
+#: counter whose growth over the call it is (engine/core.py's listener)
+_FIRST_CALL_PARTS = {
+    "trace_seconds": events.CTR_FUSED_TRACE_SECONDS,
+    "lower_seconds": events.CTR_FUSED_LOWER_SECONDS,
+    "compile_seconds": events.CTR_FUSED_COMPILE_SECONDS,
+    "cold": events.CTR_FUSED_COLD_COMPILES,
+}
+
 
 class FusedStepRunner(AcceleratedUnit):
     def __init__(self, workflow=None, loader=None, forwards=None,
@@ -233,8 +242,22 @@ class FusedStepRunner(AcceleratedUnit):
         # decided here is how the data reaches it and where it lies
         ingest = engine_core.build_ingest(
             getattr(self.loader, "dequant", None))
-        recompute = self._decide_recompute(cd)
-        loss_blocks = self._decide_loss_blocks(cd)
+        # what is decided from shapes alone, before anything is jitted
+        # (on a mesh: how each layer's gradients become the global
+        # minibatch's — gathered activations or an all-reduce — and
+        # what the chip's compiler is told with the step)
+        exchange = None
+        with telemetry.span(events.SPAN_FUSED_PLAN):
+            recompute = self._decide_recompute(cd)
+            loss_blocks = self._decide_loss_blocks(cd)
+            if core.on_mesh:
+                exchange = engine_core.GradExchange(
+                    core.mesh, self.forwards, self.gds, cd)
+                telemetry.event(events.EV_DP_GRAD_EXCHANGE,
+                                **exchange.describe())
+                telemetry.gauge(
+                    events.GAUGE_DP_GRAD_EXCHANGE_GROUPS).set(
+                    len(exchange.groups))
         blocked_head = None
         if loss_blocks:
             blocked_head = engine_core.build_blocked_head(
@@ -243,17 +266,6 @@ class FusedStepRunner(AcceleratedUnit):
         forward_pass = engine_core.build_forward(
             self.forwards, seed, cd, recompute,
             head_apart=bool(loss_blocks))
-        # on a mesh: how each layer's gradients become the global
-        # minibatch's (gathered activations or an all-reduce, from
-        # shapes), and what the chip's compiler is told with the step
-        exchange = None
-        if core.on_mesh:
-            exchange = engine_core.GradExchange(
-                core.mesh, self.forwards, self.gds, cd)
-            telemetry.event(events.EV_DP_GRAD_EXCHANGE,
-                            **exchange.describe())
-            telemetry.gauge(events.GAUGE_DP_GRAD_EXCHANGE_GROUPS).set(
-                len(exchange.groups))
         backward_update = engine_core.build_backward(
             self.forwards, self.gds, cd, seed, exchange)
 
@@ -537,7 +549,6 @@ class FusedStepRunner(AcceleratedUnit):
                 self._report_probes(indices[0])
         self._rng_counter += k
         telemetry.counter(events.CTR_FUSED_DISPATCHES).inc()
-        telemetry.counter(events.CTR_FUSED_MINIBATCHES).inc(k)
         telemetry.counter(
             f"fused.{'train' if train else 'eval'}_images").inc(images)
         if train and self._targets_are_rows():
@@ -556,14 +567,15 @@ class FusedStepRunner(AcceleratedUnit):
         import jax
 
         from veles_tpu.engine import core as engine_core
-        if self._probe is None:
-            fn = engine_core.build_probe(self.forwards,
-                                         self._resolved_dtype())
-            self._probe = self._core.jit(fn) if fn else False
-        if not self._probe:
-            return {}
-        self._ensure_params()
-        return jax.device_get(self._probe(self._params, rows))
+        with telemetry.span(events.SPAN_FUSED_PROBE):
+            if self._probe is None:
+                fn = engine_core.build_probe(self.forwards,
+                                             self._resolved_dtype())
+                self._probe = self._core.jit(fn) if fn else False
+            if not self._probe:
+                return {}
+            self._ensure_params()
+            return jax.device_get(self._probe(self._params, rows))
 
     def _report_probes(self, indices) -> None:
         """Once, right after the first train firing: the probed units
@@ -599,6 +611,8 @@ class FusedStepRunner(AcceleratedUnit):
             telemetry.histogram(events.HIST_LOOP_TURNAROUND).record(
                 now - self._fetch_returned)
             self._fetch_returned = None
+        was = {field: telemetry.counter(n).value
+               for field, n in _FIRST_CALL_PARTS.items()} if first else {}
         with telemetry.span(f"fused.first_{kind}_submit" if first
                             else f"fused.{kind}_submit") as span:
             yield
@@ -608,9 +622,14 @@ class FusedStepRunner(AcceleratedUnit):
                 f"fused.first_{kind}_submit_seconds").set(span.seconds)
             # the run record's "where and what": the device as JAX
             # reports it and the static step shape, next to the one
-            # call that traced + compiled (or loaded) the program
+            # call that traced + lowered + compiled (or loaded) the
+            # program, and how its seconds split
             telemetry.event(events.EV_FUSED_FIRST_DISPATCH, kind=kind,
                             seconds=round(span.seconds, 4),
+                            **{field: round(
+                                telemetry.counter(n).value - was[field],
+                                6) for field, n in
+                               _FIRST_CALL_PARTS.items()},
                             streaming=bool(self.streaming),
                             minibatches=k,
                             batch_shape=list(
@@ -775,9 +794,10 @@ class FusedStepRunner(AcceleratedUnit):
         super().stop()
 
     def _record_telemetry_summary(self) -> None:
-        """End-of-run throughput gauges: wall-clock images/sec since
-        the first firing and — where the device's peak is known —
-        achieved MFU via profiling.py, over MXU work alone (conv +
+        """End-of-run summary (``fused.summary``): wall-clock images/sec
+        since the first firing and — where the device's peak is known
+        — achieved MFU via profiling.py (also the gauge ``fused.mfu``),
+        over MXU work alone (conv +
         dense MACs: pool/LRN/activation passes are HBM traffic and
         would raise a utilisation).  Wall includes host time between
         dispatches, so this is the run's DELIVERED rate (a lower bound
@@ -792,16 +812,10 @@ class FusedStepRunner(AcceleratedUnit):
         if elapsed <= 0 or images <= 0:
             return
         rate = images / elapsed
-        telemetry.gauge(
-            events.GAUGE_FUSED_TRAIN_IMAGES_PER_SEC_WALL).set(
-            round(rate, 3))
         try:
             from veles_tpu import profiling
             flops = profiling.model_flops_per_sample(
                 self.forwards)["mxu_train"]
-            telemetry.gauge(
-                events.GAUGE_FUSED_TRAIN_GFLOPS_PER_IMAGE).set(
-                round(flops / 1e9, 4))
             jdev = getattr(self.device, "jax_device", None)
             u = profiling.mfu(rate, flops, jdev) \
                 if jdev is not None else None
@@ -892,6 +906,10 @@ class FusedStepRunner(AcceleratedUnit):
             self._class_open = None
             telemetry.counter(f"fused.{kind}_wall_seconds").inc(
                 self._fetch_returned - t_open)
+            if kind == "train":
+                # the first epoch is done: the program's own end of
+                # set-up (only the first call seals)
+                telemetry.seal_setup()
         self._acc, self._conf = self._fresh_acc()
         return float(acc[0]), float(acc[1]), float(acc[2]), conf
 

@@ -32,6 +32,13 @@ that has imported jax a span is also a ``jax.profiler.TraceAnnotation``
 named ``veles:<name>``, so a profiler trace shows the program's own
 spans on the device trace's clock (see :class:`span`).
 
+**The set-up timeline**: until it is sealed — once, by the training
+loop when its first train class's metric fetch has returned — every
+span's exit also appends ``(name, parent, start, end, thread)`` to
+one bounded list (``setup_timeline()``), so set-up, which runs under
+no profiler, keeps what a layer's self time is computed from.  After
+the seal a span's exit pays one global load and a check for None.
+
 **The journal** is an append-only JSONL file of notable run events
 (``event("ga.hang_detected", kind=...)``): hang detections, restarts,
 OOM degradations, snapshot fallbacks, epoch ends — the replayable
@@ -387,6 +394,17 @@ FLUSH_EVERY = 5.0
 
 _tls = threading.local()
 
+#: the set-up timeline: ``(name, parent, start, end, thread)`` of every
+#: span that exited while it was open, on ``time.perf_counter`` (the
+#: histograms' clock).  Open from import, sealed once (``seal_setup``);
+#: past ``TIMELINE_CAP`` records are counted, not kept.
+TIMELINE_CAP = 4096
+_timeline: List[tuple] = []
+_timeline_dropped = 0
+#: (perf_counter, thread) of the seal; None while the timeline is open
+_timeline_sealed: Optional[tuple] = None
+_timeline_lock = witness.lock("telemetry.timeline")
+
 
 def counter(name: str) -> Counter:
     return _registry.counter(name)
@@ -537,7 +555,7 @@ class span:
         self.journal = journal
         self.fields = fields
         self.seconds = 0.0
-        self._stack: Optional[List[str]] = None
+        self._stack: Optional[List["span"]] = None
         self._annotation = None
 
     def __enter__(self) -> "span":
@@ -545,8 +563,8 @@ class span:
             stack = getattr(_tls, "stack", None)
             if stack is None:
                 stack = _tls.stack = []
-            self._parent = stack[-1] if stack else None
-            stack.append(self.name)
+            self._parent = stack[-1].name if stack else None
+            stack.append(self)
             self._stack = stack
             # getattr: another thread may be half way through
             # ``import jax``
@@ -568,9 +586,12 @@ class span:
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
             self._annotation = None
-        if stack and stack[-1] == self.name:
+        if stack and stack[-1] is self:
             stack.pop()
         histogram(self.name).record(dt)
+        if _timeline_sealed is None:
+            _timeline_add(self.name, self._parent, self._t0,
+                          self._t0 + dt)
         if self.journal:
             event(self.name, seconds=round(dt, 6),
                   parent=self._parent, depth=len(stack),
@@ -579,13 +600,55 @@ class span:
 
 def span_stack() -> List[str]:
     """The current thread's open spans, outermost first."""
-    return list(getattr(_tls, "stack", []) or [])
+    return [s.name for s in getattr(_tls, "stack", None) or ()]
+
+
+def _timeline_add(name: str, parent: Optional[str], start: float,
+                  end: float) -> None:
+    global _timeline_dropped
+    with _timeline_lock:
+        if len(_timeline) < TIMELINE_CAP:
+            _timeline.append((name, parent, start, end,
+                              threading.get_ident()))
+        else:
+            _timeline_dropped += 1
+
+
+def seal_setup() -> None:
+    """End of set-up, as the program itself sees it: stop keeping
+    spans.  The spans still open on the calling thread (the loop's:
+    ``workflow.run``, ``decision.run``) are kept as ending now, so the
+    thread's records cover it up to the seal.  Only the first call
+    seals."""
+    global _timeline_sealed
+    with _timeline_lock:
+        if _timeline_sealed is not None:
+            return
+        now = time.perf_counter()
+        me = threading.get_ident()
+        for s in reversed(getattr(_tls, "stack", None) or ()):
+            _timeline.append((s.name, s._parent, s._t0, now, me))
+        _timeline_sealed = (now, me)
+
+
+def setup_timeline() -> Dict[str, Any]:
+    """The set-up timeline as it stands: ``records`` — ``[name, parent,
+    start, end, thread]`` in order of exit, seconds on
+    ``time.perf_counter`` — ``sealed_at`` and ``sealed_thread`` (None
+    while it is open), and ``dropped``, the spans past the cap."""
+    with _timeline_lock:
+        at, thread = _timeline_sealed or (None, None)
+        return {"records": [list(r) for r in _timeline],
+                "sealed_at": at, "sealed_thread": thread,
+                "dropped": _timeline_dropped}
 
 
 def snapshot() -> Dict[str, Any]:
     snap = _registry.snapshot()
     snap["pid"] = os.getpid()
     snap["ts"] = round(time.time(), 3)
+    if _timeline:
+        snap["setup_timeline"] = setup_timeline()
     return snap
 
 
@@ -682,13 +745,18 @@ def adopt_child_snapshot(pid: int) -> bool:
 
 def reset() -> None:
     """Zero every metric in place, clear the event ring, drop the
-    journal handle, and re-read the environment arming — the test
-    fixture's clean-slate hook.  Live Counter/Histogram references
+    journal handle, reopen the set-up timeline empty, and re-read the
+    environment arming — the test fixture's clean-slate hook.  Live
+    Counter/Histogram references
     held by long-lived objects stay valid (they are zeroed, not
     replaced)."""
     global _dir, _journal_file, _last_flush
+    global _timeline_dropped, _timeline_sealed
     _registry.reset()
     _recent.clear()
+    with _timeline_lock:
+        del _timeline[:]
+        _timeline_dropped, _timeline_sealed = 0, None
     with _journal_lock:
         if _journal_file is not None:
             try:
