@@ -325,9 +325,11 @@ def test_gdn_path_is_journaled_and_a_vmap_leaves_the_kernels():
     one = lambda i: {n: p[i] for n, p in params.items()}  # noqa: E731
     want = jnp.stack([unit.forward(one(i), x) for i in range(2)])
     assert unit.path == {"form": "chunked", "chunk": 64,
-                         "products": "xla", "reason": "platform"}
+                         "products": "xla", "reason": "platform",
+                         "scan": "xla", "scan_reason": "platform"}
     unit.device = SimpleNamespace(platform="tpu")
     assert unit._path(T)["products"] == "fused"
+    assert unit.path["scan"] == "fused"
     got = jax.vmap(unit.forward, in_axes=(0, None))(params, x)
     _close(got, want)
     assert unit._path(100) == {"form": "recurrent", "reason": "ragged",
@@ -336,8 +338,125 @@ def test_gdn_path_is_journaled_and_a_vmap_leaves_the_kernels():
     assert [(e["form"], e["products"], e["reason"]) for e in seen] == [
         ("chunked", "xla", "platform"), ("chunked", "fused", None),
         ("chunked", "xla", "batched"), ("recurrent", None, "ragged")]
+    assert [(e["scan"], e["scan_reason"]) for e in seen] == [
+        ("xla", "platform"), ("fused", None), ("xla", "batched"),
+        (None, None)]
     assert seen[1]["tiles"] == {"pairs": 8, "pack": 2}
+    assert seen[1]["scan_tiles"] == {"heads": 2, "unroll": 2}
     assert all(e["unit"] == unit.name for e in seen)
+
+
+# -- the scan over chunks in kernels (ISSUE 38), in Pallas interpret mode ---
+
+def _scan_inputs(chunk, r, cd, hk=2, n=3, g_scale=1.0):
+    """(u, w, a_qk, log Gamma, q, k) of ``n`` chunks of one row, as the
+    scan reads them: the XLA form's products of ``hk`` key heads each
+    serving ``r`` value heads of 128."""
+    parts, _ = _chunk_inputs(chunk, r, cd, n=n, hk=hk, g_scale=g_scale)
+    return tuple(deltanet.products_of(parts)) + parts[:2]
+
+
+def _xla_scan(*a):
+    return deltanet.scan_of(a[:4], a[4], a[5])
+
+
+@pytest.mark.parametrize("hk,heads", [(2, None), (3, 1), (3, None)])
+@pytest.mark.parametrize("cd,tol", [(jnp.float32, 1e-5),
+                                    (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("r", [1, 2])
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_scan_kernels_equal_the_xla_scan(chunk, r, cd, tol, hk, heads):
+    """``gdn_scan_fwd`` / ``gdn_scan_bwd`` against the XLA scan and
+    ``jax.vjp`` of it: o and the cotangents to u, w, a_qk (masked as
+    the decayed scores are), log Gamma, q, k — the same operand dtypes
+    as the XLA step, so f32 agrees to its rounding and bf16 to the
+    bf16 roundings the XLA transposition adds; key heads all in one
+    grid step, or three of one head each (a count no step size but 1
+    and 3 divides)."""
+    args = _scan_inputs(chunk, r, cd, hk=hk)
+    tiles = deltanet_pallas.ScanTiles(heads) if heads else \
+        deltanet_pallas.scan_tiles_for(chunk, 128, 128, hk, r)
+    assert tiles.heads == (heads or hk)
+    want, back = jax.vjp(_xla_scan, *args)
+    got, mine = jax.vjp(
+        lambda *a: deltanet.scan_of(a[:4], a[4], a[5], tiles, True), *args)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert _gap(got, want) <= tol, _gap(got, want)
+    do = jax.random.normal(jax.random.key(61), want.shape).astype(cd)
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    for name, a, b in zip(("u", "w", "a_qk", "gsum", "q", "k"), mine(do),
+                          back(do)):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        if name == "a_qk":
+            assert not bool(jnp.any(jnp.where(lower, 0, a)))
+            b = jnp.where(lower, b, 0)
+        assert _gap(a, b) <= tol, (name, _gap(a, b))
+
+
+def test_scan_kernels_survive_the_published_decay():
+    """``g`` x 60: a chunk's ``Gamma_C`` underflows f32; the kernels'
+    decays are the XLA step's exps of the same values — finite, and
+    the XLA scan's, forward and backward."""
+    args = _scan_inputs(64, 2, jnp.float32, g_scale=60.0)
+    tiles = deltanet_pallas.scan_tiles_for(64, 128, 128, 2, 2)
+    want, back = jax.vjp(_xla_scan, *args)
+    got, mine = jax.vjp(
+        lambda *a: deltanet_pallas.chunk_scan(*a, tiles, True), *args)
+    ones = jnp.ones_like(want)
+    for a, b in zip((got,) + mine(ones), (want,) + back(ones)):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        if a.shape[-2:] == (64, 64):
+            b = jnp.tril(b)
+        assert _gap(a, b) <= 1e-5, _gap(a, b)
+
+
+@pytest.mark.parametrize("chunk,hv", [(64, 2), (64, 1), (128, 2)])
+def test_chunked_rule_over_both_kernel_pairs_is_the_recurrence(chunk, hv):
+    """``rule_chunked`` with the products AND the scan in kernels
+    equals the token recurrence of the reference, and its gradient the
+    recurrence's (the scan's ``log Gamma`` cotangent joins the one the
+    products kernel receives)."""
+    args = tuple(a[:1] for a in _rule_inputs(256, 1, hv, 128, 128))
+    tiles = deltanet_pallas.tiles_for(chunk, 128, 128, hv)
+    scan = deltanet_pallas.scan_tiles_for(chunk, 128, 128, 1, hv)
+    run = lambda *a: deltanet.rule_chunked(  # noqa: E731
+        *a, chunk, jnp.float32, tiles, True, scan)
+    want = ref.delta_rule(*args)
+    _close(run(*args), want, tol=5e-5)
+    err = jax.random.normal(jax.random.key(8), want.shape)
+    g_want = jax.grad(lambda *a: jnp.sum(ref.delta_rule(*a) * err),
+                      argnums=range(5))(*args)
+    g_got = jax.grad(lambda *a: jnp.sum(run(*a) * err),
+                     argnums=range(5))(*args)
+    for a, b in zip(g_got, g_want):
+        _close(a, b, tol=1e-4)
+
+
+@pytest.mark.parametrize(
+    "platform,chunk,dk,r,hk,batched,scan,reason", [
+        ("cpu", 64, 128, 2, 16, False, "xla", "platform"),
+        ("tpu", 64, 128, 2, 16, False, "fused", None),
+        ("tpu", 64, 128, 2, 16, True, "xla", "batched"),
+        ("tpu", 128, 128, 1, 3, False, "fused", None),
+        ("tpu", 64, 64, 2, 16, False, "xla", "head_size"),
+        ("tpu", 16, 128, 2, 16, False, "xla", "chunk"),
+        ("tpu", 64, 128, 32, 16, False, "fused", None),
+        ("tpu", 128, 1024, 8, 1, False, "xla", "vmem")])
+def test_scan_path_is_chosen_from_platform_and_shapes(
+        platform, chunk, dk, r, hk, batched, scan, reason):
+    """Where the scan kernels engage, from what the unit observes; a
+    grid step takes the most key heads that divide the count and fit
+    half the kernels' VMEM: all 16 of the cell's (24 MiB of f32
+    blocks), all 3 of a count that divides no other way."""
+    path = deltanet.scan_path(platform, chunk, dk, dk, hk, r, batched)
+    assert (path["scan"], path.get("scan_reason")) == (scan, reason)
+    if scan == "fused":
+        heads = path["scan_tiles"].heads
+        assert hk % heads == 0
+        assert heads * deltanet_pallas._scan_head_bytes(
+            chunk, dk, dk, r) <= deltanet_pallas.SCAN_VMEM_LIMIT // 2
+        assert heads == {(64, 2): 16, (128, 1): 3, (64, 32): 1}[
+            chunk, r], path
 
 
 @pytest.mark.parametrize("kind", ["gated_delta_net", "gated_attention"])

@@ -186,11 +186,17 @@ if kind == "moe":
     assert "bf16[%d,2048]" % (10 * t) not in hlo
 if kind == "gated_delta_net":
     assert form == dict(deltanet.rule_path(t, unit.chunk_size),
-                        products="fused", tiles=(8, 2)), form
-    for kernel in ("gdn_products_fwd", "gdn_products_bwd"):
+                        products="fused", tiles=(8, 2), scan="fused",
+                        scan_tiles=(16, 2)), form
+    for kernel in ("gdn_products_fwd", "gdn_products_bwd",
+                   "gdn_scan_fwd", "gdn_scan_bwd"):
         assert kernel in hlo, kernel
     # every [C, C] matrix lives in the kernels: none is an HLO value
     assert "f32[%d,1,16,2,64,64]" % (t // 64) not in hlo
+    # the state lives in the scan kernels: no loop carries it
+    for line in hlo.splitlines():
+        assert not (" while(" in line and "f32[1,16,2,128,128]" in line), \
+            line[:200]
 print("COMPILED")
 """
 

@@ -1,6 +1,7 @@
 """The three layer types of ISSUE 32 on the chip, at Qwen3-Next's
 published widths in bf16: each TPU form (the chunked delta rule, its
-chunk products by the XLA form and by the fused kernels of ISSUE 33;
+chunk products by the XLA form and by the fused kernels of ISSUE 33,
+its scan over chunks by a ``lax.scan`` and by the kernels of ISSUE 38;
 the shipped flash kernel under ``gated_attention``; the shipped grouped
 matmul under ``moe``) against the plain form every other platform runs,
 each within the gap that plain form itself keeps from an f32 run of the
@@ -211,6 +212,51 @@ def test_fused_chunk_products_match_the_xla_form(tpu_device):
         assert _gap(a, b) <= limit, (name, _gap(a, b))
 
 
+def test_scan_kernels_match_the_xla_scan(tpu_device):
+    """ISSUE 38: ``gdn_scan_fwd`` / ``gdn_scan_bwd`` at the published
+    widths against the XLA scan and ``jax.vjp`` of it, both in bf16 on
+    the chip, over the fused products of the same row: o and the six
+    cotangents within the rounding of bf16 (the XLA
+    transposition rounds its products' results to bf16, the kernels
+    accumulate them in f32), and the whole rule both ways against the
+    f32 recurrence no further off than the XLA scan's."""
+    q, k, v, g, beta = _rule_inputs(38)
+    parts = deltanet.chunk_parts(q, k, v, g, beta, 64, jnp.bfloat16)
+    tiles = deltanet.products_path("tpu", 64, 128, 128, 2)["tiles"]
+    scan = deltanet.scan_path("tpu", 64, 128, 128, 16, 2)
+    assert scan["scan"] == "fused" and scan["scan_tiles"].heads == 16
+    made = jax.jit(lambda *a: deltanet.products_of(a, tiles))(*parts)
+    args = tuple(made) + parts[:2]
+    do = jax.random.normal(jax.random.key(5), made[0].shape, jnp.bfloat16)
+
+    def both(scan_tiles):
+        @jax.jit
+        def run(args, do):
+            out, back = jax.vjp(lambda *a: deltanet.scan_of(
+                a[:4], a[4], a[5], scan_tiles), *args)
+            return (out,) + back(do)
+        return run
+
+    want, mine = both(None)(args, do), both(scan["scan_tiles"])(args, do)
+    lower = jnp.tril(jnp.ones((64, 64), bool))
+    for name, a, b in zip(("o", "du", "dw", "da_qk", "dgsum", "dq", "dk"),
+                          mine, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert np.isfinite(np.asarray(a, np.float32)).all(), name
+        if name == "da_qk":
+            b = jnp.where(lower, b, 0)
+        assert _gap(a, b) <= 2e-2, (name, _gap(a, b))
+    with jax.default_matmul_precision("highest"):
+        exact = jax.jit(deltanet.rule_recurrent)(q, k, v, g, beta)
+    gaps = {}
+    for name, s in (("xla", None), ("fused", scan["scan_tiles"])):
+        got = jax.jit(lambda *a, s=s: deltanet.rule_chunked(
+            *a, 64, jnp.bfloat16, tiles, scan=s))(q, k, v, g, beta)
+        gaps[name] = _gap(got, exact)
+    print("chunked rule against the recurrence, scan by:", gaps)
+    assert gaps["fused"] <= 1.25 * gaps["xla"] + 1e-3, gaps
+
+
 def test_the_cells_sizes_take_the_chip_forms_and_drop_nothing(
         tpu_device):
     """``veles_tpu/models/qwen3next.py`` as the cell runs it: 4 layers,
@@ -225,9 +271,11 @@ def test_the_cells_sizes_take_the_chip_forms_and_drop_nothing(
         Launcher(), loader=dict(qwen3next.DEFAULTS["loader"], n_train=2),
         superstep=1, decision={"max_epochs": 1})
     w.initialize(device=tpu_device)
-    assert [(e["form"], e["products"]) for e in telemetry.recent_events(
-        events.EV_GDN_PATH)] == [("chunked", "fused")] * 3
+    assert [(e["form"], e["products"], e["scan"], e["scan_tiles"])
+            for e in telemetry.recent_events(events.EV_GDN_PATH)] == [
+        ("chunked", "fused", "fused", {"heads": 16, "unroll": 2})] * 3
     assert telemetry.gauge(events.GAUGE_GDN_FUSED_LAYERS).value == 3
+    assert telemetry.gauge(events.GAUGE_GDN_FUSED_SCANS).value == 3
     seen = telemetry.recent_events(events.EV_ATTN_PATH)
     assert [(e["form"], e["tiles"]["block_q"]) for e in seen] == [
         ("splash", 512)]

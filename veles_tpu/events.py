@@ -90,7 +90,10 @@ EV_EVA_PATH = _ev("eva.path")
 #: (``fused``: the Pallas kernels of ``ops/deltanet_pallas.py`` /
 #: ``xla``), ``reason`` where they are ``xla`` (``platform`` /
 #: ``batched`` / ``head_size`` / ``chunk``), the kernels' ``tiles``
-#: where they are ``fused``; once a unit at ``initialize``, again only
+#: where they are ``fused``; what runs the scan over chunks
+#: (``scan_path``): ``scan`` (``fused`` / ``xla``), ``scan_reason``
+#: where it is ``xla`` (the same four, or ``vmem``), ``scan_tiles``
+#: where it is ``fused``; once a unit at ``initialize``, again only
 #: where a later trace must differ
 EV_GDN_PATH = _ev("gdn.path")
 #: which form of the causal attention core a unit runs
@@ -362,6 +365,9 @@ GAUGE_EVA_FUSED_LAYERS = _gauge("eva.fused_layers")
 #: ``gated_delta_net`` units of the workflow whose chunk products the
 #: fused kernels make
 GAUGE_GDN_FUSED_LAYERS = _gauge("gdn.fused_layers")
+#: ``gated_delta_net`` units of the workflow whose scan over chunks the
+#: scan kernels run
+GAUGE_GDN_FUSED_SCANS = _gauge("gdn.fused_scans")
 #: attention units of the workflow whose core reads a window of keys
 GAUGE_ATTN_WINDOW_LAYERS = _gauge("attn.window_layers")
 #: rows the held experts could not take in the probed minibatch, summed

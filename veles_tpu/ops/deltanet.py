@@ -24,12 +24,11 @@ triangular system, ``(I + M) Delta = beta V - (beta Gamma K) S0`` with
 ``M_ij = beta_i (Gamma_i / Gamma_j) k_i.k_j`` for j < i, whose inverse
 is made once for all chunks as the product ``(I + P)(I + P^2)(I +
 P^4)...``, ``P = -M`` (M is nilpotent), all large matmuls; only the
-``[dk, dv]`` state is carried chunk to chunk by a ``lax.scan`` — and
-``recurrent``, the recurrence above token by token: the oracle of the
-chunked form, and what a ragged row runs.  The backward of both scans
-is ``jax.vjp`` of the scan: that of a carried state.  State, decays and
-the triangular inverse in f32; the chunk products take operands in the
-compute dtype and accumulate in f32.
+``[dk, dv]`` state is carried chunk to chunk — and ``recurrent``, the
+recurrence above token by token: the oracle of the chunked form, and
+what a ragged row runs (its backward ``jax.vjp`` of its ``lax.scan``).
+State, decays and the triangular inverse in f32; the chunk products
+take operands in the compute dtype and accumulate in f32.
 
 What the scan over chunks reads — ``u = T (beta V)``, ``w = T (beta
 Gamma K)``, the decayed ``q k^T`` and ``log Gamma``, ``T = (I + M)^-1``
@@ -57,8 +56,21 @@ the elementwise chain back to ``k``, ``beta``, ``g`` (``dM_ij`` moves
 ``beta_i``, ``k_i . k_j`` and ``log Gamma_i - log Gamma_j``) and the
 ``q k^T`` branch of the decayed scores.
 
-Device ops carry ``gdn/conv``, ``gdn/rule``, ``gdn/gate_norm``; both
-kernels run under ``gdn/rule``.
+The scan over chunks — a chunk's ``delta = u - w S``, ``o = (Gamma q)
+S + a_qk delta``, ``S <- Gamma_C S + ((Gamma_C / Gamma) k)^T delta`` —
+runs in one of two forms, chosen by :func:`scan_path` from the same
+observations and journaled beside the products (``gdn.path``: ``scan``,
+``scan_reason`` / ``scan_tiles``; gauge ``gdn.fused_scans``): ``fused``
+on a TPU where the shapes tile — the kernel pair ``gdn_scan_fwd`` /
+``gdn_scan_bwd`` of ``ops/deltanet_pallas.py``, each head's f32 state
+(and in the backward its cotangent) in VMEM across the row's chunks,
+the chunks a grid axis visited in order — else ``xla``,
+:func:`_scan_xla`: a ``lax.scan`` of a checkpointed step, its backward
+``jax.vjp`` of the scan (that of a carried state), and the kernels'
+oracle.
+
+Device ops carry ``gdn/conv``, ``gdn/rule``, ``gdn/gate_norm``; all
+four kernels run under ``gdn/rule``.
 """
 
 from __future__ import annotations
@@ -84,28 +96,54 @@ def rule_path(t: int, chunk: int) -> Dict[str, Any]:
     return {"form": "recurrent", "reason": "ragged", "chunk": 1}
 
 
+def _no_kernels(platform: str, key_head_size: int, value_head_size: int,
+                batched: bool) -> Any:
+    """Why no kernel of ``ops/deltanet_pallas.py`` can run, or None:
+    ``platform`` (not a TPU), ``batched`` (under ``vmap``: the kernels'
+    blocks have no member axis), ``head_size`` (a head is not whole
+    128-lane columns)."""
+    lanes = deltanet_pallas.LANES
+    if platform != "tpu":
+        return "platform"
+    if batched:
+        return "batched"
+    if key_head_size % lanes or value_head_size % lanes:
+        return "head_size"
+    return None
+
+
 def products_path(platform: str, chunk: int, key_head_size: int,
                   value_head_size: int, heads_a_key: int,
                   batched: bool = False) -> Dict[str, Any]:
     """Which form makes the chunks' products, from what the code
     observes: ``{"products": "fused", "tiles": Tiles}`` on a TPU where
     the shapes tile, else ``{"products": "xla", "reason": ...}`` —
-    ``platform`` (not a TPU), ``batched`` (under ``vmap``: the kernels'
-    blocks have no member axis), ``head_size`` (a head is not whole
-    128-lane columns), ``chunk`` (the chunk's systems do not fill
-    whole tiles)."""
-    if platform != "tpu":
-        return {"products": "xla", "reason": "platform"}
-    if batched:
-        return {"products": "xla", "reason": "batched"}
-    tiles = deltanet_pallas.tiles_for(chunk, key_head_size,
-                                      value_head_size, heads_a_key)
+    :func:`_no_kernels`' reasons, or ``chunk`` (the chunk's systems do
+    not fill whole tiles)."""
+    reason = _no_kernels(platform, key_head_size, value_head_size, batched)
+    tiles = None if reason else deltanet_pallas.tiles_for(
+        chunk, key_head_size, value_head_size, heads_a_key)
+    if tiles is None:
+        return {"products": "xla", "reason": reason or "chunk"}
+    return {"products": "fused", "tiles": tiles}
+
+
+def scan_path(platform: str, chunk: int, key_head_size: int,
+              value_head_size: int, key_heads: int, heads_a_key: int,
+              batched: bool = False) -> Dict[str, Any]:
+    """Which form runs the scan over chunks, from what the code
+    observes: ``{"scan": "fused", "scan_tiles": ScanTiles}`` on a TPU
+    where the shapes tile, else ``{"scan": "xla", "scan_reason":
+    ...}`` — :func:`_no_kernels`' reasons, ``chunk`` (not 64 or 128),
+    or ``vmem`` (one key head's blocks do not fit the kernels')."""
+    reason = _no_kernels(platform, key_head_size, value_head_size, batched)
+    tiles = None if reason else deltanet_pallas.scan_tiles_for(
+        chunk, key_head_size, value_head_size, key_heads, heads_a_key)
     if tiles is None:
         lanes = deltanet_pallas.LANES
-        return {"products": "xla", "reason": "head_size"
-                if key_head_size % lanes or value_head_size % lanes
-                else "chunk"}
-    return {"products": "fused", "tiles": tiles}
+        return {"scan": "xla", "scan_reason": reason or (
+            "chunk" if chunk not in (lanes // 2, lanes) else "vmem")}
+    return {"scan": "fused", "scan_tiles": tiles}
 
 
 def causal_conv(x, kernel):
@@ -229,20 +267,15 @@ def products_of(parts, tiles=None, interpret: bool = False):
     return jax.tree.map(lambda a: a.reshape((n,) + a.shape[2:]), made)
 
 
-def rule_chunked(q, k, v, g, beta, chunk: int, compute_dtype,
-                 tiles=None, interpret: bool = False):
-    """The same rule in chunks of ``chunk`` positions (the module's
-    docstring has the algebra); arguments and result as
-    :func:`rule_recurrent`, q, k, v taken in ``compute_dtype``; the
-    chunks' own products by :func:`products_of`."""
+def _scan_xla(made, qc, kc):
+    """The scan over chunks as XLA ops: a ``lax.scan`` of a
+    checkpointed ``step`` (arguments and result as
+    ``deltanet_pallas.chunk_scan``)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
-    b, t, hk, dk = q.shape
-    hv, dv = v.shape[2], v.shape[3]
-    r, cd = hv // hk, compute_dtype
-    parts = chunk_parts(q, k, v, g, beta, chunk, cd)
-    made = products_of(parts, tiles, interpret)
+    b, hk, r = made[0].shape[1:4]
+    dk, dv, cd = qc.shape[-1], made[0].shape[-1], qc.dtype
 
     @jax.checkpoint       # the scan keeps a chunk's incoming state alone
     def step(s, xs):
@@ -267,8 +300,35 @@ def rule_chunked(q, k, v, g, beta, chunk: int, compute_dtype,
                          preferred_element_type=jnp.float32)
         return s, o.astype(cd)
 
-    _, o = lax.scan(step, jnp.zeros((b, hk, r, dk, dv), jnp.float32),
-                    (made, parts[0], parts[1]))
+    return lax.scan(step, jnp.zeros((b, hk, r, dk, dv), jnp.float32),
+                    (tuple(made), qc, kc))[1]
+
+
+def scan_of(made, qc, kc, scan=None, interpret: bool = False):
+    """The scan over chunks of :func:`products_of`'s ``made`` (u, w,
+    a_qk, log Gamma) with the chunked q, k: by the kernels of
+    ``ops/deltanet_pallas.py`` where :func:`scan_path` gave their
+    ``scan`` tiles (``interpret``: the tests', off the chip) — each
+    head's state in VMEM across the row's chunks — else the XLA
+    ``lax.scan``.  o ``[n, rows, Hk, r, C, dv]`` in the compute
+    dtype."""
+    if scan is not None:
+        return deltanet_pallas.chunk_scan(*made, qc, kc, scan, interpret)
+    return _scan_xla(made, qc, kc)
+
+
+def rule_chunked(q, k, v, g, beta, chunk: int, compute_dtype,
+                 tiles=None, interpret: bool = False, scan=None):
+    """The same rule in chunks of ``chunk`` positions (the module's
+    docstring has the algebra); arguments and result as
+    :func:`rule_recurrent`, q, k, v taken in ``compute_dtype``; the
+    chunks' own products by :func:`products_of` (``tiles``), the scan
+    over them by :func:`scan_of` (``scan``)."""
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+    parts = chunk_parts(q, k, v, g, beta, chunk, compute_dtype)
+    made = products_of(parts, tiles, interpret)
+    o = scan_of(made, parts[0], parts[1], scan, interpret)
     # [n, b, hk, r, c, dv] -> [b, t, hv, dv]
     return o.transpose(1, 0, 4, 2, 3, 5).reshape(b, t, hv, dv)
 
@@ -295,7 +355,8 @@ class GatedDeltaNet(SequenceUnit):
         self.conv_kernel, self.chunk_size = conv_kernel, chunk_size
         self.eps = eps
         #: the last path journaled: :func:`rule_path` and, where it
-        #: is ``chunked``, :func:`products_path` ({} before the first)
+        #: is ``chunked``, :func:`products_path` and :func:`scan_path`
+        #: ({} before the first)
         self.path: Dict[str, Any] = {}
 
     def output_shape_for(self, input_shape):
@@ -329,18 +390,21 @@ class GatedDeltaNet(SequenceUnit):
         only where a later trace must leave it (a ``vmap``)."""
         path = rule_path(t, self.chunk_size)
         if path["form"] == "chunked":
-            path.update(products_path(
-                self.platform(), path["chunk"], self.key_head_size,
-                self.value_head_size,
-                self.n_value_heads // self.n_key_heads, batched))
+            sizes = (self.platform(), path["chunk"], self.key_head_size,
+                     self.value_head_size)
+            r = self.n_value_heads // self.n_key_heads
+            path.update(products_path(*sizes, r, batched))
+            path.update(scan_path(*sizes, self.n_key_heads, r, batched))
         if path != self.path:
             self.path = path
-            tiles = path.get("tiles")
+            tiles, scan = path.get("tiles"), path.get("scan_tiles")
             telemetry.event(
                 events.EV_GDN_PATH, unit=self.name, form=path["form"],
                 chunk=path["chunk"], products=path.get("products"),
                 reason=path.get("reason"),
-                tiles=tiles and dict(tiles._asdict()))
+                tiles=tiles and dict(tiles._asdict()),
+                scan=path.get("scan"), scan_reason=path.get("scan_reason"),
+                scan_tiles=scan and dict(scan._asdict()))
         return path
 
     def initialize(self, device=None, **kwargs) -> None:
@@ -348,9 +412,11 @@ class GatedDeltaNet(SequenceUnit):
         self._path(int(self.input.shape[1]))
         # units initialize in order: the last one sets the whole count
         peers = getattr(self.workflow, "forwards", None) or [self]
-        telemetry.gauge(events.GAUGE_GDN_FUSED_LAYERS).set(sum(
-            isinstance(f, GatedDeltaNet)
-            and f.path.get("products") == "fused" for f in peers))
+        for gauge, key in ((events.GAUGE_GDN_FUSED_LAYERS, "products"),
+                           (events.GAUGE_GDN_FUSED_SCANS, "scan")):
+            telemetry.gauge(gauge).set(sum(
+                isinstance(f, GatedDeltaNet)
+                and f.path.get(key) == "fused" for f in peers))
 
     def forward(self, params, x):
         import jax
@@ -389,7 +455,8 @@ class GatedDeltaNet(SequenceUnit):
             path = self._path(t, under_vmap(q, k, v))
             if path["form"] == "chunked":
                 o = rule_chunked(q, k, v, g, beta, path["chunk"], x.dtype,
-                                 path.get("tiles"))
+                                 path.get("tiles"),
+                                 scan=path.get("scan_tiles"))
             else:
                 o = rule_recurrent(q, k, v.astype(jnp.float32), g, beta)
         with jax.named_scope(events.SCOPE_GDN_GATE_NORM):

@@ -1,5 +1,8 @@
-"""Pallas TPU kernels for the chunk products of the gated delta rule
-(``ops/deltanet.py`` ``_chunk_products``): what the scan over chunks
+"""Pallas TPU kernels of the gated delta rule: the chunk products
+(``ops/deltanet.py`` ``_chunk_products``) and the scan over chunks that
+reads them (``_scan_xla``; at the end of this module).
+
+The chunk products: what the scan over chunks
 reads — ``u = T (beta V)``, ``w = T (beta Gamma K)``, the decayed ``q
 k^T`` and ``log Gamma``, with ``T = (I + M)^-1`` — made for a chunk
 inside ONE kernel, every ``[C, C]`` matrix (the decay, ``k k^T``, ``q
@@ -47,6 +50,23 @@ against exact ones and are f32-exact.  State, decays and the triangular
 system never leave f32.  ``ops/deltanet.py`` ``_chunk_products`` is the
 oracle (tests/test_qwen3next.py in interpret mode, tests_tpu/
 test_hybrid_layers.py on the chip).
+
+The scan over chunks: two kernels, ``gdn_scan_fwd`` and
+``gdn_scan_bwd``, on the grid (rows, key head groups, chunks), the
+chunk axis ``arbitrary`` and visited in order (the backward last to
+first); a grid step is one chunk of ``ScanTiles.heads`` key heads and
+all their value heads, and each head's f32 ``[dk, dv]`` state — in the
+backward its cotangent — lives in VMEM scratch across the row's chunks.
+The decays ``Gamma q``, ``(Gamma_C / Gamma) k``, ``Gamma_C`` are made
+in VMEM from the chunk's ``log Gamma`` row (turned into a column by the
+diagonal) and its q, k: no copy a value head in HBM.  The products take
+exactly the XLA step's operand dtypes — S and delta cast to the compute
+dtype, f32 accumulation, S itself f32 — so the forward is the XLA
+scan's to its rounding.  The VJP's forward also writes each chunk's
+incoming state (the residual the checkpointed scan kept: f32 ``[n,
+rows, Hk, r, dk, dv]``), unless nothing reads it (``custom_dce``: a
+recomputed entry's first forward); the backward re-makes delta from it.
+``ops/deltanet.py`` ``_scan_xla`` is the oracle.
 """
 
 from __future__ import annotations
@@ -449,3 +469,336 @@ def chunk_products(qc: Any, kc: Any, vc: Any, gc: Any, bc: Any,
             w[:pairs].reshape(vc.shape[:-1] + (dk,)),
             a[:pairs].reshape(vc.shape[:-1] + (c,)),
             gsum[:gc.size // (tiles.pack * c)].reshape(gc.shape))
+
+
+# -- the scan over chunks -------------------------------------------------
+
+#: scoped VMEM of the scan kernels: a grid step's blocks, both buffers,
+#: and its state take at most half of it (``scan_tiles_for``)
+SCAN_VMEM_LIMIT = 64 * 1024 * 1024
+
+#: key heads of a grid step whose chains of products one trip of the
+#: kernels' loop over heads holds side by side
+SCAN_UNROLL = 2
+
+
+class ScanTiles(NamedTuple):
+    """``heads`` key heads (with all their value heads) a grid step,
+    ``unroll`` of them a trip of the loop over heads."""
+    heads: int
+    unroll: int = 1
+
+
+def _scan_head_bytes(chunk: int, key_head_size: int, value_head_size: int,
+                     heads_a_key: int) -> int:
+    """VMEM of one key head in a backward grid step (the larger of the
+    two kernels): its blocks in and out, both buffers, a lane dim under
+    128 padded to it, and the f32 ``dS`` of its value heads — counted
+    at 4 B an element, so that an f32 compute dtype fits too."""
+    c, dk, dv, r = chunk, key_head_size, value_head_size, heads_a_key
+    state = r * dk * dv
+    blocks = (state + 3 * r * c * dv          # S, do, u, du
+              + 2 * r * c * dk                # w, dw
+              + 2 * r * c * max(c, LANES)     # a_qk, its cotangent
+              + 2 * 8 * LANES                 # log Gamma, its cotangent
+              + 4 * c * dk)                   # q, k, dq, dk
+    return 4 * (2 * blocks + state)
+
+
+def scan_tiles_for(chunk: int, key_head_size: int, value_head_size: int,
+                   key_heads: int, heads_a_key: int) -> Optional[ScanTiles]:
+    """The scan kernels' tiling for these sizes, or None where they do
+    not tile: heads of whole 128-lane columns, a chunk of 64 or 128,
+    and one key head's blocks within half of ``SCAN_VMEM_LIMIT``.  A
+    grid step takes the most key heads (a divisor of ``key_heads``:
+    no step is padded) whose blocks fit there — every value head of
+    the step is another chain of products the MXU can overlap with the
+    others', and a step's fixed cost is paid once for all of them."""
+    if key_head_size % LANES or value_head_size % LANES \
+            or chunk not in (LANES // 2, LANES):
+        return None
+    each = _scan_head_bytes(chunk, key_head_size, value_head_size,
+                            heads_a_key)
+    fit = [h for h in range(1, key_heads + 1)
+           if key_heads % h == 0 and h * each <= SCAN_VMEM_LIMIT // 2]
+    if not fit:
+        return None
+    return ScanTiles(fit[-1], max(u for u in range(1, SCAN_UNROLL + 1)
+                                  if fit[-1] % u == 0))
+
+
+class _Decay(NamedTuple):
+    """A value head's decays in a chunk, as the scan applies them."""
+    gam: Any        # Gamma_i, a column [C, 1]
+    rest: Any       # Gamma_C / Gamma_i, a column
+    end: Any        # Gamma_C, [1, 1]
+
+
+def _decay(row, eye):
+    """The decays of one value head from its ``log Gamma`` row ``[1,
+    C]``: the row turned into a column by the diagonal (exact: one
+    term a sum), the exps taken of the same f32 values as the XLA scan
+    takes them."""
+    import jax.numpy as jnp
+    c = row.shape[1]
+    col = jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+    last = jnp.sum(jnp.where(eye[c - 1:c], row, 0.0), axis=1,
+                   keepdims=True)
+    return _Decay(jnp.exp(col), jnp.exp(last - col), jnp.exp(last))
+
+
+def _over_heads(heads: int, unroll: int, head) -> None:
+    """``head(h)`` for every key head of the step, ``unroll`` of them
+    (a divisor) a trip of one loop (Mosaic unrolls a loop once or
+    whole, so the trip's heads are written out)."""
+    import jax
+
+    def trip(i, carry):
+        for j in range(unroll):
+            head(i * unroll + j)
+        return carry
+
+    jax.lax.fori_loop(0, heads // unroll, trip, 0)
+
+
+def _scan_fwd_kernel(u_ref, w_ref, a_ref, gs_ref, q_ref, k_ref, o_ref,
+                     *rest, unroll):
+    """One chunk of every head of the step: ``delta = u - w S``, ``o =
+    (Gamma q) S + a_qk delta``, ``S <- Gamma_C S + ((Gamma_C / Gamma)
+    k)^T delta`` — S f32 in VMEM scratch, cast to the compute dtype for
+    the products as the XLA scan casts it; ``rest``: the incoming
+    states' output where the VJP keeps them, then the scratch."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    *saved, s_ref = rest
+    heads, r, c = gs_ref.shape
+    cd = q_ref.dtype
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = jnp.zeros(s_ref.shape, f32)
+
+    eye = lax.broadcasted_iota(jnp.int32, (c, c), 0) \
+        == lax.broadcasted_iota(jnp.int32, (c, c), 1)
+
+    def head(h):
+        q, k, gs = q_ref[h].astype(f32), k_ref[h].astype(f32), gs_ref[h]
+        for j in range(r):
+            d = _decay(gs[j:j + 1], eye)
+            s = s_ref[h, j]
+            if saved:
+                saved[0][h, j] = s
+            sb = s.astype(cd)
+            delta = u_ref[h, j] - jnp.dot(w_ref[h, j], sb,
+                                          preferred_element_type=f32)
+            db = delta.astype(cd)
+            o = jnp.dot((q * d.gam).astype(cd), sb,
+                        preferred_element_type=f32) \
+                + jnp.dot(a_ref[h, j], db, preferred_element_type=f32)
+            o_ref[h, j] = o.astype(o_ref.dtype)
+            s_ref[h, j] = s * d.end + lax.dot_general(
+                (k * d.rest).astype(cd), db, _TN,
+                preferred_element_type=f32)
+
+    _over_heads(heads, unroll, head)
+
+
+def _scan_bwd_kernel(u_ref, w_ref, a_ref, gs_ref, q_ref, k_ref, st_ref,
+                     do_ref, du_ref, dw_ref, da_ref, dgs_ref, dq_ref,
+                     dk_ref, ds_ref, *, unroll):
+    """One chunk of every head of the step, the chunks last to first,
+    ``dS`` (the cotangent of the chunk's outgoing state) f32 in VMEM
+    scratch; ``delta`` re-made from the saved incoming state ``S``:
+
+        d delta = a_qk^T do + ((Gamma_C / Gamma) k) dS'
+        dS      = Gamma_C dS' + (Gamma q)^T do - w^T d delta
+        du = d delta,  dw = -d delta S^T,  d a_qk = do delta^T (i >= j)
+
+    the products' operands in the compute dtype as the forward's; the
+    cotangents of ``Gamma q``, ``(Gamma_C / Gamma) k`` and ``Gamma_C``
+    taken back to q, k (summed over a key head's value heads) and to
+    ``log Gamma``."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    heads, r, c = gs_ref.shape
+    cd = q_ref.dtype
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_ref[...] = jnp.zeros(ds_ref.shape, f32)
+
+    row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    eye, lower = row == col, row >= col
+
+    def dot(a, b, dims=None):
+        if dims is None:
+            return jnp.dot(a, b, preferred_element_type=f32)
+        return lax.dot_general(a, b, dims, preferred_element_type=f32)
+
+    def head(h):
+        q, k, gs = q_ref[h].astype(f32), k_ref[h].astype(f32), gs_ref[h]
+        dq = jnp.zeros(q.shape, f32)
+        dk = jnp.zeros(k.shape, f32)
+        rows = []
+        for j in range(r):
+            d = _decay(gs[j:j + 1], eye)
+            gq, gk = q * d.gam, k * d.rest
+            gqb, gkb = gq.astype(cd), gk.astype(cd)
+            s, w, do = st_ref[h, j], w_ref[h, j], do_ref[h, j]
+            sb = s.astype(cd)
+            db = (u_ref[h, j] - dot(w, sb)).astype(cd)
+            ds1 = ds_ref[h, j]
+            ds1b = ds1.astype(cd)
+            ddelta = dot(a_ref[h, j], do, _TN) + dot(gkb, ds1b)
+            ddb = ddelta.astype(cd)
+            du_ref[h, j] = ddelta.astype(du_ref.dtype)
+            dw_ref[h, j] = (-dot(ddb, sb, _NT)).astype(dw_ref.dtype)
+            da_ref[h, j] = jnp.where(lower, dot(do, db, _NT),
+                                     0.0).astype(da_ref.dtype)
+            ds_ref[h, j] = ds1 * d.end + dot(gqb, do, _TN) \
+                - dot(w, ddb, _TN)
+            dgq, dgk = dot(do, sb, _NT), dot(db, ds1b, _NT)
+            dq = dq + dgq * d.gam
+            dk = dk + dgk * d.rest
+            # log Gamma_i moves Gamma_i q_i (+), Gamma_C / Gamma_i k_i
+            # (-); log Gamma_C moves those of every k_i (+) and Gamma_C
+            pk = jnp.sum(dgk * gk, axis=1, keepdims=True)
+            dcol = jnp.sum(dgq * gq, axis=1, keepdims=True) - pk
+            dlast = d.end * jnp.sum(jnp.sum(s * ds1, axis=1, keepdims=True),
+                                    axis=0, keepdims=True) \
+                + jnp.sum(pk, axis=0, keepdims=True)
+            rows.append(jnp.sum(jnp.where(eye, dcol, 0.0), axis=0,
+                                keepdims=True)
+                        + jnp.where(eye[c - 1:c], dlast, 0.0))
+        dq_ref[h] = dq.astype(dq_ref.dtype)
+        dk_ref[h] = dk.astype(dk_ref.dtype)
+        dgs_ref[h] = jnp.concatenate(rows, axis=0)
+
+    _over_heads(heads, unroll, head)
+
+
+def _scan_call(kernel, name, tiles: ScanTiles, u, ins, outs, interpret,
+               *args):
+    """``kernel`` over the grid (rows, key head groups, chunks) of
+    ``[n, rows, Hk, ...]`` arrays — the chunk axis in order (forward)
+    or last to first (backward, ``name`` ``*_bwd``) — with the f32
+    state of the step's heads in VMEM scratch; ``ins`` / ``outs``: the
+    trailing block shape of each argument / (result, its shape)."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    n, b, hk, r = u.shape[:4]
+    back = name.endswith("_bwd")
+
+    def spec(tail):
+        return pl.BlockSpec(
+            (None, None, tiles.heads) + tail,
+            lambda i, h, t: ((n - 1 - t) if back else t, i, h)
+            + (0,) * len(tail))
+
+    dk = args[1].shape[-1]
+    dv = u.shape[-1]
+    return pl.pallas_call(
+        functools.partial(kernel, unroll=tiles.unroll),
+        grid=(b, hk // tiles.heads, n),
+        in_specs=[spec(t) for t in ins],
+        out_specs=[spec(t) for t, _ in outs],
+        out_shape=[o for _, o in outs],
+        scratch_shapes=[pltpu.VMEM((tiles.heads, r, dk, dv),
+                                   jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=SCAN_VMEM_LIMIT),
+        interpret=interpret, name=name)(*args)
+
+
+def _scan_forward(u, w, a, gs, q, k, tiles, save, interpret):
+    """o, and with ``save`` each chunk's incoming state (f32)."""
+    import jax
+    import jax.numpy as jnp
+    r, c, dv = u.shape[3:]
+    dk = w.shape[-1]
+    shape = jax.ShapeDtypeStruct
+    outs = [((r, c, dv), shape(u.shape, q.dtype))]
+    if save:
+        outs.append(((r, dk, dv),
+                     shape(u.shape[:4] + (dk, dv), jnp.float32)))
+    return _scan_call(
+        _scan_fwd_kernel, "gdn_scan_fwd", tiles, u,
+        [(r, c, dv), (r, c, dk), (r, c, c), (r, c), (c, dk), (c, dk)],
+        outs, interpret, u, w, a, gs, q, k)
+
+
+def _scan_backward(u, w, a, gs, q, k, states, do, tiles, interpret):
+    """(du, dw, da_qk, dgsum, dq, dk)."""
+    import jax
+    r, c, dv = u.shape[3:]
+    dk = w.shape[-1]
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
+    return _scan_call(
+        _scan_bwd_kernel, "gdn_scan_bwd", tiles, u,
+        [(r, c, dv), (r, c, dk), (r, c, c), (r, c), (c, dk), (c, dk),
+         (r, dk, dv), (r, c, dv)],
+        [((r, c, dv), like(u)), ((r, c, dk), like(w)),
+         ((r, c, c), like(a)), ((r, c), like(gs)), ((c, dk), like(q)),
+         ((c, dk), like(k))],
+        interpret, u, w, a, gs, q, k, states, do)
+
+
+@functools.lru_cache(maxsize=None)
+def _scan_op():
+    """The differentiable scan, made once.  The VJP's forward keeps
+    each chunk's incoming state for the backward — unless nothing
+    reads them (a forward whose residuals are dropped, as a recomputed
+    entry's first forward is): ``custom_dce`` then runs the kernel that
+    writes ``o`` alone.  Each kernel call is a ``jax.jit`` of its own,
+    so that a layer of the same shapes, or the same layer traced again
+    (the plan's walks, the step, the re-run), reuses its traced kernel
+    body instead of tracing it anew."""
+    import jax
+    from jax.experimental.custom_dce import custom_dce
+
+    forward = jax.jit(_scan_forward, static_argnums=(6, 7, 8))
+    backward = jax.jit(_scan_backward, static_argnums=(8, 9))
+
+    @functools.partial(custom_dce, static_argnums=(6, 7))
+    def saving(u, w, a, gs, q, k, tiles, interpret):
+        return tuple(forward(u, w, a, gs, q, k, tiles, True, interpret))
+
+    @saving.def_dce
+    def _(tiles, interpret, used, u, w, a, gs, q, k):
+        o, *states = forward(u, w, a, gs, q, k, tiles, used[1], interpret)
+        return o, (states[0] if used[1] else None)
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+    def op(u, w, a, gs, q, k, tiles, interpret):
+        return forward(u, w, a, gs, q, k, tiles, False, interpret)[0]
+
+    def fwd(u, w, a, gs, q, k, tiles, interpret):
+        o, states = saving(u, w, a, gs, q, k, tiles, interpret)
+        return o, (u, w, a, gs, q, k, states)
+
+    def bwd(tiles, interpret, saved, do):
+        return tuple(backward(*saved, do, tiles, interpret))
+
+    op.defvjp(fwd, bwd)
+    return op
+
+
+def chunk_scan(u: Any, w: Any, a: Any, gsum: Any, qc: Any, kc: Any,
+               tiles: ScanTiles, interpret: bool = False):
+    """``ops/deltanet.py`` ``scan_of``'s XLA scan by the kernels: the
+    chunk products ``u`` (f32), ``w``, ``a_qk`` (compute dtype) ``[n,
+    rows, Hk, r, C, d]``, ``gsum`` (f32) ``[n, rows, Hk, r, C]``, qc,
+    kc ``[n, rows, Hk, C, dk]`` (compute dtype) -> o ``[n, rows, Hk,
+    r, C, dv]`` in the compute dtype.  Differentiable in all six
+    (``jax.custom_vjp``: the residuals are these and each chunk's
+    incoming state, the backward is the second kernel)."""
+    if qc.shape[2] % tiles.heads or tiles.heads % tiles.unroll:
+        raise ValueError(f"{qc.shape[2]} key heads do not tile as {tiles}")
+    return _scan_op()(u, w, a, gsum, qc, kc, tiles, interpret)
