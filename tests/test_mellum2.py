@@ -339,7 +339,7 @@ def test_attention_path_and_share_are_journaled_with_the_new_fields():
     assert share["shared"] is False and share["experts_held"] == 2
     old = moe.MoE(None, name="m")
     assert "s_mix" in old.param_names and "s_mix" not in m.param_names
-    assert old._share(64, 64)["shared"] is True
+    assert old._share(64, 64)[0]["shared"] is True
 
 
 def test_moe_without_a_shared_expert_traces_no_shared_op():
@@ -402,13 +402,20 @@ def test_the_cells_dispatch_block_and_load_are_sized_from_shapes():
     block = moe.block_tokens(tokens, 8, 2304)
     assert (block, block * 8, tokens // block) == (4096, 32768, 8)
     assert tokens * 8 // 64 == 4096
-    # the grouped products' tiles follow the parent's rule, here ...
-    assert moe.grouped_path("tpu", block * 8, 2304, 896) == {
-        "form": "gmm", "tiles": {"in": (512, 1024, 512),
-                                 "out": (512, 512, 1024)}}
-    # ... and for the other configuration
-    assert moe.grouped_path("tpu", 40960, 2048, 512)["tiles"] == {
-        "in": (512, 1024, 512), "out": (512, 512, 1024)}
+    # every call of the grouped products takes tiles that pad nothing,
+    # here: 1 152 on the 2 304 side, 896 on the 896 side ...
+    path = moe.grouped_path("tpu", block * 8, 2304, 896)
+    assert path == {"form": "gmm", "tiles": moe.GroupedTiles(2)}
+    got = moe.call_tiles(path["tiles"], 12288, 2304, 896, moe.FOLD_TOKENS)
+    assert got["tiles"]["in"]["forward"] == (512, 1152, 896)
+    assert got["tiles"]["out"]["forward"] == (512, 896, 1152)
+    assert got["tiles"]["fold"] == (512, 512, 1152)
+    assert {p for c in ("in", "out") for p in got["padding"][c].values()} \
+        == {1.0} and got["padding"]["fold"] == 1.0
+    # ... and for the other configuration, whose forward keeps its tiles
+    tiles = moe.grouped_path("tpu", 40960, 2048, 512)["tiles"]
+    assert tiles(4096, 2048, 512) == (512, 1024, 512)
+    assert tiles(4096, 512, 2048) == (512, 512, 1024)
 
 
 # -- the whole model through StandardWorkflow -----------------------------------------------

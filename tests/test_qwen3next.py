@@ -689,11 +689,12 @@ def test_capacity_is_read_from_shapes(block, top_k, held, total, unit,
 
 def test_the_tiny_preset_keeps_the_whole_buffers():
     unit, _, _, x = _unit("moe")
-    assert unit._share(ROWS * T, HIDDEN)["capacity"] is None
-    assert unit._share(ROWS * T, HIDDEN, batched=True)["capacity"] is None
+    assert unit._share(ROWS * T, HIDDEN)[0]["capacity"] is None
+    assert unit._share(ROWS * T, HIDDEN, batched=True)[0][
+        "capacity"] is None
     compact, _, _, _ = _unit("moe", **COMPACT)
-    assert compact._share(ROWS * T, HIDDEN)["capacity"] == 192
-    assert compact._share(ROWS * T, HIDDEN, batched=True)[
+    assert compact._share(ROWS * T, HIDDEN)[0]["capacity"] == 192
+    assert compact._share(ROWS * T, HIDDEN, batched=True)[0][
         "capacity"] is None
 
 

@@ -227,7 +227,10 @@ if which == "moe":
     assert (unit.share["form"], unit.share["rows"], unit.share["blocks"],
             unit.share["capacity"], unit.share["shared"]) == (
         "gmm", 32768, 8, 12288, False), unit.share
-    assert unit.share["tiles"]["in"] == (512, 1024, 512), unit.share
+    assert unit.share["tiles"]["in"] == {
+        "forward": (512, 1152, 896), "input_grad": (512, 896, 1152),
+        "weight_grad": (512, 1152, 896)}, unit.share
+    assert unit.share["padding"]["fold"] == 1.0, unit.share
     assert hlo.count("tpu_custom_call") >= 11
     assert "bf16[32768,896]" not in hlo     # no whole-buffer product
 else:

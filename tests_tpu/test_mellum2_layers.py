@@ -92,8 +92,9 @@ def test_gmm_experts_at_the_cells_buffers_match_ragged_dot(tpu_device):
     fast = jax.jit(both)(params, x, err)
     assert (unit.share["form"], unit.share["rows"], unit.share["blocks"],
             unit.share["shared"]) == ("gmm", 32768, 8, False)
-    assert unit.share["tiles"] == {"in": (512, 1024, 512),
-                                   "out": (512, 512, 1024)}
+    assert unit.share["tiles"]["in"]["forward"] == (512, 1152, 896)
+    assert unit.share["tiles"]["out"]["forward"] == (512, 896, 1152)
+    assert unit.share["padding"]["fold"] == 1.0
     plain = moe.grouped_path
     try:
         moe.grouped_path = lambda *a, **k: {"form": "ragged_dot",
@@ -112,6 +113,48 @@ def test_gmm_experts_at_the_cells_buffers_match_ragged_dot(tpu_device):
     assert 50_000 < int(got["expert_rows"].sum()) < 80_000
     assert 2_000 < int(got["expert_rows"].min()) \
         and int(got["expert_rows"].max()) < 8_000
+
+
+def test_a_block_under_each_calls_own_tiles_matches_ragged_dot(tpu_device):
+    """One dispatch block of 4 096 tokens at the cell's shapes, forward
+    + backward, through the compact buffers: every grouped-product call
+    and the fold under tiles from its own shape (1 152 x 896, 896 x
+    1 152, no call padded) against ``ragged_dot`` everywhere, within
+    the gap the whole layer holds."""
+    flat = [c for e in mellum2_layers() for c in e.get("layers", [e])]
+    fw = dict(next(c for c in flat if c["type"] == "moe")["->"])
+    fw.pop("weights_stddev")
+    unit = forward_registry["moe"][0](None, name="u_moe", **fw)
+    unit.device = tpu_device
+    x = jax.random.normal(jax.random.key(5), (1, 4096, 2304),
+                          jnp.bfloat16)
+    params = {n: (0.02 * jax.random.normal(
+        jax.random.fold_in(jax.random.key(6), i), s)).astype(jnp.bfloat16)
+        for i, (n, s) in enumerate(unit.param_shapes(x.shape).items())}
+    err = jax.random.normal(jax.random.key(7), x.shape, jnp.bfloat16)
+
+    def both(params, x, err):
+        out, back = jax.vjp(unit.forward, params, x)
+        return (out,) + back(err)
+
+    fast = jax.jit(both)(params, x, err)
+    assert (unit.share["form"], unit.share["blocks"],
+            unit.share["capacity"]) == ("gmm", 1, 12288)
+    assert {p for c in ("in", "out")
+            for p in unit.share["padding"][c].values()} == {1.0}
+    assert unit.share["tiles"]["fold"] == (512, 512, 1152)
+    plain = moe.grouped_path
+    try:
+        moe.grouped_path = lambda *a, **k: {"form": "ragged_dot",
+                                            "reason": "test"}
+        slow = jax.jit(lambda *a: both(*a))(params, x, err)
+    finally:
+        moe.grouped_path = plain
+    assert (unit.share["form"], unit.share["capacity"]) == (
+        "ragged_dot", 12288)
+    for a, b in zip(jax.tree.leaves(fast), jax.tree.leaves(slow)):
+        assert np.isfinite(np.asarray(a, np.float32)).all()
+        assert _gap(a, b) <= 2e-2, _gap(a, b)
 
 
 def test_compact_buffers_match_the_whole_buffers(tpu_device):

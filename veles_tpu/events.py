@@ -116,8 +116,12 @@ EV_ATTN_PATH = _ev("attn.path")
 #: fit them: the pairs it expects x ``DISPATCH_HEADROOM``, from shapes
 #: alone; None: the ``rows``-row buffers), ``form`` of the grouped
 #: products (``gmm``: the Pallas grouped matmul that ships with jax /
-#: ``ragged_dot``) and its ``reason`` / ``tiles``, ``shared`` (whether
-#: the layer has a shared expert)
+#: ``ragged_dot``) and its ``reason`` / ``tiles`` (a kernel call's
+#: (rows, contraction, columns): ``in`` and ``out`` products' by
+#: ``forward`` / ``input_grad`` / ``weight_grad``, and the segment
+#: sum's ``fold`` where ``capacity`` is set) with each call's
+#: ``padding`` (its tiles' MXU work over the product's own; 1.0:
+#: nothing padded), ``shared`` (whether the layer has a shared expert)
 EV_MOE_SHARE = _ev("moe.share")
 #: what the routing of the FIRST firing's first minibatch put on the
 #: held experts, a layer — read by a forward-only probe at set-up,

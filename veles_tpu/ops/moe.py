@@ -56,7 +56,15 @@ platform and shapes and journaled with the share (``moe.share``):
 ``gmm`` — on a TPU where the shapes tile, the Pallas grouped matmul
 that ships with jax (``jax.experimental.pallas.ops.tpu.megablox``),
 which visits only the tiles that hold rows — and ``ragged_dot``
-(``jax.lax.ragged_dot``) everywhere else.
+(``jax.lax.ragged_dot``) everywhere else.  Under ``gmm`` every kernel
+call — a product's forward, its input gradient (contraction and
+columns trade places), its weight gradient (``tgmm``), and the
+segment sum's — takes its contraction and column tiles from its own
+``(m, k, n)`` (:class:`GroupedTiles`): the fewest padded elements a
+side, then the fewest grid steps, within the scoped VMEM the kernels
+get; rows are ``GMM_ROWS`` a tile.  The share journals each call's
+tiles and ``padding`` (the MXU work of those tiles over the product's
+own).
 
 Device ops carry ``moe/router``, ``moe/dispatch``, ``moe/experts``,
 ``moe/shared``.
@@ -64,9 +72,10 @@ Device ops carry ``moe/router``, ``moe/dispatch``, ``moe/experts``,
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from functools import partial
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 from veles_tpu import events, telemetry
 from veles_tpu.ops.sequence import SequenceUnit, under_vmap
@@ -84,6 +93,104 @@ DISPATCH_HEADROOM = 1.5
 #: tokens whose rows one group of the compact combine's grouped product
 #: sums
 FOLD_TOKENS = 512
+#: scoped VMEM a grouped-product kernel may take: megablox sets no
+#: ``vmem_limit_bytes``, so it is the compiler's default scoped limit,
+#: 16 MiB on a v5e (its refusal of a kernel that asks more reads
+#: "limit 16.00M"; ``scripts/moe_kernel_chip.py`` reads it on the chip)
+GMM_VMEM_BYTES = 16 << 20
+#: the least tile of a side, where the side is that long: a side of 11
+#: x 128 never falls to 128-wide steps
+GMM_LEAST_TILE = 512
+#: the three calls of one grouped product: its forward, its input
+#: gradient (``transpose_rhs``: contraction and columns trade places)
+#: and its weight gradient (``tgmm``)
+GMM_CALLS = ("forward", "input_grad", "weight_grad")
+
+
+def gmm_vmem_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """Scoped VMEM one grid step of either kernel takes, at most: its
+    three blocks ``(tm, tk)``, ``(tk, tn)``, ``(tm, tn)`` double-
+    buffered at ``itemsize``, and an f32 working copy of each — the
+    accumulator (``(tm, tn)`` in ``gmm``, ``(tk, tn)`` in ``tgmm``) is
+    one, the product's and the masked loads' values the others.  Above
+    the least limit each kernel compiles under, for every tiling tried
+    (11.2 / 13.1 MiB at 512 x 1 152 x 896 in bf16, where this reads
+    15.9)."""
+    return (2 * itemsize + 4) * (tm * tk + tk * tn + tm * tn)
+
+
+def _side_tiles(d: int):
+    """A side's candidate tiles: multiples of ``LANES`` from
+    ``min(d, GMM_LEAST_TILE)`` up to the side rounded up to them."""
+    least = -(-min(d, GMM_LEAST_TILE) // LANES) * LANES
+    return range(least, -(-d // LANES) * LANES + 1, LANES)
+
+
+def _padded(d: int, t: int) -> int:
+    return -(-d // t) * t
+
+
+@dataclasses.dataclass(frozen=True)
+class GroupedTiles:
+    """The tiling of one grouped-product kernel call from its own
+    ``(m, k, n)`` — what megablox's ``gmm`` and ``tgmm`` call when
+    handed a function: ``GMM_ROWS`` rows, and the ``(tk, tn)`` of the
+    fewest padded elements ``ceil(k / tk) tk x ceil(n / tn) tn``, then
+    the fewest grid steps, then the widest columns, among tiles of
+    whole lanes no shorter than ``min(side, GMM_LEAST_TILE)`` whose
+    step fits ``GMM_VMEM_BYTES`` (:func:`gmm_vmem_bytes`) at operands of
+    ``itemsize`` bytes.  Hashable, equal by value: the kernels' jit
+    takes it as a static argument."""
+    itemsize: int = 2
+
+    def __call__(self, m: int, k: int, n: int) -> Tuple[int, int, int]:
+        del m       # rows are GMM_ROWS a tile whatever the buffer
+        _, tk, tn = min(
+            ((_padded(k, tk) * _padded(n, tn),
+              -(-k // tk) * -(-n // tn), -(-n // tn)), tk, tn)
+            for tk in _side_tiles(k) for tn in _side_tiles(n)
+            if gmm_vmem_bytes(GMM_ROWS, tk, tn, self.itemsize)
+            <= GMM_VMEM_BYTES)
+        return GMM_ROWS, tk, tn
+
+
+def padding(tiles, k: int, n: int) -> float:
+    """The MXU work of ``tiles`` over a ``[·, k] x [k, n]`` product's
+    own, rows aside (1.0: nothing padded)."""
+    return _padded(k, tiles[1]) * _padded(n, tiles[2]) / float(k * n)
+
+
+def product_calls(width: int, inner: int):
+    """``(product, call, k, n)`` of every kernel call of the experts'
+    two products: ``in`` (gate and up, ``[·, width] x [width, inner]``)
+    and ``out`` (down, ``[·, inner] x [inner, width]``)."""
+    for product, (k, n) in (("in", (width, inner)),
+                            ("out", (inner, width))):
+        for call, shape in zip(GMM_CALLS, ((k, n), (n, k), (k, n))):
+            yield (product, call) + shape
+
+
+def fold_tile(tokens: int) -> int:
+    """Tokens a group of the segment sum (:func:`token_sums`) sums."""
+    return FOLD_TOKENS if tokens % FOLD_TOKENS == 0 else tokens
+
+
+def call_tiles(tiles: GroupedTiles, rows: int, width: int, inner: int,
+               fold_tokens: Optional[int] = None) -> Dict[str, Any]:
+    """The tiles ``tiles`` gives each kernel call of the experts'
+    products over ``rows``-row buffers and, given ``fold_tokens``, the
+    segment sum's (``[fold_tokens, rows] x [rows, width]``), and each
+    call's ``padding``: numbers alone, for the journal."""
+    calls: Dict[str, Any] = {"in": {}, "out": {}}
+    pads: Dict[str, Any] = {"in": {}, "out": {}}
+    for product, call, k, n in product_calls(width, inner):
+        calls[product][call] = tiles(rows, k, n)
+        pads[product][call] = round(padding(calls[product][call], k, n), 4)
+    if fold_tokens is not None:
+        calls["fold"] = (GMM_ROWS, fold_tokens,
+                         tiles(rows, fold_tokens, width)[2])
+        pads["fold"] = round(padding(calls["fold"], fold_tokens, width), 4)
+    return {"tiles": calls, "padding": pads}
 
 
 def block_tokens(tokens: int, top_k: int, width: int,
@@ -98,20 +205,19 @@ def block_tokens(tokens: int, top_k: int, width: int,
 
 
 def grouped_path(platform: str, rows: int, width: int, inner: int,
-                 batched: bool = False) -> Dict[str, Any]:
+                 batched: bool = False, itemsize: int = 2
+                 ) -> Dict[str, Any]:
     """Which form the grouped products take, from what the code
-    observes; ``tiles`` are (rows, contraction, columns) of the two
-    shapes of product."""
+    observes; under ``gmm``, ``tiles``: the ONE chooser every kernel
+    call asks for its (rows, contraction, columns) tiles, from that
+    call's own ``(m, k, n)`` (operands of ``itemsize`` bytes)."""
     if platform != "tpu":
         return {"form": "ragged_dot", "reason": "platform"}
     if batched:
         return {"form": "ragged_dot", "reason": "batched"}
     if rows % GMM_ROWS or width % LANES or inner % LANES:
         return {"form": "ragged_dot", "reason": "shapes"}
-    return {"form": "gmm",
-            "tiles": {"in": (GMM_ROWS, min(width, 1024), min(inner, 512)),
-                      "out": (GMM_ROWS, min(inner, 512),
-                              min(width, 1024))}}
+    return {"form": "gmm", "tiles": GroupedTiles(itemsize)}
 
 
 def dispatch_capacity(rows: int, experts_held: int, experts_total: int,
@@ -134,7 +240,9 @@ def dispatch_capacity(rows: int, experts_held: int, experts_total: int,
 def grouped_matmul(lhs, rhs, group_sizes, tiles=None):
     """``lhs[rows of group e] @ rhs[e]``: lhs ``[m, k]`` sorted by
     group, rhs ``[groups, k, n]``; rows beyond the groups come out
-    zero.  ``tiles`` given: the Pallas kernel; else ``lax.ragged_dot``.
+    zero.  ``tiles`` given (a :class:`GroupedTiles`, asked by each of
+    the forward's and the backward's kernel calls): the Pallas kernel;
+    else ``lax.ragged_dot``.
 
     The kernel never writes the tiles it does not visit — forward (the
     product's rows beyond the groups) and backward (the same rows of
@@ -150,7 +258,7 @@ def grouped_matmul(lhs, rhs, group_sizes, tiles=None):
         out = lax.ragged_dot(lhs, rhs, group_sizes)
     else:
         from jax.experimental.pallas.ops.tpu.megablox import ops
-        out = ops.gmm(lhs, rhs, group_sizes, lhs.dtype, tuple(tiles))
+        out = ops.gmm(lhs, rhs, group_sizes, lhs.dtype, tiles)
     return jnp.where(live, out, jnp.zeros((), out.dtype))
 
 
@@ -226,12 +334,13 @@ def token_sums(y, pairs, by_token, tokens: int, top_k: int, tiles=None):
     token order (``by_token``: the argsort of ``pairs``), then a one-hot
     (row -> its token inside a tile of ``FOLD_TOKENS`` tokens) is
     multiplied into them, a group a tile of tokens: 1.0 x the row
-    accumulated in f32, so the sum is exact.  ``tiles`` given: the
-    Pallas kernel (it visits every group, so every tile of the result
-    is written); else ``lax.ragged_dot_general``."""
+    accumulated in f32, so the sum is exact.  ``tiles`` given (a
+    :class:`GroupedTiles`: the column tile is its choice for this
+    call): the Pallas kernel (it visits every group, so every tile of
+    the result is written); else ``lax.ragged_dot_general``."""
     import jax.numpy as jnp
     from jax import lax
-    tile = FOLD_TOKENS if tokens % FOLD_TOKENS == 0 else tokens
+    tile = fold_tile(tokens)
     token = _take(pairs, by_token) // top_k
     y = _take(y, by_token)
     sizes = jnp.sum(token[:, None] // tile == jnp.arange(tokens // tile),
@@ -247,9 +356,9 @@ def token_sums(y, pairs, by_token, tokens: int, top_k: int, tiles=None):
         # (``ops.backend``: the module of the kernels behind ``ops.gmm``)
         from jax.experimental.pallas.ops.tpu.megablox import ops
         onehot = jnp.arange(tile)[:, None] == token % tile
-        out = ops.backend.tgmm(onehot.astype(y.dtype), y, sizes,
-                               jnp.float32,
-                               (GMM_ROWS, tile, tiles["out"][2]))
+        out = ops.backend.tgmm(
+            onehot.astype(y.dtype), y, sizes, jnp.float32,
+            (GMM_ROWS, tile, tiles(y.shape[0], tile, y.shape[1])[2]))
     return out.reshape(tokens, -1)
 
 
@@ -335,32 +444,43 @@ class MoE(SequenceUnit):
 
     # -- the share, and how it is laid out --------------------------------
 
-    def _share(self, tokens: int, width: int, batched: bool = False
-               ) -> Dict[str, Any]:
-        """How ``tokens`` tokens of a row are dispatched, journaled
-        (``moe.share``) whenever it differs from the last one."""
+    def _share(self, tokens: int, width: int, batched: bool = False,
+               itemsize: int = 2
+               ) -> Tuple[Dict[str, Any], Optional[GroupedTiles]]:
+        """How ``tokens`` tokens of a row are dispatched — journaled
+        (``moe.share``) whenever it differs from the last one; under
+        ``gmm`` with each kernel call's ``tiles`` and ``padding`` — and
+        the chooser those tiles come from (None: ``ragged_dot``)."""
         block = block_tokens(tokens, self.top_k, width)
         rows = block * self.top_k
         path = grouped_path(self.platform(), rows, width,
-                            self.expert_size, batched)
+                            self.expert_size, batched, itemsize)
+        tiles = path.pop("tiles", None)
+        capacity = dispatch_capacity(
+            rows, self.experts_held, self.experts_total,
+            GMM_ROWS if tiles else SUBLANES, batched)
         share = {"experts_total": self.experts_total,
                  "experts_held": self.experts_held,
                  "first_held": self.first_held, "top_k": self.top_k,
                  "rows": rows, "blocks": tokens // block,
-                 "capacity": dispatch_capacity(
-                     rows, self.experts_held, self.experts_total,
-                     GMM_ROWS if path["form"] == "gmm" else SUBLANES,
-                     batched),
+                 "capacity": capacity,
                  "shared": bool(self.shared_size), **path}
+        if tiles:
+            share.update(call_tiles(
+                tiles, capacity or rows, width, self.expert_size,
+                capacity and fold_tile(block)))
         if share != self.share:
             self.share = share
             telemetry.event(events.EV_MOE_SHARE, unit=self.name, **share)
-        return share
+        return share, tiles
 
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device=device, **kwargs)
+        from veles_tpu.ops.batching import resolve_compute_dtype
         shape = self.input.shape
-        self._share(int(shape[0]) * int(shape[1]), int(shape[2]))
+        self._share(int(shape[0]) * int(shape[1]), int(shape[2]),
+                    itemsize=resolve_compute_dtype(None,
+                                                   self.device).itemsize)
 
     # -- pure compute ------------------------------------------------------
 
@@ -392,15 +512,13 @@ class MoE(SequenceUnit):
         import jax
         import jax.numpy as jnp
         with jax.named_scope(events.SCOPE_MOE_EXPERTS):
-            t_in, t_out = (tiles["in"], tiles["out"]) if tiles \
-                else (None, None)
-            gate = grouped_matmul(rows, params["w_gate"], sizes, t_in)
-            up = grouped_matmul(rows, params["w_up"], sizes, t_in)
+            gate = grouped_matmul(rows, params["w_gate"], sizes, tiles)
+            up = grouped_matmul(rows, params["w_up"], sizes, tiles)
             # the routing weight on the narrow side of the down product
             act = (jax.nn.silu(gate.astype(jnp.float32))
                    * up.astype(jnp.float32)
                    * weight[:, None]).astype(rows.dtype)
-            return grouped_matmul(act, params["w_down"], sizes, t_out)
+            return grouped_matmul(act, params["w_down"], sizes, tiles)
 
     def _routed_block(self, params, tiles, x, top_i, top_w):
         """One block: x ``[n, hidden]``, its routing ``[n, top_k]`` ->
@@ -512,14 +630,15 @@ class MoE(SequenceUnit):
         b, t, h = x.shape
         tokens = x.reshape(b * t, h)
         top_i, top_w = self.route(params, tokens)
-        share = self._share(b * t, h, under_vmap(x))
+        share, tiles = self._share(b * t, h, under_vmap(x),
+                                   x.dtype.itemsize)
         n, k = share["blocks"], self.top_k
         if share["capacity"] is None:
             block = jax.checkpoint(
-                partial(self._routed_block, params, share.get("tiles")))
+                partial(self._routed_block, params, tiles))
         else:
             block = partial(
-                self._capped_block(share.get("tiles"), share["capacity"]),
+                self._capped_block(tiles, share["capacity"]),
                 {name: params[name] for name in self.ROUTED[1:]})
         if n == 1:
             routed = block(tokens, top_i, top_w)
@@ -558,7 +677,7 @@ class MoE(SequenceUnit):
         b, t, h = x.shape
         top_i, _ = self.route(params, x.reshape(b * t, h))
         key, held = self._held(top_i)
-        share = self._share(b * t, h, under_vmap(x))
+        share, _ = self._share(b * t, h, under_vmap(x), x.dtype.itemsize)
         per_block = jnp.sum(held.reshape(share["blocks"], -1), axis=1)
         return {"choice": top_i.reshape(b, t, self.top_k),
                 "blocks": share["blocks"],
