@@ -603,21 +603,32 @@ def test_model_file_trains_and_the_loss_falls():
 
 # -- the configurations that share the changed code ------------------------------------------
 
-#: sha256 of the tiny train step's jaxpr (addresses blanked) as the
-#: commit before this issue traces it
+#: sha256 of the tiny train step's jaxpr as the commit before the
+#: ``mlp/swiglu`` scope traces it (addresses blanked, the padding before each name
+#: stack collapsed, the ``mlp/swiglu`` scope the pinned step predates
+#: taken out of the name stacks: :func:`_step_text`)
 PARENT_STEP = {
-    "qwen3next": "5d16326e828777f50fd9291738f8078c"
-                 "6645693fdd0735006401612d70aa24e8",
-    "evabyte": "f34ee85d4420cc8b3ba93ac870ded38e"
-               "4fa6277942f42730cc973504b7decc7c",
+    "qwen3next": "7779767606e3a0feea4642503dcb3ba8"
+                 "be0cb141a2d6762bcf0ec449647edfcb",
+    "evabyte": "29a2dece6b8b44029e6e8de750eb9cf5"
+               "0e595748f69a5e9124659783dff949ea",
 }
+
+
+def _step_text(text: str) -> str:
+    """A step's jaxpr as :data:`PARENT_STEP` hashes it: the same ops
+    under the same name stacks, ``mlp/swiglu`` left out of them."""
+    text = re.sub(r"0x[0-9a-f]+", "0x", text)
+    text = re.sub(r"(transpose\()?jvp\(mlp/swiglu\)(\)?)(?:/([^\]]*))?\]",
+                  lambda m: f"{m[1] or ''}jvp({m[3] or ''}){m[2]}]", text)
+    return re.sub(r" +#", " #", text)
 
 
 @pytest.mark.parametrize("name", sorted(PARENT_STEP))
 def test_the_other_sequence_models_step_is_the_parents(name):
-    """``rope``, the attention core and ``moe`` changed under
-    ``qwen3next`` and ``evabyte``: the step each traces is, op for op,
-    the one it traced before."""
+    """``rope``, the attention core, ``moe`` and ``swiglu`` changed
+    under ``qwen3next`` and ``evabyte``: the step each traces is, op
+    for op, the one it traced before."""
     layers, t, vocab = {
         "qwen3next": (qwen3next.qwen3next_layers(**qwen3next.TINY),
                       qwen3next.TINY["seq_len"],
@@ -636,6 +647,5 @@ def test_the_other_sequence_models_step_is_the_parents(name):
         w.loader.original_data.unmap(), fused._target_store(), idx,
         mask, fused._lr_rates_array(idx.shape[0]),
         fused._rng_counter).pretty_print(name_stack=True)
-    text = re.sub(r"0x[0-9a-f]+", "0x", text)
-    assert hashlib.sha256(text.encode()).hexdigest() \
+    assert hashlib.sha256(_step_text(text).encode()).hexdigest() \
         == PARENT_STEP[name]

@@ -102,9 +102,10 @@ EV_GDN_PATH = _ev("gdn.path")
 #: of queries at a time), ``reason`` where it is ``xla`` (``platform`` /
 #: ``batched`` / ``head_size`` / ``row``), ``tiles``, ``window`` (keys
 #: a query reads; None: every key up to itself), ``rope`` (``default``
-#: / ``yarn``) and, under the kernel form, ``kv_blocks``: the most key
-#: blocks a query block visits; once a unit at ``initialize``, again
-#: only where a later trace must differ
+#: / ``yarn``), ``rotary`` (elements of a head RoPE rotates), ``gate``
+#: (``per_head`` / ``elementwise`` / None) and, under the kernel form,
+#: ``kv_blocks``: the most key blocks a query block visits; once a unit
+#: at ``initialize``, again only where a later trace must differ
 EV_ATTN_PATH = _ev("attn.path")
 #: the share of a mixture of experts a unit holds, once at
 #: ``initialize`` (``ops/moe.py``): ``unit``, ``experts_total``,
@@ -121,7 +122,8 @@ EV_ATTN_PATH = _ev("attn.path")
 #: ``forward`` / ``input_grad`` / ``weight_grad``, and the segment
 #: sum's ``fold`` where ``capacity`` is set) with each call's
 #: ``padding`` (its tiles' MXU work over the product's own; 1.0:
-#: nothing padded), ``shared`` (whether the layer has a shared expert)
+#: nothing padded), ``shared`` (whether the layer has a shared
+#: expert), ``routed_scale`` (the factor on the routed weights)
 EV_MOE_SHARE = _ev("moe.share")
 #: what the routing of the FIRST firing's first minibatch put on the
 #: held experts, a layer — read by a forward-only probe at set-up,
@@ -532,6 +534,12 @@ SCOPE_GDN_GATE_NORM = _scope("gdn/gate_norm")
 SCOPE_ATTN_CORE = _scope("attn/core")
 #: the same of a layer that has a window of keys
 SCOPE_ATTN_WINDOW = _scope("attn/window")
+#: the per-head output gate of ``attention``: its product and the
+#: sigmoid's product with the core's output
+SCOPE_ATTN_GATE = _scope("attn/gate")
+#: a dense SwiGLU MLP's gate and up products and its activation (the
+#: down projection is the ``dense`` that follows, outside it)
+SCOPE_MLP_SWIGLU = _scope("mlp/swiglu")
 #: mixture of experts: router (product, softmax, top-k); dispatch
 #: (sort, gather, combine); the grouped products over the held
 #: experts; the shared expert

@@ -13,12 +13,16 @@ query head h reading key head ``h // (nh / nkv)``; ``y = o *
 sigmoid(gate)``.  The out-projection is the ``dense`` layer that
 follows.  A row's whole ``[nh, T, T]`` scores never exist.
 
-``attention`` is the same without the gate and the norms: ``q = u Wq
-[T, nh, d]``, ``k, v [T, nkv, d]``; rotate-half RoPE over the whole
-head with the inverse frequencies and the scale of the layer's ``rope``
-specification (``ops/sequence.py`` ``rope_frequencies``: ``default`` or
-``yarn``); with ``window`` W a query at n reads the keys ``n - W < m
-<= n`` (W keys, its own among them), without one every ``m <= n``.
+``attention`` is the same without the norms: ``q = u Wq [T, nh, d]``,
+``k, v [T, nkv, d]``; rotate-half RoPE with the inverse frequencies and
+the scale of the layer's ``rope`` specification (``ops/sequence.py``
+``rope_frequencies``: ``default`` or ``yarn``) on the first ``rotary``
+elements of each head — the whole head unless the specification gives
+a ``partial_rotary_factor`` — the rest untouched and unscaled; with
+``window`` W a query at n reads the keys ``n - W < m <= n`` (W keys,
+its own among them), without one every ``m <= n``; with ``gate`` a
+sigmoid a head, ``y_h = sigmoid(u W_g)_h o_h`` (``W_g [hidden, nh]``,
+under ``attn/gate``), without one ``y = o``.
 
 Two forms of the core (scores, softmax, weighted sums), chosen by
 :func:`attention_path` from platform and shapes and journaled
@@ -152,13 +156,42 @@ def core_xla(q, k, v, block: int = XLA_BLOCK,
     return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
 
 
+def _gate_heads():
+    """``gated(o, gate)``: ``o [rows, T, heads, d]`` times ``gate [rows,
+    T, heads]`` (f32) a head, in o's dtype — the compute dtype, as the
+    core's output and the out-projection's input are; the backward's
+    ``d_o = dy gate`` likewise, ``d_gate = sum_d dy o`` accumulated in
+    f32.  In f32 the product's backward held f32 copies of o and of the
+    gate's broadcast, 1.1 GB each at 72 heads of four rows of 8 192:
+    the step a chip could not hold by 4.5 MB."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.custom_vjp
+    def gated(o, gate):
+        return o * gate.astype(o.dtype)[..., None]
+
+    def fwd(o, gate):
+        return gated(o, gate), (o, gate)
+
+    def bwd(res, dy):
+        o, gate = res
+        return (dy * gate.astype(dy.dtype)[..., None],
+                jnp.sum(dy.astype(jnp.float32) * o.astype(jnp.float32),
+                        -1))
+
+    gated.defvjp(fwd, bwd)
+    return gated
+
+
 class GroupedQueryAttention(SequenceUnit):
     """What the attention layer types share: query heads over key
     heads, the journaled path, and the ONE core — causal, under
     ``window`` keys where the layer has a window."""
 
-    #: the layer's RoPE law as the journal names it
+    #: the layer's RoPE law and output gate as the journal names them
     rope_kind = "default"
+    gate_kind: Optional[str] = None
 
     def __init__(self, workflow=None, n_heads: int = 4,
                  n_kv_heads: int = 2, head_size: int = 16,
@@ -169,6 +202,8 @@ class GroupedQueryAttention(SequenceUnit):
                              f"{n_kv_heads} key heads")
         self.n_heads, self.n_kv_heads = n_heads, n_kv_heads
         self.head_size, self.window = head_size, window
+        #: elements of a head that RoPE rotates
+        self.rotary = head_size
         #: the last :func:`attention_path` journaled
         self.path: Dict[str, Any] = {}
 
@@ -183,7 +218,8 @@ class GroupedQueryAttention(SequenceUnit):
         if path != self.path:
             self.path = path
             telemetry.event(events.EV_ATTN_PATH, unit=self.name,
-                            rope=self.rope_kind, **path)
+                            rope=self.rope_kind, rotary=self.rotary,
+                            gate=self.gate_kind, **path)
         return path
 
     def initialize(self, device=None, **kwargs) -> None:
@@ -222,12 +258,13 @@ class GatedAttention(GroupedQueryAttention):
 
     matrix_names = ("wq", "wk", "wv")
     param_names = ("wq", "wk", "wv", "q_gain", "k_gain")
+    gate_kind = "elementwise"
 
     def __init__(self, workflow=None, rotary_size: int = 4,
                  rope_theta: float = 1e7, eps: float = 1e-6,
                  **kwargs: Any) -> None:
         super().__init__(workflow, **kwargs)
-        self.rotary_size = rotary_size
+        self.rotary = rotary_size
         self.rope_theta, self.eps = rope_theta, eps
 
     def param_shapes(self, input_shape):
@@ -253,7 +290,7 @@ class GatedAttention(GroupedQueryAttention):
 
         def normed(a, gain, scale=1.0):
             af = rms_norm(a, params[gain], self.eps)
-            return (rope(af, self.rope_theta, self.rotary_size)
+            return (rope(af, self.rope_theta, self.rotary)
                     * scale).astype(x.dtype)
 
         # a head's columns of wq are its query, then its gate: the
@@ -280,46 +317,64 @@ class GatedAttention(GroupedQueryAttention):
 
 class Attention(GroupedQueryAttention):
     """RoPE by the layer's own law + causal grouped-query attention,
-    ``window`` keys wide where it has a window, over ``[rows, T,
-    hidden]``; no gate, no per-head norm, no bias."""
-
-    matrix_names = param_names = ("wq", "wk", "wv")
+    ``window`` keys wide where it has a window, + a sigmoid gate a head
+    where it has one, over ``[rows, T, hidden]``; no per-head norm, no
+    bias."""
 
     def __init__(self, workflow=None,
-                 rope: Optional[Dict[str, Any]] = None,
+                 rope: Optional[Dict[str, Any]] = None, gate: bool = False,
                  **kwargs: Any) -> None:
+        # (before the base makes a Vector a name)
+        self.matrix_names = self.param_names = ("wq", "wk", "wv") + (
+            ("wg",) if gate else ())
         super().__init__(workflow, **kwargs)
         self.rope = dict(rope or {"rope_type": "default",
                                   "rope_theta": 1e4})
         self.rope_kind = self.rope.get("rope_type", "default")
+        self.gate_kind = "per_head" if gate else None
         #: made once, on the host
         self.inv_freq, self.rope_scale = rope_frequencies(
             self.rope, self.head_size)
+        self.rotary = 2 * len(self.inv_freq)
 
     def param_shapes(self, input_shape):
         h, d = int(input_shape[-1]), self.head_size
-        return {"wq": (h, self.n_heads * d),
-                "wk": (h, self.n_kv_heads * d),
-                "wv": (h, self.n_kv_heads * d)}
+        shapes = {"wq": (h, self.n_heads * d),
+                  "wk": (h, self.n_kv_heads * d),
+                  "wv": (h, self.n_kv_heads * d)}
+        if self.gate_kind:
+            shapes["wg"] = (h, self.n_heads)
+        return shapes
 
     def forward(self, params, x):
+        import jax
         import jax.numpy as jnp
         b, t, _ = x.shape
         nh, nkv, d = self.n_heads, self.n_kv_heads, self.head_size
 
         def rotated(w, n, scale=1.0):
             a = jnp.einsum("bth,hk->btk", x, w).reshape(b, t, n, d)
-            return (rope(a.astype(jnp.float32), inv_freq=self.inv_freq,
-                         scale=self.rope_scale) * scale).astype(x.dtype)
+            return (rope(a.astype(jnp.float32), rotary=self.rotary,
+                         inv_freq=self.inv_freq, scale=self.rope_scale)
+                    * scale).astype(x.dtype)
 
         q = rotated(params["wq"], nh, d ** -0.5)
         k = rotated(params["wk"], nkv)
         v = jnp.einsum("bth,hk->btk", x, params["wv"]).reshape(
             b, t, nkv, d)
         o = self.core(q.reshape(b, t, nkv, nh // nkv, d), k, v)
-        return o.reshape(b, t, nh * d)
+        if not self.gate_kind:
+            return o.reshape(b, t, nh * d)
+        with jax.named_scope(events.SCOPE_ATTN_GATE):
+            gate = jax.nn.sigmoid(jnp.einsum(
+                "bth,hn->btn", x, params["wg"],
+                preferred_element_type=jnp.float32))
+            y = _gate_heads()(o.reshape(b, t, nh, d), gate)
+            return y.reshape(b, t, nh * d)
 
     def mxu_flops_per_sample(self) -> float:
         t, h = int(self.input.shape[1]), int(self.input.shape[2])
         nh, nkv, d = self.n_heads, self.n_kv_heads, self.head_size
-        return 2.0 * t * h * (nh * d + 2 * nkv * d) + self.core_flops(t)
+        gate = nh if self.gate_kind else 0
+        return 2.0 * t * h * (nh * d + 2 * nkv * d + gate) \
+            + self.core_flops(t)

@@ -5,13 +5,15 @@ a share, beside one shared expert where the model has one
 (``ops/sequence.py``).
 
 ``p = softmax(u W_r)`` over ALL ``experts_total`` experts in f32; the
-``top_k`` largest, ``w_e = p_e / sum_topk p``; ``y = sum over e in topk
-AND held of w_e W_d,e(silu(W_g,e u) * W_u,e u) + sigmoid(u . w_s)
-Shared(u)``, the shared expert a SwiGLU.  The layer is told which
-experts it holds (``experts_held`` of them from ``first_held``): only
-those contribute, and that partial sum goes on — in a deployment the
-other shares' parts arrive by an exchange between chips; on one chip
-there is none, and no code stands in for the absent chips.
+``top_k`` largest, ``w_e = s p_e / sum_topk p`` (``s`` the
+``routed_scale``, 1 unless the model scales its routed experts); ``y =
+sum over e in topk AND held of w_e W_d,e(silu(W_g,e u) * W_u,e u) +
+sigmoid(u . w_s) Shared(u)``, the shared expert a SwiGLU.  The layer
+is told which experts it holds (``experts_held`` of them from
+``first_held``): only those contribute, and that partial sum goes on
+— in a deployment the other shares' parts arrive by an exchange
+between chips; on one chip there is none, and no code stands in for
+the absent chips.
 
 **No token is dropped whatever the imbalance.**  A row is cut into
 blocks of tokens.  Inside a block the (token, slot) pairs are sorted by
@@ -410,7 +412,8 @@ class MoE(SequenceUnit):
     def __init__(self, workflow=None, experts_total: int = 8,
                  experts_held: int = 4, first_held: int = 0,
                  top_k: int = 2, expert_size: int = 32,
-                 shared_size: int = 32, **kwargs: Any) -> None:
+                 shared_size: int = 32, routed_scale: float = 1.0,
+                 **kwargs: Any) -> None:
         # (before the base makes a Vector a name)
         self.matrix_names = self.param_names = self.ROUTED + (
             self.SHARED if shared_size else ())
@@ -423,6 +426,7 @@ class MoE(SequenceUnit):
         self.experts_total, self.experts_held = experts_total, experts_held
         self.first_held, self.top_k = first_held, top_k
         self.expert_size, self.shared_size = expert_size, shared_size
+        self.routed_scale = float(routed_scale)
         #: the last share journaled (``moe.share``)
         self.share: Dict[str, Any] = {}
         #: the last load journaled (``moe.load``)
@@ -464,7 +468,8 @@ class MoE(SequenceUnit):
                  "first_held": self.first_held, "top_k": self.top_k,
                  "rows": rows, "blocks": tokens // block,
                  "capacity": capacity,
-                 "shared": bool(self.shared_size), **path}
+                 "shared": bool(self.shared_size),
+                 "routed_scale": self.routed_scale, **path}
         if tiles:
             share.update(call_tiles(
                 tiles, capacity or rows, width, self.expert_size,
@@ -487,7 +492,7 @@ class MoE(SequenceUnit):
     def route(self, params, tokens):
         """(ids, weights) ``[tokens, top_k]``: each token's chosen
         experts among all ``experts_total``, and their renormalised
-        weights (f32)."""
+        weights times ``routed_scale`` (f32)."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -496,7 +501,10 @@ class MoE(SequenceUnit):
                 "th,he->te", tokens, params["router"],
                 preferred_element_type=jnp.float32), axis=-1)
             top_p, top_i = lax.top_k(prob, self.top_k)
-            return top_i, top_p / jnp.sum(top_p, -1, keepdims=True)
+            top_w = top_p / jnp.sum(top_p, -1, keepdims=True)
+            if self.routed_scale != 1.0:
+                top_w = top_w * self.routed_scale
+            return top_i, top_w
 
     def _held(self, top_i):
         """(local id or ``experts_held`` where held elsewhere, held?)"""

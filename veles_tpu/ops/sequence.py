@@ -240,7 +240,8 @@ class Dense(SequenceUnit):
 
 class SwiGLU(SequenceUnit):
     """``silu(x W_gate) * (x W_up)``; the down projection is the
-    ``dense`` layer that follows."""
+    ``dense`` layer that follows.  Its device ops carry ``mlp/swiglu``,
+    forward and backward."""
 
     matrix_names = param_names = ("w_gate", "w_up")
 
@@ -259,11 +260,12 @@ class SwiGLU(SequenceUnit):
     def forward(self, params, x):
         import jax
         import jax.numpy as jnp
-        gate = jnp.einsum("bth,hk->btk", x, params["w_gate"])
-        up = jnp.einsum("bth,hk->btk", x, params["w_up"])
-        y = jax.nn.silu(gate.astype(jnp.float32)) \
-            * up.astype(jnp.float32)
-        return y.astype(x.dtype)
+        with jax.named_scope(events.SCOPE_MLP_SWIGLU):
+            gate = jnp.einsum("bth,hk->btk", x, params["w_gate"])
+            up = jnp.einsum("bth,hk->btk", x, params["w_up"])
+            y = jax.nn.silu(gate.astype(jnp.float32)) \
+                * up.astype(jnp.float32)
+            return y.astype(x.dtype)
 
     def mxu_flops_per_sample(self) -> float:
         t, n_in = self.input.shape[1], self.input.shape[2]
@@ -311,10 +313,13 @@ class LMHead(SequenceUnit):
 
 def rope_frequencies(spec: Dict[str, Any], head_size: int
                      ) -> Tuple[np.ndarray, float]:
-    """(inverse frequencies ``[head_size / 2]`` f32, the scale on cos
-    and sin) of a layer's ``rope`` specification, made once on the
-    host.  ``rope_type`` ``default``: ``theta^(-2j/d)``, scale 1.
-    ``yarn`` (as ``transformers`` computes it): ``e_j = theta^(-2j/d)``,
+    """(inverse frequencies ``[d / 2]`` f32, the scale on cos and sin)
+    of a layer's ``rope`` specification, made once on the host, over
+    the ``d = head_size * partial_rotary_factor`` elements of a head
+    that are rotated (the factor 1 where the specification gives none:
+    the whole head).  ``rope_type`` ``default``: ``theta^(-2j/d)``,
+    scale 1.  ``yarn`` (as ``transformers`` computes it): ``e_j =
+    theta^(-2j/d)``,
     ``p_j = e_j / factor``; ``dim(r) = d ln(original / (2 pi r)) / (2 ln
     theta)``, ``low = max(floor(dim(beta_fast)), 0)``, ``high =
     min(ceil(dim(beta_slow)), d - 1)``; ``ramp_j = clip((j - low) /
@@ -322,7 +327,11 @@ def rope_frequencies(spec: Dict[str, Any], head_size: int
     scale is ``attention_factor`` (``0.1 ln(factor) + 1`` where the
     specification gives none)."""
     kind = spec.get("rope_type", "default")
-    theta, d = float(spec["rope_theta"]), int(head_size)
+    theta = float(spec["rope_theta"])
+    d = int(round(head_size * float(spec.get("partial_rotary_factor", 1))))
+    if d % 2 or not 0 < d <= head_size:
+        raise ValueError(f"rope: {d} rotated elements of a head of "
+                         f"{head_size}")
     e = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
     if kind == "default":
         return e.astype(np.float32), 1.0
@@ -353,13 +362,14 @@ def rope(x, theta: Optional[float] = None, rotary: Optional[int] = None,
     whole head (``rotary`` None), angle ``n * theta^(-2i/d)``, or over
     the first ``rotary`` elements of each head alone (a partial rotary
     factor: angle ``n * theta^(-2i/rotary)``), the rest untouched.
-    ``inv_freq`` ``[d / 2]`` given (:func:`rope_frequencies`): angle
-    ``n * inv_freq_i`` in ``theta``'s place, cos and sin times
-    ``scale``."""
+    ``inv_freq`` ``[rotated / 2]`` given (:func:`rope_frequencies`):
+    angle ``n * inv_freq_i`` in ``theta``'s place, cos and sin times
+    ``scale`` — on the rotated elements alone."""
     import jax.numpy as jnp
     if rotary is not None and rotary < x.shape[-1]:
         return jnp.concatenate(
-            [rope(x[..., :rotary], theta), x[..., rotary:]], -1)
+            [rope(x[..., :rotary], theta, inv_freq=inv_freq, scale=scale),
+             x[..., rotary:]], -1)
     t, d = x.shape[1], x.shape[-1]
     if inv_freq is None:
         inv = jnp.float32(theta) ** (
