@@ -200,12 +200,16 @@ def test_xla_core_in_blocks_equals_one_block_under_a_window(window):
 
 
 def test_the_kernels_block_list_leaves_out_blocks_left_of_the_window():
-    """The mask tables the shipped kernel is built from, at the cell's
-    sizes (made on the host; no chip): a query block visits 3 key
-    blocks of 512 under a window of 1024, 16 without one, forward and
-    in both backward kernels."""
-    t, block, w = 8192, 512, 1024
-    path = attention.attention_path("tpu", 128, t, window=w)
+    """The cell's window of 1024 keys takes the band kernels; a window
+    their blocks do not tile (1000 keys) keeps the shipped kernel, whose
+    mask tables, at the cell's row, are checked here (made on the host;
+    no chip): a query block visits 3 key blocks of 512 under that
+    window, 16 without one, forward and in both backward kernels."""
+    t, block, w = 8192, 512, 1000
+    band = attention.attention_path("tpu", 128, t, window=1024, group=8)
+    assert (band["form"], band["tiles"], band["visited"]) == (
+        "band", {"block_q": 256, "slab": 1280}, 1.25)
+    path = attention.attention_path("tpu", 128, t, window=w, group=8)
     assert (path["form"], path["window"], path["kv_blocks"]) == (
         "splash", w, 3)
     assert path["tiles"] == {"block_q": block, "block_kv": block}
@@ -327,6 +331,18 @@ def test_attention_path_and_share_are_journaled_with_the_new_fields():
         ("xla", 64, "default"), ("splash", 64, "default"),
         ("xla", 64, "default")]
     assert seen[1]["kv_blocks"] == 2 and "kv_blocks" not in seen[0]
+    # a window the band kernels' blocks tile takes them
+    band, _, _, _ = _unit(WINDOW, head_size=128, window=256)
+    band.device = SimpleNamespace(platform="tpu")
+    assert band._path(1024) == {
+        "form": "band", "window": 256, "visited": 2.0,
+        "tiles": {"block_q": 256, "slab": 512}}
+    last = telemetry.recent_events(events.EV_ATTN_PATH)[-1]
+    assert (last["form"], last["visited"], last["rope"]) == (
+        "band", 2.0, "default") and "kv_blocks" not in last
+    assert telemetry.gauge(events.GAUGE_ATTN_BAND_LAYERS).value == 1
+    assert band._path(1024, batched=True)["form"] == "xla"
+    assert telemetry.gauge(events.GAUGE_ATTN_BAND_LAYERS).value == 0
     gated = attention.GatedAttention(None, name="g")
     gated.device = SimpleNamespace(platform="tpu")
     gated.head_size = 128

@@ -235,10 +235,47 @@ if which == "moe":
     assert "bf16[32768,896]" not in hlo     # no whole-buffer product
 else:
     assert (unit.path["form"], unit.path["window"],
-            unit.path["kv_blocks"]) == (
-        ("splash", 1024, 3) if which == "window"
+            unit.path.get("visited", unit.path.get("kv_blocks"))) == (
+        ("band", 1024, 1.25) if which == "window"
         else ("splash", None, 16)), unit.path
     assert hlo.count("tpu_custom_call") >= 3
+print("COMPILED")
+"""
+
+BAND_CORE = PRELUDE + """
+import re
+from veles_tpu.ops import attention, attention_pallas
+
+nkv, group, window = (int(a) for a in sys.argv[1:4])
+rows, t, d = 4, 8192, 128
+path = attention.attention_path("tpu", d, t, window=window, group=group)
+assert path["form"] == "band", path
+tiles = attention_pallas.BandTiles(**path["tiles"])
+
+
+def core(q, k, v):
+    # the projections' layout in and out, as the attention layer has it
+    o = attention_pallas.band_attention(
+        q.reshape(rows, t, nkv, group, d), k.reshape(rows, t, nkv, d),
+        v.reshape(rows, t, nkv, d), window, tiles)
+    return o.reshape(q.shape)
+
+
+def both(q, k, v, err):
+    out, back = jax.vjp(core, q, k, v)
+    return (out,) + back(err)
+
+
+q = spec((rows, t, nkv * group * d), jnp.bfloat16)
+k = spec((rows, t, nkv * d), jnp.bfloat16)
+hlo = jax.jit(both).lower(q, k, k, q).compile().as_text()
+for name in ("band_fwd", "band_bwd_dq", "band_bwd_dkv"):
+    assert name in hlo, name
+# nothing is laid out anew round the kernels
+moved = [line for line in hlo.splitlines()
+         if re.search(r"= \\S+ (copy|transpose)\\(", line)
+         and f"[{rows},{t}," in line]
+assert not moved, moved[:3]
 print("COMPILED")
 """
 
@@ -329,3 +366,18 @@ def test_mellum2_layer_types_compile_at_the_cells_sizes(which):
     32 768 rows would take the worst routing), 8 blocks, no shared expert.
     tests_tpu/test_mellum2_layers.py runs them on the chip."""
     _compile(MELLUM2_UNITS, which)
+
+
+@pytest.mark.parametrize("nkv,group,window", [(8, 9, 512), (4, 8, 1024)],
+                         ids=["laguna", "mellum2"])
+def test_band_kernels_compile_at_the_cells_shapes(nkv, group, window):
+    """The window core's three band kernels, forward and
+    backward, through Mosaic in bf16 at the two window cells' shapes
+    (four rows of 8 192, head size 128) and at the block
+    ``attention_path`` chooses: the laguna window of 512 keys at group
+    9, the mellum2 window of 1024 at group 8; no copy or transpose of
+    a row-sized array is left round them.  Interpret mode
+    (tests/test_attention_pallas.py) cannot see an unaligned slice or
+    a VMEM overrun; this can.  tests_tpu/test_band_attention.py runs
+    them on the chip."""
+    _compile(BAND_CORE, nkv, group, window)

@@ -1,7 +1,8 @@
 """Laguna-S-2.1's attention layers on the chip, at the published widths
 in bf16 on one row of 8 192: a window layer (72 query heads over 8 key
-heads, a window of 512 keys: the shipped flash kernel at group 9) and a
-full layer (48 over 8, YaRN on half of each head: group 6), each with
+heads, a window of 512 keys: the band kernels at group 9) and a full
+layer (48 over 8, YaRN on half of each head: the shipped flash kernel
+at group 6), each with
 its gate a head, forward and gradients, against the plain form every
 other platform runs — within the gap that plain form itself keeps from
 an f32 run of the same mathematics (the bf16 witness).
@@ -61,8 +62,13 @@ def test_gated_layer_on_the_kernel_matches_the_xla_core(tpu_device,
         return jax.tree.leaves(out), time.perf_counter() - t0
 
     splash, t_splash = run(jnp.bfloat16)
-    assert unit.path["form"] == "splash" and unit.path["window"] == window
-    assert unit.path["kv_blocks"] == (2 if window else T // 512)
+    assert unit.path["form"] == ("band" if window else "splash") \
+        and unit.path["window"] == window
+    if window:
+        assert unit.path["tiles"] == {"block_q": 256, "slab": 768}
+        assert unit.path["visited"] == 1.5
+    else:
+        assert unit.path["kv_blocks"] == T // 512
     plain = attention.attention_path
     monkeypatch.setattr(attention, "attention_path",
                         lambda *a, **k: dict(plain("cpu", *a[1:], **k)))

@@ -234,10 +234,12 @@ def test_the_cells_sizes_take_the_chip_forms_and_drop_nothing(
         superstep=1, decision={"max_epochs": 1})
     w.initialize(device=tpu_device)
     seen = telemetry.recent_events(events.EV_ATTN_PATH)
-    assert [(e["form"], e["window"], e["rope"], e["kv_blocks"])
-            for e in seen] == [("splash", 1024, "default", 3)] * 3 + [
+    assert [(e["form"], e["window"], e["rope"],
+             e.get("visited", e.get("kv_blocks"))) for e in seen] == [
+        ("band", 1024, "default", 1.25)] * 3 + [
         ("splash", None, "yarn", 16)]
     assert telemetry.gauge(events.GAUGE_ATTN_WINDOW_LAYERS).value == 3
+    assert telemetry.gauge(events.GAUGE_ATTN_BAND_LAYERS).value == 3
     shares = telemetry.recent_events(events.EV_MOE_SHARE)
     assert len(shares) == 4 and all(
         (e["form"], e["experts_total"], e["experts_held"], e["top_k"],

@@ -98,14 +98,16 @@ EV_EVA_PATH = _ev("eva.path")
 EV_GDN_PATH = _ev("gdn.path")
 #: which form of the causal attention core a unit runs
 #: (``ops/attention.py`` ``attention_path``): ``unit``, ``form``
-#: (``splash``: the Pallas kernel that ships with jax / ``xla``: a block
+#: (``band``: the window's kernels of ``ops/attention_pallas.py`` /
+#: ``splash``: the Pallas kernel that ships with jax / ``xla``: a block
 #: of queries at a time), ``reason`` where it is ``xla`` (``platform`` /
 #: ``batched`` / ``head_size`` / ``row``), ``tiles``, ``window`` (keys
 #: a query reads; None: every key up to itself), ``rope`` (``default``
 #: / ``yarn``), ``rotary`` (elements of a head RoPE rotates), ``gate``
-#: (``per_head`` / ``elementwise`` / None) and, under the kernel form,
-#: ``kv_blocks``: the most key blocks a query block visits; once a unit
-#: at ``initialize``, again only where a later trace must differ
+#: (``per_head`` / ``elementwise`` / None); under ``splash``
+#: ``kv_blocks``, the most key blocks a query block visits, under
+#: ``band`` ``visited``, keys scored a key the window reads; once a
+#: unit at ``initialize``, again only where a later trace must differ
 EV_ATTN_PATH = _ev("attn.path")
 #: the share of a mixture of experts a unit holds, once at
 #: ``initialize`` (``ops/moe.py``): ``unit``, ``experts_total``,
@@ -376,6 +378,9 @@ GAUGE_GDN_FUSED_LAYERS = _gauge("gdn.fused_layers")
 GAUGE_GDN_FUSED_SCANS = _gauge("gdn.fused_scans")
 #: attention units of the workflow whose core reads a window of keys
 GAUGE_ATTN_WINDOW_LAYERS = _gauge("attn.window_layers")
+#: attention units of the workflow whose core the band kernels run
+#: (``ops/attention_pallas.py``), as their last journaled paths say
+GAUGE_ATTN_BAND_LAYERS = _gauge("attn.band_layers")
 #: rows the held experts could not take in the probed minibatch, summed
 #: over the layers (static buffers are sized for the worst routing: 0)
 GAUGE_MOE_DROPPED_ROWS = _gauge("moe.dropped_rows")

@@ -71,9 +71,11 @@ ln(8192 / (2 pi r')) / (2 ln 5e5)``, ``low = max(floor(dim(32)), 0)``
 v_m`` over ``m <= n`` on a full layer and over ``n - 512 < m <= n`` on
 a window layer, scores and softmax in f32.  The gate: ``y_a =
 sigmoid(u W_g)_a o_a``, ``W_g [3072, nh]``; then the ``nh 128 -> 3072``
-out-projection (the entry's ``dense``).  On a TPU the core is the
-flash kernel that ships with jax at groups 6 and 9 (``attn.path``:
-``form``, ``window``, ``rope``, ``rotary``, ``gate``, ``kv_blocks``).
+out-projection (the entry's ``dense``).  On a TPU the window layers'
+core is the band kernels of ``ops/attention_pallas.py`` at group 9, the
+full layers' the flash kernel that ships with jax at group 6
+(``attn.path``: ``form``, ``window``, ``rope``, ``rotary``, ``gate``,
+``visited`` / ``kv_blocks``).
 
 FFN of layer 0: ``W_down(silu(u W_gate) * u W_up)``, 12 288 wide
 (``swiglu`` then ``dense``).  FFN of the MoE layers: ``p = softmax(u

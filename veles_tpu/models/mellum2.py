@@ -58,10 +58,13 @@ scores squared).  Query head h reads key head h // 8; ``o_n =
 softmax_m(q_n . k_m / sqrt(128)) v_m`` over ``m <= n`` on a full layer
 and over ``n - 1024 < m <= n`` on a window layer, scores and softmax in
 f32; then the 4096 -> 2304 out-projection (the entry's ``dense``).  On
-a TPU the core is the flash kernel that ships with jax — it visits no
-block above the diagonal and none left of the window — elsewhere a
-block of queries at a time (``attn.path``: ``form``, ``window``,
-``rope``, ``kv_blocks``).
+a TPU a window layer's core is the band kernels of
+``ops/attention_pallas.py`` — a grid step every query head of a key
+head's group against the one slab of keys the window reads — and the
+full layer's the flash kernel that ships with jax, which visits no
+block above the diagonal; elsewhere a block of queries at a time
+(``attn.path``: ``form``, ``window``, ``rope``, ``visited`` /
+``kv_blocks``).
 
 MoE: ``p = softmax(u W_r)`` over all 64 in f32; the 8 largest, ``w_e =
 p_e / sum_8 p``; ``y = sum_{e in top 8 and held} w_e W_d,e(silu(W_g,e u)

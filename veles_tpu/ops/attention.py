@@ -24,12 +24,19 @@ its own among them), without one every ``m <= n``; with ``gate`` a
 sigmoid a head, ``y_h = sigmoid(u W_g)_h o_h`` (``W_g [hidden, nh]``,
 under ``attn/gate``), without one ``y = o``.
 
-Two forms of the core (scores, softmax, weighted sums), chosen by
+Three forms of the core (scores, softmax, weighted sums), chosen by
 :func:`attention_path` from platform and shapes and journaled
 (``attn.path``):
 
-- ``splash`` — on a TPU where the shapes tile: the Pallas flash
-  attention that ships with jax (``jax.experimental.pallas.ops.tpu.
+- ``band`` — on a TPU under a window whose keys and row tile the
+  kernels' query blocks: the Pallas kernels of
+  ``ops/attention_pallas.py`` (``band_fwd``, ``band_bwd_dq``,
+  ``band_bwd_dkv``), a grid step one block of queries of EVERY query
+  head of a key head's group against the one slab of ``window +
+  block`` keys the window reads, in the projections' own layout;
+- ``splash`` — on a TPU where the shapes tile otherwise (no window, or
+  one the band's blocks do not tile): the Pallas flash attention that
+  ships with jax (``jax.experimental.pallas.ops.tpu.
   splash_attention``), its multi-query kernel mapped over the key
   heads: a tile of scores lives in VMEM under a running softmax,
   blocks above the diagonal and blocks left of the window are never
@@ -37,7 +44,7 @@ Two forms of the core (scores, softmax, weighted sums), chosen by
 - ``xla`` — everywhere else (XLA:CPU, ragged shapes, under ``vmap``):
   plain XLA ops, a block of queries against the keys from the window's
   left edge up to the block's end (``jax.checkpoint`` round a block),
-  the kernel's oracle.
+  the kernels' oracle.
 
 Device ops of the core carry ``attn/core`` — ``attn/window`` where the
 layer has a window — forward and backward.
@@ -49,6 +56,7 @@ from functools import lru_cache
 from typing import Any, Dict, Optional
 
 from veles_tpu import events, telemetry
+from veles_tpu.ops import attention_pallas
 from veles_tpu.ops.sequence import (SequenceUnit, rms_norm, rope,
                                     rope_frequencies, under_vmap)
 
@@ -60,15 +68,24 @@ XLA_BLOCK = 512
 
 
 def attention_path(platform: str, head_size: int, t: int,
-                   batched: bool = False, window: Optional[int] = None
-                   ) -> Dict[str, Any]:
+                   batched: bool = False, window: Optional[int] = None,
+                   group: int = 1) -> Dict[str, Any]:
     """Which form of the core runs, from what the code observes; under
-    the kernel form ``kv_blocks``, the most key blocks a query block
-    visits (the causal triangle's widest row, or the window's)."""
-    block = min(KERNEL_BLOCK, t)
+    the band kernels their ``tiles`` (``block_q``, ``slab``) and
+    ``visited``, keys scored a key the window reads; under the splash
+    kernel ``kv_blocks``, the most key blocks a query block visits (the
+    causal triangle's widest row, or the window's)."""
     reason = "platform" if platform != "tpu" else "batched" if batched \
-        else "head_size" if head_size % LANES \
-        else "row" if t % block or block % LANES else None
+        else "head_size" if head_size % LANES else None
+    band = None if reason or window is None else \
+        attention_pallas.band_tiles(t, window, group, head_size)
+    if band:
+        return {"form": "band", "window": window,
+                "tiles": {"block_q": band.block_q, "slab": band.slab},
+                "visited": band.slab / window}
+    block = min(KERNEL_BLOCK, t)
+    if not reason and (t % block or block % LANES):
+        reason = "row"
     if reason:
         return {"form": "xla", "reason": reason, "window": window}
     return {"form": "splash", "window": window,
@@ -214,21 +231,27 @@ class GroupedQueryAttention(SequenceUnit):
         """:func:`attention_path` of this unit, journaled whenever it
         differs from the last one journaled."""
         path = attention_path(self.platform(), self.head_size, t, batched,
-                              self.window)
+                              self.window, self.n_heads // self.n_kv_heads)
         if path != self.path:
             self.path = path
             telemetry.event(events.EV_ATTN_PATH, unit=self.name,
                             rope=self.rope_kind, rotary=self.rotary,
                             gate=self.gate_kind, **path)
+            telemetry.gauge(events.GAUGE_ATTN_BAND_LAYERS).set(sum(
+                f.path.get("form") == "band" for f in self._peers()))
         return path
+
+    def _peers(self):
+        """The workflow's attention units (this one alone without a
+        workflow)."""
+        return [f for f in getattr(self.workflow, "forwards", None)
+                or [self] if isinstance(f, GroupedQueryAttention)]
 
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device=device, **kwargs)
         self._path(int(self.input.shape[1]))
-        peers = getattr(self.workflow, "forwards", None) or [self]
         telemetry.gauge(events.GAUGE_ATTN_WINDOW_LAYERS).set(sum(
-            f.window is not None for f in peers
-            if isinstance(f, GroupedQueryAttention)))
+            f.window is not None for f in self._peers()))
 
     def core(self, q, k, v):
         """q ``[rows, T, nkv, group, d]`` (already scaled), k, v
@@ -238,6 +261,10 @@ class GroupedQueryAttention(SequenceUnit):
         path = self._path(q.shape[1], under_vmap(q, k, v))
         with jax.named_scope(events.SCOPE_ATTN_CORE if self.window is None
                              else events.SCOPE_ATTN_WINDOW):
+            if path["form"] == "band":
+                return attention_pallas.band_attention(
+                    q, k, v, self.window,
+                    attention_pallas.BandTiles(**path["tiles"]))
             if path["form"] == "splash":
                 return core_splash(q, k, v, path["tiles"]["block_q"],
                                    self.window)
