@@ -279,6 +279,35 @@ assert not moved, moved[:3]
 print("COMPILED")
 """
 
+EMBED_GATHER = PRELUDE + """
+from veles_tpu.ops import sequence
+
+vocab, hidden = (int(a) for a in sys.argv[1:3])
+rows, t = 4, 8192
+path = sequence.embed_path(vocab, hidden, rows * t, "tpu")
+assert path == {"form": "gather", "backward": "segment_sum"}, path
+
+
+def step(table, ids, err):
+    # the f32 table looked up in bf16, its gradient back in f32 and the
+    # SGD update, as the train step has them
+    out, back = jax.vjp(lambda w: sequence.gather_embed(
+        w, ids, path["backward"]), table.astype(jnp.bfloat16))
+    (dw,) = back(err)
+    return out, table - 0.01 * dw.astype(jnp.float32)
+
+
+compiled = jax.jit(step, donate_argnums=0).lower(
+    spec((vocab, hidden), jnp.float32), spec((rows, t), jnp.int32),
+    spec((rows, t, hidden), jnp.bfloat16)).compile()
+hlo = compiled.as_text()
+assert "embed_segment_sum" in hlo
+# no one-hot product and no f32 copy of the error rows
+assert " convolution(" not in hlo and " dot(" not in hlo
+assert "f32[%d,%d]" % (rows * t, hidden) not in hlo
+print("COMPILED")
+"""
+
 
 def _compile(src, *argv):
     res = subprocess.run(
@@ -381,3 +410,17 @@ def test_band_kernels_compile_at_the_cells_shapes(nkv, group, window):
     a VMEM overrun; this can.  tests_tpu/test_band_attention.py runs
     them on the chip."""
     _compile(BAND_CORE, nkv, group, window)
+
+
+@pytest.mark.parametrize("vocab,hidden", [(24576, 2304), (12544, 3072)],
+                         ids=["mellum2", "laguna"])
+def test_embedding_gather_compiles_at_the_cells_shapes(vocab, hidden):
+    """The embedding's gather form (``ops/sequence.py``
+    ``gather_embed``) at the two cells' held tables and four rows of
+    8 192 ids: the lookup, the table's gradient by the segment sum
+    kernel of ``ops/embed_pallas.py`` (through Mosaic: interpret mode,
+    tests/test_embedding.py, cannot see an unaligned slice or a VMEM
+    overrun) and the f32 table's SGD update, with no product left and
+    no f32 copy of the error rows.  tests_tpu/test_mellum2_layers.py
+    runs it on the chip."""
+    _compile(EMBED_GATHER, vocab, hidden)

@@ -279,6 +279,11 @@ def test_the_cells_sizes_take_the_chip_forms_and_drop_nothing(
     seen = telemetry.recent_events(events.EV_ATTN_PATH)
     assert [(e["form"], e["tiles"]["block_q"]) for e in seen] == [
         ("splash", 512)]
+    embeds = telemetry.recent_events(events.EV_EMBED_PATH)
+    assert [(e["form"], e["backward"], e["vocab"], e["hidden"], e["rows"])
+            for e in embeds] == [("gather", "segment_sum", 18992, 2048,
+                                  32768)]
+    assert telemetry.gauge(events.GAUGE_EMBED_GATHER_LAYERS).value == 1
     shares = telemetry.recent_events(events.EV_MOE_SHARE)
     assert len(shares) == 4 and all(
         (e["form"], e["experts_total"], e["experts_held"], e["top_k"],

@@ -5,7 +5,9 @@ layer (48 over 8, YaRN on half of each head: the shipped flash kernel
 at group 6), each with
 its gate a head, forward and gradients, against the plain form every
 other platform runs — within the gap that plain form itself keeps from
-an f32 run of the same mathematics (the bf16 witness).
+an f32 run of the same mathematics (the bf16 witness); and the
+embedding at the cell's table and four rows of 8 192 ids takes the
+gather form.
 """
 
 import time
@@ -15,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from veles_tpu import events, telemetry
 from veles_tpu.models.laguna import CUT, laguna_layers
 from veles_tpu.ops import attention
 from veles_tpu.ops.registry import forward_registry
@@ -84,3 +87,17 @@ def test_gated_layer_on_the_kernel_matches_the_xla_core(tpu_device,
     print(f"gated attention layer, one row, forward + backward: kernel "
           f"{t_splash:.4f} s, xla {t_xla:.4f} s (group {group}, window "
           f"{window})")
+
+
+def test_the_embedding_at_the_cells_sizes_takes_the_gather(tpu_device):
+    fw = dict(FLAT[0]["->"])
+    assert FLAT[0]["type"] == "embedding"
+    fw.pop("weights_stddev")
+    telemetry.reset()
+    unit = forward_registry["embedding"][0](None, name="u_embed", **fw)
+    unit.device = tpu_device
+    unit._path(CUT["minibatch"] * T)
+    (e,) = telemetry.recent_events(events.EV_EMBED_PATH)
+    assert (e["form"], e["backward"], e["vocab"], e["hidden"]) == (
+        "gather", "segment_sum", 12544, 3072), e
+    assert telemetry.gauge(events.GAUGE_EMBED_GATHER_LAYERS).value == 1

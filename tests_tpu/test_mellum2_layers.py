@@ -6,7 +6,9 @@ the bf16 witness), forward and gradients, and the share of 16 of 64
 experts on the shipped grouped matmul at 32 768-row buffers against
 ``ragged_dot``; then the cell's own sizes through ``StandardWorkflow``:
 the journaled paths and one firing whose ``moe.load`` reads ``dropped``
-0 at four rows.
+0 at four rows; and the embedding's gather form (the rows taken, the
+table's gradient by the segment sum kernel) against the one-hot product
+at the cell's table and ids.
 """
 
 import time
@@ -19,6 +21,7 @@ from veles_tpu import events, prng, telemetry
 from veles_tpu.models import mellum2
 from veles_tpu.models.mellum2 import CUT, mellum2_layers
 from veles_tpu.ops import attention, moe
+from veles_tpu.ops import sequence as seq
 from veles_tpu.ops.registry import forward_registry
 
 T, WINDOW = CUT["seq_len"], 1024
@@ -248,6 +251,11 @@ def test_the_cells_sizes_take_the_chip_forms_and_drop_nothing(
     blocked = telemetry.recent_events(events.EV_LOSS_BLOCKED)[-1]
     assert (blocked["blocks"], blocked["reason"]) == (
         32, "whole_exceeds_free")
+    embeds = telemetry.recent_events(events.EV_EMBED_PATH)
+    assert [(e["form"], e["backward"], e["vocab"], e["hidden"], e["rows"])
+            for e in embeds] == [("gather", "segment_sum", 24576, 2304,
+                                  32768)]
+    assert telemetry.gauge(events.GAUGE_EMBED_GATHER_LAYERS).value == 1
     print(telemetry.recent_events(events.EV_FUSED_RECOMPUTE)[-1])
     w.loader.run()
     w.fused.run()
@@ -267,3 +275,43 @@ def test_the_cells_sizes_take_the_chip_forms_and_drop_nothing(
     assert count == CUT["minibatch"] * (CUT["seq_len"] - 1)
     assert abs(loss_sum / count - np.log(CUT["vocab_held"])) < 0.5
     w.stop()
+
+
+def test_the_embeddings_gather_matches_the_one_hot_at_the_cells_table(
+        tpu_device):
+    """The f32 table of 24 576 x 2 304 looked up in bf16 at four rows of
+    8 192 ids (some outside the table): the gather's rows are the
+    one-hot product's bit for bit, and its gradient — the segment sum
+    kernel's f32 sums, rounded to bf16 once — is the transposed
+    product's to within one rounding of bf16."""
+    vocab, hidden = CUT["vocab_held"], 2304
+    key = jax.random.key(44)
+    w = jax.random.normal(key, (vocab, hidden)).astype(jnp.bfloat16)
+    ids = jax.random.randint(jax.random.fold_in(key, 1),
+                             (CUT["minibatch"], T), -8, vocab + 8, jnp.int32)
+    err = jax.random.normal(jax.random.fold_in(key, 2),
+                            (CUT["minibatch"], T, hidden), jnp.bfloat16)
+    path = seq.embed_path(vocab, hidden, ids.size, "tpu")
+    assert path == {"form": "gather", "backward": "segment_sum"}, path
+
+    def onehot(w):
+        return jnp.einsum("btv,vh->bth", jax.nn.one_hot(
+            ids, vocab, dtype=w.dtype), w)
+
+    def both(fn):
+        out, back = jax.vjp(fn, w)
+        return out, back(err)[0]
+
+    t0 = time.perf_counter()
+    y, dw = jax.block_until_ready(jax.jit(lambda: both(
+        lambda w: seq.gather_embed(w, ids, path["backward"])))())
+    t1 = time.perf_counter()
+    want_y, want = jax.block_until_ready(jax.jit(lambda: both(onehot))())
+    print("gather, one-hot: %.1f s, %.1f s (compile included)" % (
+        t1 - t0, time.perf_counter() - t1))
+    assert bool(jnp.array_equal(y, want_y))
+    got, want = (np.asarray(a, np.float32) for a in (dw, want))
+    # a row of ~1.3 error rows on average: its f32 sum in another order,
+    # then one rounding to bf16 on each side
+    np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=1e-5)
+    assert not got[np.setdiff1d(np.arange(vocab), np.asarray(ids))].any()

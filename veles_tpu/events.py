@@ -82,6 +82,16 @@ EV_FUSED_RECOMPUTE = _ev("fused.recompute")
 #: ``window``), the kernels' ``tiles`` where it is ``fused``; once a
 #: unit at ``initialize``, again only where a later trace must differ
 EV_EVA_PATH = _ev("eva.path")
+#: which form of the token embedding a unit runs (``ops/sequence.py``
+#: ``embed_path``): ``unit``, ``form`` (``onehot``: the one-hot
+#: product / ``gather``: the rows taken, the table's gradient a sum of
+#: each id's error rows), ``reason`` (``cost`` where it is ``onehot``;
+#: where the gather's gradient is ``scatter``: ``platform`` /
+#: ``batched``), ``backward`` (``onehot`` / ``segment_sum``: the Pallas
+#: kernel of ``ops/embed_pallas.py`` / ``scatter``: XLA's scatter-add),
+#: ``vocab``, ``hidden``, ``rows`` (ids a step); once a unit at
+#: ``initialize``, again only where a later trace must differ
+EV_EMBED_PATH = _ev("embed.path")
 #: which form of the gated delta rule a unit runs (``ops/deltanet.py``
 #: ``rule_path``): ``unit``, ``form`` (``chunked`` / ``recurrent``),
 #: ``reason`` where it is ``recurrent`` (``ragged``: the row is not
@@ -370,6 +380,9 @@ GAUGE_EVA_CHUNK = _gauge("eva.chunk")
 GAUGE_EVA_SUMMARIES_PER_ROW = _gauge("eva.summaries_per_row")
 #: ``eva_attention`` units of the workflow on the fused kernels
 GAUGE_EVA_FUSED_LAYERS = _gauge("eva.fused_layers")
+#: ``embedding`` units of the workflow on the gather form, as their
+#: last journaled paths say
+GAUGE_EMBED_GATHER_LAYERS = _gauge("embed.gather_layers")
 #: ``gated_delta_net`` units of the workflow whose chunk products the
 #: fused kernels make
 GAUGE_GDN_FUSED_LAYERS = _gauge("gdn.fused_layers")

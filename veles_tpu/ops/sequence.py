@@ -48,13 +48,14 @@ once at ``initialize`` (``eva.path``; gauge ``eva.fused_layers``).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
 from veles_tpu import events, prng, telemetry
 from veles_tpu.memory import Vector
-from veles_tpu.ops import eva_pallas
+from veles_tpu.ops import embed_pallas, eva_pallas
 from veles_tpu.ops.nn_units import ForwardUnit, GradientUnit
 
 
@@ -154,11 +155,104 @@ class GDSequence(GradientUnit):
         return err_input, grads
 
 
+#: bf16 FLOP/s of a v5e's MXU and bytes/s of its HBM: the units of
+#: :func:`embed_path`'s two prices
+MXU_FLOPS = 197e12
+HBM_BYTES_PER_S = 819e9
+
+
+def embed_path(vocab: int, hidden: int, n: int, platform: str,
+               batched: bool = False) -> Dict[str, Any]:
+    """Which form of the embedding runs, from what the code observes:
+    ``onehot`` (reason ``cost``) where the one-hot form's two products,
+    lookup and gradient, take no longer on the MXU (``4 N V H /
+    MXU_FLOPS``, ``N`` ids a step) than the gather form moves its bytes
+    through HBM (``(6 N H + 2 V H)`` bf16 elements: the rows looked up
+    read and written, the error rows read, sorted and read again, the
+    gradient written); else ``gather``, whose ``backward`` is
+    ``segment_sum`` (``ops/embed_pallas.py``) on a TPU and ``scatter``
+    (XLA's scatter-add) where it is not (``reason`` ``platform``) or
+    under ``vmap`` (``batched``: the kernel's schedule has no member
+    axis)."""
+    onehot_s = 4.0 * n * vocab * hidden / MXU_FLOPS
+    gather_s = 2.0 * (6.0 * n + 2.0 * vocab) * hidden / HBM_BYTES_PER_S
+    if onehot_s <= gather_s:
+        return {"form": "onehot", "reason": "cost", "backward": "onehot"}
+    reason = "platform" if platform != "tpu" else \
+        "batched" if batched else None
+    if reason:
+        return {"form": "gather", "backward": "scatter", "reason": reason}
+    return {"form": "gather", "backward": "segment_sum"}
+
+
+def take_rows(w, ids):
+    """Rows ``w[ids]`` (``[..., hidden]``); an id outside ``[0, V)``
+    gives a row of zeros, as its one-hot does."""
+    import jax.numpy as jnp
+    v = w.shape[0]
+    ids = ids.astype(jnp.int32)
+    return jnp.take(w, jnp.where((ids >= 0) & (ids < v), ids, v), axis=0,
+                    mode="fill", fill_value=0)
+
+
+def scatter_rows(ids, err, vocab: int):
+    """``[vocab, hidden]`` in ``err``'s dtype: row ``v`` the f32 sum of
+    the error rows of the ids equal to ``v`` (XLA's scatter-add); ids
+    outside ``[0, vocab)`` dropped."""
+    import jax.numpy as jnp
+    h = err.shape[-1]
+    ids = ids.reshape(-1).astype(jnp.int32)
+    ids = jnp.where((ids >= 0) & (ids < vocab), ids, vocab)
+    return jnp.zeros((vocab, h), jnp.float32).at[ids].add(
+        err.reshape(-1, h).astype(jnp.float32), mode="drop").astype(
+            err.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _gather_op():
+    """The gather form, differentiable in the table alone
+    (``jax.custom_vjp``; the residual is the ids)."""
+    import jax
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+    def op(w, ids, vocab, dtype, backward, interpret):
+        return take_rows(w, ids)
+
+    def fwd(w, ids, vocab, dtype, backward, interpret):
+        return take_rows(w, ids), ids
+
+    def bwd(vocab, dtype, backward, interpret, ids, err):
+        if backward == "segment_sum":
+            dw = embed_pallas.embed_segment_sum(ids, err, vocab,
+                                                interpret=interpret)
+        else:
+            dw = scatter_rows(ids, err, vocab)
+        return dw.astype(dtype), np.zeros(ids.shape, jax.dtypes.float0)
+
+    op.defvjp(fwd, bwd)
+    return op
+
+
+def gather_embed(w, ids, backward: str = "scatter",
+                 interpret: bool = False):
+    """The gather form of the embedding: :func:`take_rows`, whose
+    gradient in ``w`` is the f32 sum of each id's error rows in ``w``'s
+    dtype — by ``backward`` ``segment_sum`` (the Pallas kernel;
+    ``interpret``: its interpret mode, off the chip) or ``scatter``."""
+    return _gather_op()(w, ids, int(w.shape[0]), np.dtype(w.dtype),
+                        backward, interpret)
+
+
 class Embedding(SequenceUnit):
     """ids ``[rows, T]`` -> ``[rows, T, hidden]``: row ``id`` of the
-    table.  Written as a one-hot product (exact; the MXU's form of a
-    gather), so the table's gradient — a scatter-add over the ids — is
-    the transposed product with f32 accumulation."""
+    table, an id outside the table a row of zeros.  Two forms of the
+    one lookup, chosen by :func:`embed_path` from the shapes: the
+    one-hot product (the MXU's form of a gather; the table's gradient
+    is the transposed product with f32 accumulation) where the table
+    is small, the gather and a segment sum of the error rows
+    (:func:`gather_embed`) where the one-hot's FLOPs would cost more
+    than the rows' bytes.  Which form a unit took is journaled once at
+    ``initialize`` (``embed.path``; gauge ``embed.gather_layers``)."""
 
     matrix_names = param_names = ("weights",)
 
@@ -166,6 +260,8 @@ class Embedding(SequenceUnit):
                  hidden_size: int = 64, **kwargs: Any) -> None:
         super().__init__(workflow, **kwargs)
         self.vocab_size, self.hidden_size = vocab_size, hidden_size
+        #: the last :func:`embed_path` journaled ({} before the first)
+        self.path: Dict[str, Any] = {}
 
     def output_shape_for(self, input_shape):
         return tuple(input_shape) + (self.hidden_size,)
@@ -173,10 +269,33 @@ class Embedding(SequenceUnit):
     def param_shapes(self, input_shape):
         return {"weights": (self.vocab_size, self.hidden_size)}
 
+    def _path(self, n: int, batched: bool = False) -> Dict[str, Any]:
+        """:func:`embed_path` of this unit for ``n`` ids a step,
+        journaled whenever it differs from the last one journaled."""
+        path = embed_path(self.vocab_size, self.hidden_size, n,
+                          self.platform(), batched)
+        if path != self.path:
+            self.path = path
+            telemetry.event(events.EV_EMBED_PATH, unit=self.name,
+                            vocab=self.vocab_size, hidden=self.hidden_size,
+                            rows=n, **path)
+            peers = getattr(self.workflow, "forwards", None) or [self]
+            telemetry.gauge(events.GAUGE_EMBED_GATHER_LAYERS).set(sum(
+                isinstance(f, Embedding) and f.path.get("form") == "gather"
+                for f in peers))
+        return path
+
+    def initialize(self, device=None, **kwargs) -> None:
+        super().initialize(device=device, **kwargs)
+        self._path(int(np.prod(self.input.shape)))
+
     def forward(self, params, x):
         import jax
         import jax.numpy as jnp
         w = params["weights"]
+        path = self._path(int(np.prod(x.shape)), under_vmap(w, x))
+        if path["form"] == "gather":
+            return gather_embed(w, x, path["backward"])
         return jnp.einsum("btv,vh->bth",
                           jax.nn.one_hot(x, self.vocab_size,
                                          dtype=w.dtype), w)
